@@ -16,6 +16,8 @@ import os
 import sys
 import traceback
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 from . import (bench_paper_table1, bench_matching, bench_streaming,
                bench_dtw, bench_wavelet, bench_autotune, bench_roofline)
 
@@ -42,6 +44,7 @@ def main() -> None:
     ap.add_argument("--json", default=None,
                     help="also write rows (+ failures) to this JSON file")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows = []
     failed = []

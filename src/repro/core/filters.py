@@ -206,7 +206,8 @@ class StreamingFilter:
         xp[:c] = x
         y, self._z = _lfilter_scan_carry(self._b, self._a, jnp.asarray(xp),
                                          self._z, jnp.int32(c))
-        return np.asarray(y[:c])
+        # slice on the host: a device slice compiles once per tail length
+        return np.asarray(y)[:c]
 
 
 # ---------------------------------------------------------------------------

@@ -6,9 +6,10 @@ queries against the whole padded [K, M] reference bank — the offline
 mirror of the fused streaming tick (``stream.py``).  The grid is
 (query, reference-tile); each program runs its query through the full DP
 with the [BK, M] row slice AND the three warp-path correlation-moment
-slabs (sy, syy, sxy) pinned in VMEM, then reduces to the [BK] scores and
-endpoint distances **in-kernel** — the only HBM writes are the [J, K]
-score/distance tiles, never a row, a moment slab, or a [K, N, M] matrix.
+slabs (sy, syy, sxy) pinned in VMEM scratch, then reduces to the [BK]
+scores and endpoint distances **in-kernel** — the only HBM writes are the
+[J, K] score/distance tiles, never a row, a moment slab, or a [K, N, M]
+matrix.
 
 Row updates and moment carries are the streaming scored kernel's
 (``stream._stream_scored_kernel``): min-plus Hillis-Steele row scan,
@@ -31,107 +32,114 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .stream import _INF, _MOM_SHIFT, _fill_from_anchor, _minplus_scan2
+from .stream import (_INF, _MOM_SHIFT, _compiler_params, _pad_bank,
+                     _per_job, _scored_row, _smem_job_spec)
 
 __all__ = ["score_bank_offline_kernel", "score_bank_offline",
            "score_bank_offline_var_kernel",
            "score_bank_offline_var_approx_kernel"]
 
 
-def _score_kernel(xlen_ref, sx_ref, sxx_ref, x_ref, len_ref, bank_ref,
-                  score_ref, dist_ref, *, n: int, m: int,
-                  band: Optional[int]):
-    """One (query, reference-tile) program: full-query DP + moments in
-    VMEM, closed-end score reduction, [BK] outputs."""
-    from ...core.dtw import _corr_from_moments
-
-    xlen = xlen_ref[0]
-    x = x_ref[0]                                   # [N]
+def _endpoint_moments(xlen, x_ref, vx_ref, len_ref, bank_ref, row_scr,
+                      mom_scr, *, n: int, nch: int, band: Optional[int]):
+    """Run one query's full DP against the [BK, M] tile with the row and
+    its ``nch`` moment slabs in VMEM scratch, then read them at each
+    reference's closed endpoint column ``len - 1`` -> (dist, [sel]), all
+    [BK, 1] columns."""
     bank = bank_ref[...]                           # [BK, M]
-    bk = bank.shape[0]
-    lens = len_ref[...]                            # [BK]
-    jj = jax.lax.iota(jnp.int32, m)
+    lens = len_ref[...]                            # [BK, 1]
+    col = jax.lax.broadcasted_iota(jnp.int32, bank.shape, 1)
     yc = bank - _MOM_SHIFT
     yy = yc * yc
+    row_scr[...] = jnp.full(bank.shape, _INF, jnp.float32)
+    mom_scr[...] = jnp.zeros(mom_scr.shape, jnp.float32)
 
     def body(i, carry):
-        row, moms = carry                          # [BK, M], [3, BK, M]
-        d = jnp.abs(x[i] - bank)
-        if band is not None:
-            centers = (i * (lens - 1)) // jnp.maximum(xlen - 1, 1)
-            d = jnp.where(jnp.abs(jj[None, :] - centers[:, None]) <= band,
-                          d, _INF)
-        corner = jnp.where(i == 0, 0.0, _INF)
-        p_diag = jnp.concatenate(
-            [jnp.broadcast_to(corner, (bk, 1)).astype(row.dtype),
-             row[:, :-1]], axis=1)
-        p_vert = row
-        mn = jnp.minimum(p_vert, p_diag)
-        new = _minplus_scan2(d, mn + d, m)
-        if band is not None:
-            new = jnp.where(d >= _INF, _INF, new)
-        new = jnp.minimum(new, _INF)
-        p_horiz = jnp.concatenate(
-            [jnp.full((bk, 1), _INF, new.dtype), new[:, :-1]], axis=1)
-        sel_diag = p_diag <= jnp.minimum(p_vert, p_horiz)
-        sel_vert = jnp.logical_and(~sel_diag, p_vert <= p_horiz)
-        anch = jnp.logical_or(sel_diag, sel_vert)
-        m_diag = jnp.concatenate(
-            [jnp.zeros((3, bk, 1), moms.dtype), moms[:, :, :-1]], axis=2)
-        base = jnp.where(sel_diag[None], m_diag,
-                         jnp.where(sel_vert[None], moms, 0.0))
-        base = _fill_from_anchor(base, anch, m)
-        xm = x[i] - _MOM_SHIFT
-        new_moms = base + jnp.stack([yc, yy, xm * yc])
-        valid = i < xlen
-        return (jnp.where(valid, new, row),
-                jnp.where(valid, new_moms, moms))
+        @pl.when(i < xlen)
+        def _():
+            corner = jnp.where(i == 0, 0.0, _INF)
+            new, moms = _scored_row(
+                x_ref[0, 0, i], None if vx_ref is None else vx_ref[0, 0, i],
+                row_scr[...], [mom_scr[ch] for ch in range(nch)], bank, yc,
+                yy, col, corner, (lens, i, xlen), band)
+            row_scr[...] = new
+            for ch in range(nch):
+                mom_scr[ch] = moms[ch]
+        return carry
 
-    row0 = jnp.full((bk, m), _INF, jnp.float32)
-    moms0 = jnp.zeros((3, bk, m), jnp.float32)
-    row, moms = jax.lax.fori_loop(0, n, body, (row0, moms0))
+    jax.lax.fori_loop(0, n, body, 0)
+    onehot = col == lens - 1
 
-    # closed-end reduction: endpoint column len_k - 1 per reference.
-    onehot = jj[None, :] == (lens - 1)[:, None]              # [BK, M]
-    dist = jnp.sum(jnp.where(onehot, row, 0.0), axis=1)
-    msel = jnp.sum(jnp.where(onehot[None], moms, 0.0), axis=2)  # [3, BK]
-    nn = jnp.maximum(xlen, 1).astype(jnp.float32)
-    scores = _corr_from_moments(msel[0], msel[1], msel[2], sx_ref[0],
-                                sxx_ref[0], nn)
-    score_ref[0] = jnp.where(xlen > 0, scores, 0.0)
-    dist_ref[0] = dist
+    def pick(v):
+        return jnp.sum(jnp.where(onehot, v, 0.0), axis=1, keepdims=True)
+
+    return pick(row_scr[...]), [pick(mom_scr[ch]) for ch in range(nch)]
+
+
+def _endpoint_kernel(xlen_ref, x_ref, *refs, n: int, nch: int,
+                     band: Optional[int]):
+    """One (query, reference-tile) program: full-query DP + ``nch``
+    moment slabs in VMEM, then the closed-end reads -> one
+    [1, 1 + nch, BK, 1] output block (endpoint distance, then the
+    endpoint moments).  The score and probability tails run on these
+    in the caller's jit, so they stay the single jnp definitions in
+    ``core.dtw`` (``lax.erfc`` has no Mosaic lowering)."""
+    if nch > 3:
+        vx_ref, len_ref, bank_ref, out_ref, row_scr, mom_scr = refs
+    else:
+        vx_ref = None
+        len_ref, bank_ref, out_ref, row_scr, mom_scr = refs
+    dist, msel = _endpoint_moments(xlen_ref[0, 0, 0], x_ref, vx_ref,
+                                   len_ref, bank_ref, row_scr, mom_scr,
+                                   n=n, nch=nch, band=band)
+    for ch, v in enumerate([dist] + msel):
+        out_ref[0, ch] = v
+
+
+def _offline_call(xs, xlens, bank, lengths, *, nch: int, band, block_k: int,
+                  interpret: bool, xvars=None):
+    """The (query, reference-tile) grid shared by the offline scorers:
+    per-job scalars and queries in SMEM, the bank tile and its [BK, 1]
+    lengths in VMEM -> (dists [J, K], endpoint moments [nch, J, K])."""
+    j, n = xs.shape
+    k, m = bank.shape
+    queries = [xs] if xvars is None else [xs, xvars]
+    kernel = functools.partial(_endpoint_kernel, n=n, nch=nch, band=band)
+    out = pl.pallas_call(
+        kernel,
+        grid=(j, k // block_k),
+        in_specs=[_smem_job_spec(1)] + [_smem_job_spec(n)] * len(queries)
+        + [
+            pl.BlockSpec((block_k, 1), lambda ji, ki: (ki, 0)),  # lengths
+            pl.BlockSpec((block_k, m), lambda ji, ki: (ki, 0)),  # bank
+        ],
+        out_specs=pl.BlockSpec((1, 1 + nch, block_k, 1),
+                               lambda ji, ki: (ji, 0, ki, 0)),
+        out_shape=jax.ShapeDtypeStruct((j, 1 + nch, k, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_k, m), jnp.float32),
+                        pltpu.VMEM((nch, block_k, m), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name=f"dtw_score_offline_{nch}ch",
+    )(_per_job(xlens), *map(_per_job, queries), lengths[:, None], bank)
+    out = out[..., 0].transpose(1, 0, 2)                   # [1+nch, J, K]
+    return out[0], out[1:]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("band", "block_k", "interpret"))
 def _score_call(xs, xlens, bank, lengths, sx, sxx, band: Optional[int],
                 block_k: int, interpret: bool):
-    j, n = xs.shape
-    k, m = bank.shape
-    kernel = functools.partial(_score_kernel, n=n, m=m, band=band)
-    scores, dists = pl.pallas_call(
-        kernel,
-        grid=(j, k // block_k),
-        in_specs=[
-            pl.BlockSpec((1,), lambda ji, ki: (ji,)),          # xlen
-            pl.BlockSpec((1,), lambda ji, ki: (ji,)),          # sx
-            pl.BlockSpec((1,), lambda ji, ki: (ji,)),          # sxx
-            pl.BlockSpec((1, n), lambda ji, ki: (ji, 0)),      # query
-            pl.BlockSpec((block_k,), lambda ji, ki: (ki,)),    # lengths
-            pl.BlockSpec((block_k, m), lambda ji, ki: (ki, 0)),  # bank
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k), lambda ji, ki: (ji, ki)),
-            pl.BlockSpec((1, block_k), lambda ji, ki: (ji, ki)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((j, k), jnp.float32),
-            jax.ShapeDtypeStruct((j, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xlens, sx, sxx, xs, lengths, bank)
-    return scores, dists
+    from ...core.dtw import _corr_from_moments
+
+    dists, msel = _offline_call(xs, xlens, bank, lengths, nch=3, band=band,
+                                block_k=block_k, interpret=interpret)
+    nn = jnp.maximum(xlens, 1).astype(jnp.float32)[:, None]
+    scores = _corr_from_moments(msel[0], msel[1], msel[2], sx[:, None],
+                                sxx[:, None], nn)
+    return jnp.where(xlens[:, None] > 0, scores, 0.0), dists
 
 
 def score_bank_offline_kernel(xs, xlens, bank, lengths, sx, sxx,
@@ -144,23 +152,18 @@ def score_bank_offline_kernel(xs, xlens, bank, lengths, sx, sxx,
     xs [J, N] f32 (padded; ``xlens`` [J] i32 true lengths); bank [K, M]
     f32 with lengths [K] i32; sx/sxx [J] f32 centered query folds
     (``core.dtw.query_moments``) -> (scores [J, K], dists [J, K]).  K is
-    padded up to a ``block_k`` multiple internally (padding rows never
-    influence real rows; their outputs are sliced away).
+    padded up to a ``block_k`` multiple and M to whole lane groups
+    internally (padding never influences real cells; its outputs are
+    sliced away).
     """
     xs = jnp.asarray(xs, jnp.float32)
-    bank = jnp.asarray(bank, jnp.float32)
     xlens = jnp.asarray(xlens, jnp.int32)
-    lengths = jnp.asarray(lengths, jnp.int32)
     sx = jnp.asarray(sx, jnp.float32)
     sxx = jnp.asarray(sxx, jnp.float32)
-    k, m = bank.shape
-    bk = min(block_k, k)
-    pad = (-k) % bk
-    if pad:
-        bank = jnp.concatenate(
-            [bank, jnp.zeros((pad, m), jnp.float32)], axis=0)
-        lengths = jnp.concatenate(
-            [lengths, jnp.ones((pad,), jnp.int32)], axis=0)
+    k = bank.shape[0]
+    bank, lengths, bk, _, _ = _pad_bank(jnp.asarray(bank, jnp.float32),
+                                        jnp.asarray(lengths, jnp.int32),
+                                        block_k)
     scores, dists = _score_call(xs, xlens, bank, lengths, sx, sxx, band,
                                 bk, interpret)
     return scores[:, :k], dists[:, :k]
@@ -176,15 +179,17 @@ def score_bank_offline(xs, xlens, bank, lengths, sx, sxx,
                                      band=band, interpret=interpret)
 
 
-def _score_var_kernel(xlen_ref, sx_ref, sxx_ref, vstats_ref, x_ref, vx_ref,
-                      len_ref, bank_ref, score_ref, prob_ref, dist_ref, *,
-                      n: int, m: int, band: Optional[int],
-                      threshold: float, approx: bool = False):
-    """Variance-carrying twin of :func:`_score_kernel`: six moment slabs
-    ([6, BK, M]: sy, syy, sxy, svy, svyy, svxy — each variance channel's
-    delta is ``v_i *`` the matching base delta) plus an in-kernel
-    probabilistic reduction (``core.dtw._prob_from_moments``, the single
-    shared probability tail) beside the point score.
+@functools.partial(jax.jit,
+                   static_argnames=("band", "threshold", "block_k",
+                                    "interpret", "approx"))
+def _score_var_call(xs, xvars, xlens, bank, lengths, sx, sxx, vstats,
+                    band: Optional[int], threshold: float, block_k: int,
+                    interpret: bool, approx: bool = False):
+    """Variance-carrying twin of :func:`_score_call`: six endpoint moment
+    slabs (sy, syy, sxy, svy, svyy, svxy — each variance channel's delta
+    is ``v_i *`` the matching base delta) feed the probabilistic tail
+    (``core.dtw._prob_from_moments``, the single shared probability
+    tail) beside the point score.
 
     ``approx=True`` is the calibration twin of the approx serving tick:
     FOUR slabs (only svy rides beside the base three) and the
@@ -193,114 +198,19 @@ def _score_var_kernel(xlen_ref, sx_ref, sxx_ref, vstats_ref, x_ref, vx_ref,
     from ...core.dtw import (_corr_from_moments, _prob_from_moments,
                              _prob_from_moments_approx)
 
-    nch = 4 if approx else 6
-
-    xlen = xlen_ref[0]
-    x = x_ref[0]                                   # [N]
-    xv = vx_ref[0]                                 # [N]
-    bank = bank_ref[...]                           # [BK, M]
-    bk = bank.shape[0]
-    lens = len_ref[...]                            # [BK]
-    jj = jax.lax.iota(jnp.int32, m)
-    yc = bank - _MOM_SHIFT
-    yy = yc * yc
-
-    def body(i, carry):
-        row, moms = carry                          # [BK, M], [6, BK, M]
-        d = jnp.abs(x[i] - bank)
-        if band is not None:
-            centers = (i * (lens - 1)) // jnp.maximum(xlen - 1, 1)
-            d = jnp.where(jnp.abs(jj[None, :] - centers[:, None]) <= band,
-                          d, _INF)
-        corner = jnp.where(i == 0, 0.0, _INF)
-        p_diag = jnp.concatenate(
-            [jnp.broadcast_to(corner, (bk, 1)).astype(row.dtype),
-             row[:, :-1]], axis=1)
-        p_vert = row
-        mn = jnp.minimum(p_vert, p_diag)
-        new = _minplus_scan2(d, mn + d, m)
-        if band is not None:
-            new = jnp.where(d >= _INF, _INF, new)
-        new = jnp.minimum(new, _INF)
-        p_horiz = jnp.concatenate(
-            [jnp.full((bk, 1), _INF, new.dtype), new[:, :-1]], axis=1)
-        sel_diag = p_diag <= jnp.minimum(p_vert, p_horiz)
-        sel_vert = jnp.logical_and(~sel_diag, p_vert <= p_horiz)
-        anch = jnp.logical_or(sel_diag, sel_vert)
-        m_diag = jnp.concatenate(
-            [jnp.zeros((nch, bk, 1), moms.dtype), moms[:, :, :-1]], axis=2)
-        base = jnp.where(sel_diag[None], m_diag,
-                         jnp.where(sel_vert[None], moms, 0.0))
-        base = _fill_from_anchor(base, anch, m)
-        xm = x[i] - _MOM_SHIFT
-        dm = jnp.stack([yc, yy, xm * yc])
-        new_moms = base + jnp.concatenate([dm, xv[i] * dm[:nch - 3]],
-                                          axis=0)
-        valid = i < xlen
-        return (jnp.where(valid, new, row),
-                jnp.where(valid, new_moms, moms))
-
-    row0 = jnp.full((bk, m), _INF, jnp.float32)
-    moms0 = jnp.zeros((nch, bk, m), jnp.float32)
-    row, moms = jax.lax.fori_loop(0, n, body, (row0, moms0))
-
-    onehot = jj[None, :] == (lens - 1)[:, None]              # [BK, M]
-    dist = jnp.sum(jnp.where(onehot, row, 0.0), axis=1)
-    msel = jnp.sum(jnp.where(onehot[None], moms, 0.0), axis=2)  # [nch, BK]
-    nn = jnp.maximum(xlen, 1).astype(jnp.float32)
-    scores = _corr_from_moments(msel[0], msel[1], msel[2], sx_ref[0],
-                                sxx_ref[0], nn)
-    if approx:
-        probs = _prob_from_moments_approx(
-            msel[0], msel[1], msel[2], msel[3],
-            sx_ref[0], sxx_ref[0], vstats_ref[0, 0], vstats_ref[0, 1],
-            vstats_ref[0, 2], nn, jnp.float32(threshold))
-    else:
-        probs = _prob_from_moments(
-            msel[0], msel[1], msel[2], msel[3], msel[4], msel[5],
-            sx_ref[0], sxx_ref[0], vstats_ref[0, 0], vstats_ref[0, 1],
-            vstats_ref[0, 2], nn, jnp.float32(threshold))
-    score_ref[0] = jnp.where(xlen > 0, scores, 0.0)
-    prob_ref[0] = jnp.where(xlen > 0, probs, 0.0)
-    dist_ref[0] = dist
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("band", "threshold", "block_k",
-                                    "interpret", "approx"))
-def _score_var_call(xs, xvars, xlens, bank, lengths, sx, sxx, vstats,
-                    band: Optional[int], threshold: float, block_k: int,
-                    interpret: bool, approx: bool = False):
-    j, n = xs.shape
-    k, m = bank.shape
-    kernel = functools.partial(_score_var_kernel, n=n, m=m, band=band,
-                               threshold=threshold, approx=approx)
-    scores, probs, dists = pl.pallas_call(
-        kernel,
-        grid=(j, k // block_k),
-        in_specs=[
-            pl.BlockSpec((1,), lambda ji, ki: (ji,)),          # xlen
-            pl.BlockSpec((1,), lambda ji, ki: (ji,)),          # sx
-            pl.BlockSpec((1,), lambda ji, ki: (ji,)),          # sxx
-            pl.BlockSpec((1, 3), lambda ji, ki: (ji, 0)),      # vstats
-            pl.BlockSpec((1, n), lambda ji, ki: (ji, 0)),      # query
-            pl.BlockSpec((1, n), lambda ji, ki: (ji, 0)),      # variances
-            pl.BlockSpec((block_k,), lambda ji, ki: (ki,)),    # lengths
-            pl.BlockSpec((block_k, m), lambda ji, ki: (ki, 0)),  # bank
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k), lambda ji, ki: (ji, ki)),
-            pl.BlockSpec((1, block_k), lambda ji, ki: (ji, ki)),
-            pl.BlockSpec((1, block_k), lambda ji, ki: (ji, ki)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((j, k), jnp.float32),
-            jax.ShapeDtypeStruct((j, k), jnp.float32),
-            jax.ShapeDtypeStruct((j, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xlens, sx, sxx, vstats, xs, xvars, lengths, bank)
-    return scores, probs, dists
+    dists, msel = _offline_call(xs, xlens, bank, lengths,
+                                nch=4 if approx else 6, band=band,
+                                block_k=block_k, interpret=interpret,
+                                xvars=xvars)
+    nn = jnp.maximum(xlens, 1).astype(jnp.float32)[:, None]
+    sx, sxx = sx[:, None], sxx[:, None]
+    scores = _corr_from_moments(msel[0], msel[1], msel[2], sx, sxx, nn)
+    tail = _prob_from_moments_approx if approx else _prob_from_moments
+    probs = tail(*msel, sx, sxx, vstats[:, 0:1], vstats[:, 1:2],
+                 vstats[:, 2:3], nn, jnp.float32(threshold))
+    live = xlens[:, None] > 0
+    return (jnp.where(live, scores, 0.0), jnp.where(live, probs, 0.0),
+            dists)
 
 
 def score_bank_offline_var_kernel(xs, xvars, xlens, bank, lengths, sx,
@@ -322,20 +232,14 @@ def score_bank_offline_var_kernel(xs, xvars, xlens, bank, lengths, sx,
     """
     xs = jnp.asarray(xs, jnp.float32)
     xvars = jnp.asarray(xvars, jnp.float32)
-    bank = jnp.asarray(bank, jnp.float32)
     xlens = jnp.asarray(xlens, jnp.int32)
-    lengths = jnp.asarray(lengths, jnp.int32)
     sx = jnp.asarray(sx, jnp.float32)
     sxx = jnp.asarray(sxx, jnp.float32)
     vstats = jnp.asarray(vstats, jnp.float32)
-    k, m = bank.shape
-    bk = min(block_k, k)
-    pad = (-k) % bk
-    if pad:
-        bank = jnp.concatenate(
-            [bank, jnp.zeros((pad, m), jnp.float32)], axis=0)
-        lengths = jnp.concatenate(
-            [lengths, jnp.ones((pad,), jnp.int32)], axis=0)
+    k = bank.shape[0]
+    bank, lengths, bk, _, _ = _pad_bank(jnp.asarray(bank, jnp.float32),
+                                        jnp.asarray(lengths, jnp.int32),
+                                        block_k)
     scores, probs, dists = _score_var_call(
         xs, xvars, xlens, bank, lengths, sx, sxx, vstats, band,
         float(threshold), bk, interpret, approx=approx)

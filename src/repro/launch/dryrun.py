@@ -237,14 +237,9 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
         if v is not None:
             mem_info[attr] = int(v)
 
-    # jax < 0.5 returns a one-element list of dicts (one per program);
-    # newer jax returns the dict directly.
-    raw_cost = compiled.cost_analysis() or {}
-    if isinstance(raw_cost, (list, tuple)):
-        raw_cost = raw_cost[0] if raw_cost else {}
-    cost = dict(raw_cost)
     cost = {k: (float(v) if np.isscalar(v) else float(np.sum(v)))
-            for k, v in cost.items() if not isinstance(v, (dict, list))}
+            for k, v in (compiled.cost_analysis() or {}).items()
+            if not isinstance(v, (dict, list))}
 
     hlo = compiled.as_text()
     mc = parse_module(hlo)          # trip-count-aware per-device cost model

@@ -136,14 +136,36 @@ def embed_init(key, cfg: ModelConfig) -> Dict:
     return p
 
 
+def _take_rows(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` along axis 0 on any mesh.  Under explicit-sharding
+    axes the gather needs its output sharding spelled out: it keeps the
+    indices' sharding, then the table's trailing-dim sharding (the
+    compiler brings in the collectives a vocab-sharded table needs)."""
+    mesh = jax.typeof(table).sharding.mesh
+    if jax.sharding.AxisType.Explicit not in mesh.axis_types:
+        return jnp.take(table, idx, axis=0)
+    ispec = tuple(jax.typeof(idx).sharding.spec)
+    tspec = tuple(jax.typeof(table).sharding.spec)
+    ispec += (None,) * (idx.ndim - len(ispec))
+    tspec += (None,) * (table.ndim - len(tspec))
+    used = {a for s in ispec if s is not None
+            for a in (s if isinstance(s, tuple) else (s,))}
+    trail = tuple(None if s is not None and set(
+        s if isinstance(s, tuple) else (s,)) & used else s
+        for s in tspec[1:])
+    out = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(*ispec, *trail))
+    return table.at[idx].get(out_sharding=out)
+
+
 def embed(p, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     """tokens: [B, S] or [B, S, num_codebooks] -> [B, S, d] (codebooks sum)."""
     table = p["table"].astype(Dtypes.compute(cfg))
     if tokens.ndim == 3:                      # musicgen: per-codebook offset
         nb = tokens.shape[-1]
         offs = jnp.arange(nb, dtype=tokens.dtype) * cfg.vocab_size
-        return jnp.take(table, tokens + offs, axis=0).sum(axis=2)
-    return jnp.take(table, tokens, axis=0)
+        return _take_rows(table, tokens + offs).sum(axis=2)
+    return _take_rows(table, tokens)
 
 
 @jax.named_scope("unembed")

@@ -31,9 +31,6 @@ from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
 from .layers import Dtypes, dense_init
-# version-tolerant shard_map shim, shared with the sharded streaming
-# matcher tick (serve.tuning)
-from ..sharding.compat import shard_map as _shard_map
 
 __all__ = ["moe_init", "moe_apply"]
 
@@ -177,8 +174,8 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig,
 
         out_spec = P(data_axes, None, None) if B % dp == 0 \
             else P(None, None, None)
-        out, aux = _shard_map(
-            mapped_tp, mesh=mesh,
+        out, aux = jax.shard_map(
+            mapped_tp, mesh=mesh, check_vma=False,
             in_specs=(P(None, None, None), P(None, None),
                       P(model_axis, None, data_axes),
                       P(model_axis, None, data_axes),
@@ -197,8 +194,8 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig,
             out, aux = run(x_loc, rw, wg, wu, wd, e_off, model_axis)
             return out, jax.lax.pmean(aux, all_axes)
 
-        out, aux = _shard_map(
-            mapped, mesh=mesh,
+        out, aux = jax.shard_map(
+            mapped, mesh=mesh, check_vma=False,
             in_specs=(P(data_axes, None, None), P(None, None),
                       P(model_axis, None, None), P(model_axis, None, None),
                       P(model_axis, None, None)),

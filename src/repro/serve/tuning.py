@@ -42,7 +42,7 @@ Tick engine (device-resident tick)
   (``kernels.dtw.stream``).
 * ``mesh=`` shards the bank: a 1-D device mesh partitions the ``[M, K]``
   reference bank and every ``[.., K]`` state slab over its single axis
-  via ``sharding.compat.shard_map``.  The sharded tick is bit-identical
+  via ``jax.shard_map``.  The sharded tick is bit-identical
   to the unsharded one and remains ONE dispatch.  :meth:`rescale`
   re-homes the state onto a different mesh mid-flight (or back to a
   single device) — the hook a ``runtime.fault.ElasticController``
@@ -160,24 +160,25 @@ from ..core.similarity import MATCH_THRESHOLD
 from ..core.tuner import TuneDecision, _RowBuffer
 from ..runtime.chaos import FaultPlan, InjectedDispatchError
 from ..runtime.retry import CircuitBreaker, RetryPolicy, call_with_retry
-from ..sharding.compat import shard_map as _shard_map
 from .ingest import IngestFront, PoisonedSampleError, TraceLog
 from .overload import (RUNGS, AdmissionController, AdmissionPolicy,
                        AdmissionShedError, OverloadConfig,
                        OverloadController)
 from .scheduler import SlotScheduler
 
-
-def _transient_errors() -> tuple:
-    """Exception classes a dispatch retry treats as transient: injected
-    chaos faults plus the runtime's real device-side failure class."""
-    errs = [InjectedDispatchError]
-    rt = getattr(jax.errors, "JaxRuntimeError", None)
-    if rt is not None:
-        errs.append(rt)
-    return tuple(errs)
-
 __all__ = ["InFlightJob", "TuningService", "MultiTenantTuningService"]
+
+
+def _auto_axes(mesh: Optional[jax.sharding.Mesh]
+               ) -> Optional[jax.sharding.Mesh]:
+    """``mesh`` with every axis ``Auto`` (``jax.make_mesh`` defaults to
+    ``Explicit``): the state re-pack gathers and the tick's shard_map
+    then need no sharding annotations, whatever mesh the caller built."""
+    if mesh is None:
+        return None
+    return jax.sharding.Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
 
 
 @dataclasses.dataclass
@@ -351,6 +352,7 @@ class TuningService:
         self.slots = slots
         self.denoise = denoise
         self.score_in_flight = score_in_flight
+        mesh = _auto_axes(mesh)
         self.mesh = mesh
         if prefilter_top is not None and prefilter_top < 1:
             raise ValueError("prefilter_top must be >= 1 (or None)")
@@ -372,7 +374,9 @@ class TuningService:
         self.retry_policy = retry_policy
         self.chaos = chaos
         self.breaker = breaker
-        self._transient = _transient_errors()
+        # dispatch signatures that have executed at least once (see
+        # _dispatch_resilient: only these may retry a runtime error).
+        self._executed: set = set()
         # overload control plane: the degradation-ladder controller and
         # the admission gate (see serve.overload's runbook docstring).
         # Dict forms are accepted so a snapshot's JSON config rebuilds
@@ -898,8 +902,8 @@ class TuningService:
                     vchunks=vchunks, vstats=vstats,
                     threshold=threshold)
             P = jax.sharding.PartitionSpec
-            return jax.jit(_shard_map(
-                inner_var, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                inner_var, mesh=self.mesh, check_vma=False,
                 in_specs=(P(None, None, axis),
                           P(None, None, None, axis),
                           P(), P(), P(), P(None, None), P(None, axis),
@@ -935,8 +939,8 @@ class TuningService:
                     vchunks=vchunks, vstats=vstats,
                     threshold=threshold)
             P = jax.sharding.PartitionSpec
-            return jax.jit(_shard_map(
-                inner_approx, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                inner_approx, mesh=self.mesh, check_vma=False,
                 in_specs=(P(None, None, axis),
                           P(None, None, None, axis),
                           P(), P(), P(), P(None, None), P(None, axis),
@@ -962,8 +966,8 @@ class TuningService:
                     rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
                     nvalid, qlens, band=band, score=True)
             P = jax.sharding.PartitionSpec
-            return jax.jit(_shard_map(
-                inner, mesh=self.mesh,
+            return jax.jit(jax.shard_map(
+                inner, mesh=self.mesh, check_vma=False,
                 in_specs=(P(None, None, axis), P(None, None, None, axis),
                           P(), P(), P(), P(None, axis), P(axis), P(), P(),
                           P()),
@@ -985,27 +989,35 @@ class TuningService:
             return _dtw.bank_extend_tick(rows, ns, bank_t, lengths, chunks,
                                          nvalid, qlens, band=band)
         P = jax.sharding.PartitionSpec
-        return jax.jit(_shard_map(
-            inner, mesh=self.mesh,
+        return jax.jit(jax.shard_map(
+            inner, mesh=self.mesh, check_vma=False,
             in_specs=(P(None, None, axis), P(), P(None, axis), P(axis),
                       P(), P(), P()),
             out_specs=(P(None, None, axis), P()))), None
 
     # -- dispatch resilience --------------------------------------------------
-    def _dispatch_resilient(self, primary, fallback, kind: str):
-        """Run one device dispatch through the retry/backoff wrapper.
+    def _dispatch_resilient(self, fn, fallback, args, kind: str):
+        """Run one device dispatch, ``fn(*args)``, through the
+        retry/backoff wrapper.
 
-        ``primary``/``fallback`` are zero-arg thunks (the fallback is the
-        jnp wavefront twin on unsharded paths, None when the primary
-        already is jnp).  Transient device errors — and chaos-injected
-        ones, consulted per *attempt* so a fault burst spans retries —
-        are retried per ``self.retry_policy``; after exhaustion the
-        fallback serves the tick once and the service surfaces
-        ``degraded``.  Results are bit-identical either way (the twin is
-        pinned against the kernel), so injected faults move latency and
-        counters, never scores or decisions.  With neither a policy nor
-        a chaos plan nor a breaker armed this is a plain call — the hot
-        path pays one attribute test.
+        ``fallback`` is the jnp wavefront twin on unsharded paths (None
+        when ``fn`` already is jnp), called with the same ``args``.
+        Transient device errors — and chaos-injected ones, consulted per
+        *attempt* so a fault burst spans retries — are retried per
+        ``self.retry_policy``; after exhaustion the fallback serves the
+        tick once and the service surfaces ``degraded``.  Results are
+        bit-identical either way (the twin is pinned against the kernel),
+        so injected faults move latency and counters, never scores or
+        decisions.  With neither a policy nor a chaos plan nor a breaker
+        armed this is a plain call — the hot path pays one attribute
+        test.
+
+        A runtime error counts as transient only for a dispatch signature
+        (``kind`` plus argument shapes) that has already executed: the
+        first call of a signature also lowers and compiles it, and a
+        lowering or compile failure — a kernel the chip's compiler
+        refuses, a program over the device's memory — must raise, never
+        be retried or hidden behind the jnp twin.
 
         A :class:`runtime.retry.CircuitBreaker` (``breaker=``) wraps the
         whole ladder: while OPEN the fallback serves directly (no
@@ -1016,35 +1028,41 @@ class TuningService:
         chaos = self.chaos
         breaker = self.breaker if fallback is not None else None
         if chaos is None and self.retry_policy is None and breaker is None:
-            return primary()
+            return fn(*args)
+        sig = (kind,) + tuple((a.shape, a.dtype) for a in args
+                              if a is not None)
+        transient = (InjectedDispatchError, jax.errors.JaxRuntimeError) \
+            if sig in self._executed else (InjectedDispatchError,)
 
         def attempt():
             if chaos is not None:
                 chaos.on_dispatch(kind)
-            return primary()
+            result = fn(*args)
+            self._executed.add(sig)
+            return result
 
         if breaker is not None:
             route = breaker.before_dispatch()
             if route == "fallback":
                 self.degraded_dispatch_count += 1
                 self.last_tick_degraded = True
-                return fallback()
+                return fallback(*args)
             if route == "probe":
                 try:
                     result = attempt()       # one un-retried attempt
-                except self._transient:
+                except transient:
                     breaker.record_failure()
                     self.degraded_dispatch_count += 1
                     self.last_tick_degraded = True
-                    return fallback()
+                    return fallback(*args)
                 breaker.record_success()
                 return result
 
         policy = self.retry_policy or RetryPolicy(max_retries=0,
                                                   base_delay=0.0)
         result, report = call_with_retry(
-            attempt, policy=policy, transient=self._transient,
-            fallback=fallback)
+            attempt, policy=policy, transient=transient,
+            fallback=None if fallback is None else lambda: fallback(*args))
         self.retry_count += report["retries"]
         if report["degraded"]:
             self.degraded_dispatch_count += 1
@@ -1078,6 +1096,7 @@ class TuningService:
         a prefilter re-pack uses, so scores and decisions are unchanged
         (sharding is exact); the tick callable recompiles for the new
         mesh."""
+        mesh = _auto_axes(mesh)
         ndev, axis = 1, None
         if mesh is not None:
             if len(mesh.axis_names) != 1:
@@ -1094,6 +1113,7 @@ class TuningService:
             self._vstats = self._put(np.asarray(self._vstats), (None, None))
         self._pack_device_state(self._packed_idx, rows, moms)
         self._tick_fns = {}            # per-mode callables are mesh-bound
+        self._executed.clear()
         self._tick_fn, self._tick_fallback = \
             self._tick_fn_for(self._base_mode())
         self.rescale_count += 1
@@ -1342,9 +1362,7 @@ class TuningService:
                     jnp.asarray(nvalid), jnp.asarray(self._qlens))
             (self._rows, self._moms, self._ns, self._sx, self._sxx,
              scores, self._vstats, probs) = self._dispatch_resilient(
-                lambda: tick_fn(*args),
-                (lambda: tick_fb(*args))
-                if tick_fb is not None else None, "tick")
+                tick_fn, tick_fb, args, "tick")
             sims_all = np.full((self._s_cap, self._k), -np.inf)
             sims_all[:, self._packed_idx] = \
                 np.asarray(scores, np.float64)[:, :k_live]
@@ -1367,9 +1385,7 @@ class TuningService:
                     jnp.asarray(nvalid), jnp.asarray(self._qlens))
             (self._rows, moms_out, self._ns, self._sx, self._sxx,
              scores, self._vstats, probs) = self._dispatch_resilient(
-                lambda: tick_fn(*args),
-                (lambda: tick_fb(*args))
-                if tick_fb is not None else None, "tick")
+                tick_fn, tick_fb, args, "tick")
             if base == "prob":
                 self._moms = self._put(
                     jnp.concatenate([moms_out, self._moms[4:]], axis=0),
@@ -1396,9 +1412,7 @@ class TuningService:
                     jnp.asarray(nvalid), jnp.asarray(self._qlens))
             (self._rows, moms_out, self._ns, self._sx, self._sxx,
              scores) = self._dispatch_resilient(
-                lambda: tick_fn(*args),
-                (lambda: tick_fb(*args))
-                if tick_fb is not None else None, "tick")
+                tick_fn, tick_fb, args, "tick")
             if base in ("prob", "approx_prob"):
                 self._moms = self._put(
                     jnp.concatenate([moms_out, self._moms[3:]], axis=0),
@@ -1416,9 +1430,7 @@ class TuningService:
                     jnp.asarray(chunks), jnp.asarray(nvalid),
                     jnp.asarray(self._qlens))
             self._rows, self._ns = self._dispatch_resilient(
-                lambda: tick_fn(*args),
-                (lambda: tick_fb(*args))
-                if tick_fb is not None else None, "tick")
+                tick_fn, tick_fb, args, "tick")
         self.dispatch_count += 1
 
         if mode != base:
@@ -1588,24 +1600,20 @@ class TuningService:
                 v = variances[i]
                 if v is not None and v.shape[0] == q.shape[0]:
                     xv[r, : q.shape[0]] = v
+        kw = dict(xvars=xv, threshold=float(self.threshold)) \
+            if prob_mode else {}
+
+        def call(xs, xl, sx, sxx, use_kernel=None):
+            return _dtw.dtw_score_bank_many(
+                xs, self.bank.series, self.bank.lengths, xlens=xl,
+                band=self.band, sx=sx, sxx=sxx,
+                plan=self.bank.score_plan(), use_kernel=use_kernel, **kw)
+        res = self._dispatch_resilient(
+            call, functools.partial(call, use_kernel=False),
+            (xs, xl, sx, sxx), "verdict")
+        scores, probs = res if prob_mode else (res, None)
         if prob_mode:
-            def call(use_kernel=None):
-                return _dtw.dtw_score_bank_many(
-                    xs, self.bank.series, self.bank.lengths, xlens=xl,
-                    band=self.band, sx=sx, sxx=sxx, xvars=xv,
-                    threshold=float(self.threshold),
-                    plan=self.bank.score_plan(), use_kernel=use_kernel)
-            scores, probs = self._dispatch_resilient(
-                call, lambda: call(use_kernel=False), "verdict")
             probs = np.asarray(probs, np.float64)
-        else:
-            def call(use_kernel=None):
-                return _dtw.dtw_score_bank_many(
-                    xs, self.bank.series, self.bank.lengths, xlens=xl,
-                    band=self.band, sx=sx, sxx=sxx,
-                    plan=self.bank.score_plan(), use_kernel=use_kernel)
-            scores, probs = self._dispatch_resilient(
-                call, lambda: call(use_kernel=False), "verdict"), None
         scores = np.asarray(scores, np.float64)
         self.offline_dispatch_count += 1
         for r, i in enumerate(live):

@@ -1,7 +1,6 @@
-from .compat import shard_map
 from .rules import (ExecConfig, param_specs, cache_specs, batch_specs,
                     opt_state_specs, make_shard_fn, logical_batch_axes)
 
-__all__ = ["shard_map", "ExecConfig", "param_specs", "cache_specs",
+__all__ = ["ExecConfig", "param_specs", "cache_specs",
            "batch_specs", "opt_state_specs", "make_shard_fn",
            "logical_batch_axes"]

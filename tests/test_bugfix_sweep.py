@@ -17,7 +17,7 @@ tentpole:
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve.ingest import BackpressureError, BoundedBuffer
 

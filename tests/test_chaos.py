@@ -310,3 +310,56 @@ def test_backwards_beat_clock_cannot_rewind_liveness():
     assert svc.sweep_stalled(105.0) == {}, \
         "backwards beat rewound the heartbeat and got the job evicted"
     assert svc.n_active == 1
+
+
+class _FailingTick:
+    """Wraps a tick callable; raises a runtime error on the listed calls
+    (1-based), the way a refused kernel or a lost device surfaces."""
+
+    def __init__(self, fn, fail_on):
+        self.fn, self.fail_on, self.calls = fn, set(fail_on), 0
+
+    def __call__(self, *args):
+        self.calls += 1
+        if self.calls in self.fail_on:
+            import jax
+            raise jax.errors.JaxRuntimeError(
+                "INTERNAL: Mosaic failed to compile TPU kernel")
+        return self.fn(*args)
+
+
+def _armed_service(fail_on):
+    from repro.runtime.retry import CircuitBreaker
+    svc = TuningService(_bank(), slots=4,
+                        retry_policy=_policy(max_retries=3),
+                        breaker=CircuitBreaker())
+    mode = svc._base_mode()
+    fn, fb = svc._tick_fn_for(mode)
+    svc._tick_fns[mode] = (_FailingTick(fn, fail_on), fb)
+    svc.submit("j0", 48)
+    return svc
+
+
+def test_first_dispatch_runtime_error_raises_never_falls_back():
+    """A runtime error on a signature's FIRST dispatch is a lowering or
+    compile failure: it raises through an armed retry policy and
+    breaker — never retried, never served by the jnp twin."""
+    import jax
+    svc = _armed_service(fail_on={1})
+    svc.push("j0", np.linspace(0, 1, 8, dtype=np.float32))
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        svc.tick()
+    assert svc.retry_count == 0
+    assert svc.degraded_dispatch_count == 0
+
+
+def test_runtime_error_after_first_execution_is_retried():
+    """Once a signature has executed, a runtime error is an execution
+    fault: the retry re-runs it and the tick is served undegraded."""
+    svc = _armed_service(fail_on={2})
+    for t in range(3):
+        svc.push("j0", np.linspace(0, 1, 8, dtype=np.float32) + t)
+        svc.tick()
+    assert svc.retry_count == 1
+    assert svc.degraded_dispatch_count == 0
+    assert svc.dispatch_count == 3

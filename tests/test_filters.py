@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 import scipy.signal as ss
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import filters
 

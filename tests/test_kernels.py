@@ -385,7 +385,7 @@ def test_stream_var_chunking_invariance():
     """Any chunking of the variance-mode stream reproduces the one-shot
     solve BITWISE on dyadic grids (samples and variances both dyadic):
     rows, all six moment slabs, variance folds, scores and probs."""
-    from _hypothesis_compat import given, settings, st
+    from hypothesis import given, settings, strategies as st
     import jax.numpy as jnp
     from repro.core import dtw as _dtw
 
@@ -635,7 +635,7 @@ def test_stream_var_approx_chunking_invariance():
     """Any chunking of the approx-mode stream reproduces the one-shot
     solve BITWISE on dyadic grids: rows, all four moment slabs,
     variance folds, scores and approx probs."""
-    from _hypothesis_compat import given, settings, st
+    from hypothesis import given, settings, strategies as st
     import jax.numpy as jnp
     from repro.core import dtw as _dtw
 

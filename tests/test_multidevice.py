@@ -31,7 +31,8 @@ SCRIPT = textwrap.dedent("""
 
     out_local, aux_local = moe_apply(p, x, cfg)                # 1-device path
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_auto_mesh
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
     out_ep, aux_ep = jax.jit(
         lambda p, x: moe_apply(p, x, cfg, mesh=mesh, data_axes=("data",))
@@ -85,6 +86,18 @@ SCRIPT = textwrap.dedent("""
     md = max(jax.tree.leaves(diffs))
     assert md < 1e-4, f"param mismatch {md}"
     print("SHARDED_STEP_OK", dl, md)
+
+    # the embedding gather also runs on jax.make_mesh's default
+    # Explicit-axes mesh: vocab-sharded table, batch-sharded tokens.
+    from repro.models.layers import embed
+    emesh = jax.make_mesh((2, 4), ("data", "model"))
+    table = jax.random.normal(jax.random.PRNGKey(2), (128, 64))
+    want = np.asarray(embed({"table": table}, batch["tokens"], cfg2))
+    got = jax.jit(lambda p, t: embed(p, t, cfg2))(
+        {"table": jax.device_put(table, NamedSharding(emesh, P("model", None)))},
+        jax.device_put(batch["tokens"], NamedSharding(emesh, P("data", None))))
+    assert np.array_equal(np.asarray(got), want), "explicit-mesh embed"
+    print("EXPLICIT_EMBED_OK")
 """)
 
 
@@ -97,3 +110,4 @@ def test_multidevice_semantics():
         text=True, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "MOE_EP_OK" in r.stdout and "SHARDED_STEP_OK" in r.stdout
+    assert "EXPLICIT_EMBED_OK" in r.stdout

@@ -17,7 +17,7 @@ import warnings
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.database import pack_series
 from repro.runtime.chaos import truncate_file
 from repro.serve.ingest import TraceLog

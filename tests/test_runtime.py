@@ -81,3 +81,54 @@ def test_elastic_grows_back():
     ec = ElasticController(model_parallel=16)
     d = ec.decide(8, alive=list(range(16)))
     assert d.should_rescale and d.new_data_parallel == 16
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache (entry points call enable_compile_cache)
+# ---------------------------------------------------------------------------
+
+_CACHE_SCRIPT = """
+import sys
+sys.path.insert(0, "src")
+import jax, jax.numpy as jnp
+from repro.runtime.compile_cache import enable_compile_cache
+print("CACHE_DIR", enable_compile_cache())
+print("CONFIG_DIR", jax.config.jax_compilation_cache_dir)
+jax.jit(lambda x: jnp.sin(x) * 3.0)(jnp.arange(7.0)).block_until_ready()
+"""
+
+
+def _run_cache_script(env_dir):
+    import os
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT], cwd=root,
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stdout + r.stderr
+    return dict(line.split(" ", 1) for line in r.stdout.splitlines()
+                if line.startswith(("CACHE_DIR", "CONFIG_DIR")))
+
+
+def test_compile_cache_uses_env_dir_when_set(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the helper leaves it alone and the
+    compiled program lands there."""
+    got = _run_cache_script(str(tmp_path))
+    assert got["CACHE_DIR"] == got["CONFIG_DIR"] == str(tmp_path)
+    assert any(tmp_path.iterdir()), "no cache entry written"
+
+
+def test_compile_cache_defaults_to_repo_dir():
+    """Unset: the fixed <repo>/.jax_cache, never a temp or pid path."""
+    import os
+    from repro.runtime import compile_cache
+    got = _run_cache_script(None)
+    want = os.path.join(compile_cache._REPO_ROOT, ".jax_cache")
+    assert os.path.realpath(got["CACHE_DIR"]) == os.path.realpath(want)
+    assert got["CONFIG_DIR"] == got["CACHE_DIR"]

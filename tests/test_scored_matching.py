@@ -21,7 +21,7 @@ import os
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import dtw, similarity, similarity_bank
 from repro.core.database import pack_series

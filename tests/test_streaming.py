@@ -15,7 +15,7 @@ The tentpole invariants:
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import mrsim
 from repro.core import (OnlineMatcher, StreamingFilter, dtw, similarity_bank)

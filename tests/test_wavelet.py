@@ -1,7 +1,7 @@
 """Haar wavelet compression (paper §5 future plan)."""
 import numpy as np
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import wavelet
 
