@@ -29,6 +29,11 @@ here, so the tick engine only ever sees clean, causally-filtered chunks.
   stalled job's slot (``TuningService.sweep_stalled``) and flag jobs
   whose monitoring agent has degraded.
 
+Each filter call in a drain is one device round trip; it runs inside a
+``tuner.filter`` profiler span (``jax.profiler.TraceAnnotation``, free
+while no trace is being captured) and is counted in
+:attr:`IngestFront.filter_count`.
+
 The filter is applied at *drain* time on the concatenated chunk — the
 same call structure the monolithic service used — so layering changes
 no numerics: a drained chunk is bit-identical to what the old
@@ -43,6 +48,7 @@ import warnings
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.database import atomic_write_json, atomic_write_npz
 from ..core.filters import StreamingFilter
@@ -497,6 +503,9 @@ class IngestFront:
         self.stragglers = StragglerDetector(factor=straggler_factor)
         self._jobs: Dict[str, _JobIngest] = {}
         self._last_push: Dict[str, float] = {}
+        #: causal-filter calls made by :meth:`drain` (one device round
+        #: trip each)
+        self.filter_count = 0
 
     def register(self, job_id: str) -> None:
         self._jobs[job_id] = _JobIngest(
@@ -574,7 +583,12 @@ class IngestFront:
         raw = ji.buffer.drain()
         if raw is None:
             return (None, None) if with_variance else None
-        chunk = ji.filt(raw) if ji.filt is not None else raw
+        if ji.filt is not None:
+            with TraceAnnotation("tuner.filter", samples=raw.shape[0]):
+                chunk = ji.filt(raw)
+            self.filter_count += 1
+        else:
+            chunk = raw
         if ji.vbuffer is not None:
             vchunk = ji.vbuffer.drain()
             if not with_variance:

@@ -140,6 +140,35 @@ machinery can touch the numbers.
 Chebyshev filter (``filters.StreamingFilter``) before matching.
 Reference banks are expected to be stored pre-processed (as
 ``AutoTuner.profile`` does).
+
+Profiler spans
+--------------
+The tick and verdict paths open ``jax.profiler.TraceAnnotation`` spans
+(``tuner.*``), which cost about a microsecond each and record nothing
+unless a profiler trace is being captured.  Captured with
+``jax.profiler.trace(<dir>)`` around a live service, they sit on the
+profiler's host timeline on the same clock as the device's ops
+(TensorBoard's profile plugin or Perfetto show both), so a stretch in
+which the device waited can be named by what the host was doing.  Each
+span nests in its parent; its arguments are counts taken at the span's
+boundary:
+
+* ``tuner.tick`` (``tick``: the tick's id, ``internal``: 1 for the
+  drain tick of a finish) over the region ``last_tick_latency`` times,
+  holding in order ``tuner.drain`` (``jobs``, ``samples``, ``filtered``:
+  the due-job loop with one ``tuner.filter`` span, ``samples``, per
+  causal-filter call of ``serve.ingest``), ``tuner.repack``
+  (``slot_repacks``, ``k_repacks``: this tick's), ``tuner.chunks``
+  (``chunk``, ``slots``), ``tuner.dispatch`` (``mode``, ``k_live``: the
+  uploads and the tick dispatch), ``tuner.pull`` (the ``[S, K]`` pull and
+  its scatter to bank columns), ``tuner.decide`` (``jobs``,
+  ``decisions``: the decision rule) and ``tuner.prefilter``.
+* ``tuner.finish_many`` (``jobs``), from :meth:`finish_many` and from
+  each batched drain of the :meth:`finish_later` queue, holding the drain
+  tick (a nested ``tuner.tick`` with ``internal=1``), ``tuner.retire``
+  (``jobs``), ``tuner.verdict.pack`` (``jobs``, ``padded``, ``npad``),
+  ``tuner.verdict.dispatch``, ``tuner.verdict.pull`` and
+  ``tuner.verdict.render`` (``jobs``).
 """
 
 from __future__ import annotations
@@ -152,6 +181,7 @@ from typing import Dict, List, Mapping, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import dtw as _dtw
 from ..core import wavelet as _wavelet
@@ -1282,7 +1312,9 @@ class TuningService:
             if _observe and self._overload.rung >= 1:
                 self.overload_ticks += 1
         t0 = time.perf_counter()
-        out = self._tick_impl(now)
+        with TraceAnnotation("tuner.tick", tick=self.ticks + 1,
+                             internal=int(not _observe)):
+            out = self._tick_impl(now)
         if _observe:
             lat = time.perf_counter() - t0 if latency is None \
                 else float(latency)
@@ -1301,26 +1333,33 @@ class TuningService:
         self.last_tick_degraded = False
         out: Dict[str, Optional[TuneDecision]] = self._undelivered
         self._undelivered = {}
-        due = self._sched.due_jobs(now, self._jobs.keys())
         prob_mode = self.min_probability is not None
         pending: List[Tuple[InFlightJob, np.ndarray,
                             Optional[np.ndarray]]] = []
-        for job in self._jobs.values():
-            if job.job_id not in due:
-                continue
-            if prob_mode:
-                chunk, vchunk = self._front.drain(job.job_id,
-                                                  with_variance=True)
-            else:
-                chunk, vchunk = self._front.drain(job.job_id), None
-            if chunk is None:
-                continue
-            job.x.append(chunk)
-            if vchunk is not None:
-                job.vx.append(vchunk)
-            if job.haar is not None:
-                job.haar.update(chunk)
-            pending.append((job, chunk, vchunk))
+        with TraceAnnotation("tuner.drain") as span:
+            filters0 = self._front.filter_count
+            samples = 0
+            due = self._sched.due_jobs(now, self._jobs.keys())
+            for job in self._jobs.values():
+                if job.job_id not in due:
+                    continue
+                if prob_mode:
+                    chunk, vchunk = self._front.drain(job.job_id,
+                                                      with_variance=True)
+                else:
+                    chunk, vchunk = self._front.drain(job.job_id), None
+                if chunk is None:
+                    continue
+                job.x.append(chunk)
+                if vchunk is not None:
+                    job.vx.append(vchunk)
+                if job.haar is not None:
+                    job.haar.update(chunk)
+                pending.append((job, chunk, vchunk))
+                samples += chunk.shape[0]
+            span.set_metadata(
+                jobs=len(pending), samples=samples,
+                filtered=self._front.filter_count - filters0)
         if not pending:
             return out
 
@@ -1329,22 +1368,28 @@ class TuningService:
         # then K-axis when the prefilter's survivor union crossed a
         # bucket boundary, then S-axis when the active set fits a
         # smaller slot bucket.
-        self._apply_resets()
-        if self.prefilter_top is not None:
-            self._maybe_repack()
-        self._maybe_shrink_slots()
+        with TraceAnnotation("tuner.repack") as span:
+            slot0, k0 = self.slot_repack_count, self.repack_count
+            self._apply_resets()
+            if self.prefilter_top is not None:
+                self._maybe_repack()
+            self._maybe_shrink_slots()
+            span.set_metadata(slot_repacks=self.slot_repack_count - slot0,
+                              k_repacks=self.repack_count - k0)
         k_live = len(self._packed_idx)
 
-        c = _dtw._chunk_bucket(max(ch.shape[0] for _, ch, _ in pending))
-        chunks = np.zeros((self._s_cap, c), np.float32)
-        nvalid = np.zeros((self._s_cap,), np.int32)
-        vchunks = np.zeros((self._s_cap, c), np.float32) if prob_mode \
-            else None
-        for job, ch, vch in pending:
-            chunks[job.slot, : ch.shape[0]] = ch
-            nvalid[job.slot] = ch.shape[0]
-            if prob_mode:
-                vchunks[job.slot, : ch.shape[0]] = vch
+        with TraceAnnotation("tuner.chunks") as span:
+            c = _dtw._chunk_bucket(max(ch.shape[0] for _, ch, _ in pending))
+            chunks = np.zeros((self._s_cap, c), np.float32)
+            nvalid = np.zeros((self._s_cap,), np.int32)
+            vchunks = np.zeros((self._s_cap, c), np.float32) if prob_mode \
+                else None
+            for job, ch, vch in pending:
+                chunks[job.slot, : ch.shape[0]] = ch
+                nvalid[job.slot] = ch.shape[0]
+                if prob_mode:
+                    vchunks[job.slot, : ch.shape[0]] = vch
+            span.set_metadata(chunk=c, slots=self._s_cap)
 
         # Effective tick mode: the configured flavor, or a cheaper one
         # under the overload ladder.  Every flavor updates the DP rows
@@ -1354,118 +1399,121 @@ class TuningService:
         mode = self._tick_mode()
         base = self._base_mode()
         tick_fn, tick_fb = self._tick_fn_for(mode)
-        sims_all = probs_all = None
-        if mode == "prob":
-            args = (self._rows, self._moms, self._ns, self._sx, self._sxx,
-                    self._vstats, self._bank_t, self._lengths,
-                    jnp.asarray(chunks), jnp.asarray(vchunks),
-                    jnp.asarray(nvalid), jnp.asarray(self._qlens))
-            (self._rows, self._moms, self._ns, self._sx, self._sxx,
-             scores, self._vstats, probs) = self._dispatch_resilient(
-                tick_fn, tick_fb, args, "tick")
-            sims_all = np.full((self._s_cap, self._k), -np.inf)
-            sims_all[:, self._packed_idx] = \
-                np.asarray(scores, np.float64)[:, :k_live]
-            # pruned-out references carry zero match probability.
-            probs_all = np.zeros((self._s_cap, self._k))
-            probs_all[:, self._packed_idx] = \
-                np.asarray(probs, np.float64)[:, :k_live]
-        elif mode == "approx_prob":
-            # the approx tail needs only channels 0:4 (sy, syy, sxy,
-            # svy).  An approx-configured service carries exactly those
-            # four; an exact-configured service capped to this rung
-            # dispatches over the first four of its six — svyy/svxy
-            # stay stale (degraded_level=1 suppresses early decisions),
-            # but probabilities keep flowing: the rung sheds precision,
-            # not probabilities.
-            moms_in = self._moms[:4] if base == "prob" else self._moms
-            args = (self._rows, moms_in, self._ns, self._sx, self._sxx,
-                    self._vstats, self._bank_t, self._lengths,
-                    jnp.asarray(chunks), jnp.asarray(vchunks),
-                    jnp.asarray(nvalid), jnp.asarray(self._qlens))
-            (self._rows, moms_out, self._ns, self._sx, self._sxx,
-             scores, self._vstats, probs) = self._dispatch_resilient(
-                tick_fn, tick_fb, args, "tick")
-            if base == "prob":
-                self._moms = self._put(
-                    jnp.concatenate([moms_out, self._moms[4:]], axis=0),
-                    (None, None, None, self._axis))
+        scores = probs = None
+        with TraceAnnotation("tuner.dispatch", mode=mode, k_live=k_live):
+            if mode == "prob":
+                args = (self._rows, self._moms, self._ns, self._sx,
+                        self._sxx, self._vstats, self._bank_t,
+                        self._lengths, jnp.asarray(chunks),
+                        jnp.asarray(vchunks), jnp.asarray(nvalid),
+                        jnp.asarray(self._qlens))
+                (self._rows, self._moms, self._ns, self._sx, self._sxx,
+                 scores, self._vstats, probs) = self._dispatch_resilient(
+                    tick_fn, tick_fb, args, "tick")
+            elif mode == "approx_prob":
+                # the approx tail needs only channels 0:4 (sy, syy, sxy,
+                # svy).  An approx-configured service carries exactly
+                # those four; an exact-configured service capped to this
+                # rung dispatches over the first four of its six —
+                # svyy/svxy stay stale (degraded_level=1 suppresses early
+                # decisions), but probabilities keep flowing: the rung
+                # sheds precision, not probabilities.
+                moms_in = self._moms[:4] if base == "prob" else self._moms
+                args = (self._rows, moms_in, self._ns, self._sx, self._sxx,
+                        self._vstats, self._bank_t, self._lengths,
+                        jnp.asarray(chunks), jnp.asarray(vchunks),
+                        jnp.asarray(nvalid), jnp.asarray(self._qlens))
+                (self._rows, moms_out, self._ns, self._sx, self._sxx,
+                 scores, self._vstats, probs) = self._dispatch_resilient(
+                    tick_fn, tick_fb, args, "tick")
+                if base == "prob":
+                    self._moms = self._put(
+                        jnp.concatenate([moms_out, self._moms[4:]], axis=0),
+                        (None, None, None, self._axis))
+                else:
+                    self._moms = moms_out
+            elif mode == "scored":
+                # a prob-configured service ticking at the exact_score
+                # rung runs the 3-channel dispatch over channels 0:3 of
+                # its moment slab (6 channels exact, 4 approx); the
+                # variance channels (and vstats) simply stay what they
+                # were — stale, never wrong-and-used, because
+                # degraded_level >= 1 suppresses every probability read.
+                moms_in = self._moms[:3] \
+                    if base in ("prob", "approx_prob") else self._moms
+                args = (self._rows, moms_in, self._ns, self._sx, self._sxx,
+                        self._bank_t, self._lengths, jnp.asarray(chunks),
+                        jnp.asarray(nvalid), jnp.asarray(self._qlens))
+                (self._rows, moms_out, self._ns, self._sx, self._sxx,
+                 scores) = self._dispatch_resilient(
+                    tick_fn, tick_fb, args, "tick")
+                if base in ("prob", "approx_prob"):
+                    self._moms = self._put(
+                        jnp.concatenate([moms_out, self._moms[3:]], axis=0),
+                        (None, None, None, self._axis))
+                else:
+                    self._moms = moms_out
             else:
-                self._moms = moms_out
-            sims_all = np.full((self._s_cap, self._k), -np.inf)
-            sims_all[:, self._packed_idx] = \
-                np.asarray(scores, np.float64)[:, :k_live]
-            probs_all = np.zeros((self._s_cap, self._k))
-            probs_all[:, self._packed_idx] = \
-                np.asarray(probs, np.float64)[:, :k_live]
-        elif mode == "scored":
-            # a prob-configured service ticking at the exact_score rung
-            # runs the 3-channel dispatch over channels 0:3 of its
-            # moment slab (6 channels exact, 4 approx); the variance
-            # channels (and vstats) simply stay what they were — stale,
-            # never wrong-and-used, because degraded_level >= 1
-            # suppresses every probability read.
-            moms_in = self._moms[:3] \
-                if base in ("prob", "approx_prob") else self._moms
-            args = (self._rows, moms_in, self._ns, self._sx, self._sxx,
-                    self._bank_t, self._lengths, jnp.asarray(chunks),
-                    jnp.asarray(nvalid), jnp.asarray(self._qlens))
-            (self._rows, moms_out, self._ns, self._sx, self._sxx,
-             scores) = self._dispatch_resilient(
-                tick_fn, tick_fb, args, "tick")
-            if base in ("prob", "approx_prob"):
-                self._moms = self._put(
-                    jnp.concatenate([moms_out, self._moms[3:]], axis=0),
-                    (None, None, None, self._axis))
-            else:
-                self._moms = moms_out
-            # the tick's ONLY device->host transfer: the [S, K_live]
-            # scores, scattered back to full-bank columns (pruned-out
-            # references read -inf — never a leader, never a runner-up).
-            sims_all = np.full((self._s_cap, self._k), -np.inf)
-            sims_all[:, self._packed_idx] = \
-                np.asarray(scores, np.float64)[:, :k_live]
-        else:
-            args = (self._rows, self._ns, self._bank_t, self._lengths,
-                    jnp.asarray(chunks), jnp.asarray(nvalid),
-                    jnp.asarray(self._qlens))
-            self._rows, self._ns = self._dispatch_resilient(
-                tick_fn, tick_fb, args, "tick")
+                args = (self._rows, self._ns, self._bank_t, self._lengths,
+                        jnp.asarray(chunks), jnp.asarray(nvalid),
+                        jnp.asarray(self._qlens))
+                self._rows, self._ns = self._dispatch_resilient(
+                    tick_fn, tick_fb, args, "tick")
         self.dispatch_count += 1
 
-        if mode != base:
-            lvl = 2 if mode == "distance" else 1
-            for job, *_ in pending:
-                job.degraded_level = max(job.degraded_level, lvl)
+        # the tick's ONLY device->host transfer: the [S, K_live] scores
+        # (and probabilities), scattered back to full-bank columns
+        # (pruned-out references read -inf — never a leader, never a
+        # runner-up — and carry zero match probability).
+        sims_all = probs_all = None
+        if scores is not None:
+            with TraceAnnotation("tuner.pull"):
+                sims_all = np.full((self._s_cap, self._k), -np.inf)
+                sims_all[:, self._packed_idx] = \
+                    np.asarray(scores, np.float64)[:, :k_live]
+                if probs is not None:
+                    probs_all = np.zeros((self._s_cap, self._k))
+                    probs_all[:, self._packed_idx] = \
+                        np.asarray(probs, np.float64)[:, :k_live]
 
-        for job, ch, _ in pending:
-            job.n += ch.shape[0]
-            decision = None
-            # a level-2 job's moment/query-stat channels are stale, so
-            # any score a later scored tick emits for its slot is
-            # garbage: freeze last_sims/last_probs at their last exact
-            # values instead of overwriting them.
-            if sims_all is not None and job.degraded_level < 2:
-                sims = sims_all[job.slot]
-                if job.allowed is not None:
-                    # a column another job kept alive may be pruned for
-                    # THIS job: mask it out of this job's view.
-                    sims = np.where(job.allowed, sims, -np.inf)
-                job.last_sims = sims
-                if probs_all is not None:
-                    pr = probs_all[job.slot]
+        with TraceAnnotation("tuner.decide") as span:
+            if mode != base:
+                lvl = 2 if mode == "distance" else 1
+                for job, *_ in pending:
+                    job.degraded_level = max(job.degraded_level, lvl)
+
+            decisions = 0
+            for job, ch, _ in pending:
+                job.n += ch.shape[0]
+                decision = None
+                # a level-2 job's moment/query-stat channels are stale,
+                # so any score a later scored tick emits for its slot is
+                # garbage: freeze last_sims/last_probs at their last
+                # exact values instead of overwriting them.
+                if sims_all is not None and job.degraded_level < 2:
+                    sims = sims_all[job.slot]
                     if job.allowed is not None:
-                        pr = np.where(job.allowed, pr, 0.0)
-                    job.last_probs = pr
-                if job.early is None and job.degraded_level == 0:
-                    decision = self._maybe_decide(job)
-            if out.get(job.job_id) is None:
-                out[job.job_id] = decision
+                        # a column another job kept alive may be pruned
+                        # for THIS job: mask it out of this job's view.
+                        sims = np.where(job.allowed, sims, -np.inf)
+                    job.last_sims = sims
+                    if probs_all is not None:
+                        pr = probs_all[job.slot]
+                        if job.allowed is not None:
+                            pr = np.where(job.allowed, pr, 0.0)
+                        job.last_probs = pr
+                    if job.early is None and job.degraded_level == 0:
+                        decision = self._maybe_decide(job)
+                        decisions += decision is not None
+                if out.get(job.job_id) is None:
+                    out[job.job_id] = decision
+            span.set_metadata(jobs=len(pending), decisions=decisions)
         # prune with THIS tick's information (scores just computed, n just
         # advanced): eviction decisions lag the data by zero ticks, the
         # re-pack they imply happens at the top of the next tick.
         if self.prefilter_top is not None:
-            self._update_prefilter(pending)
+            with TraceAnnotation("tuner.prefilter"):
+                self._update_prefilter(pending)
         return out
 
     # -- decision rule -------------------------------------------------------
@@ -1535,7 +1583,8 @@ class TuningService:
         row-independent, so eviction cannot perturb their scores."""
         if job_id not in self._jobs:
             raise KeyError(job_id)
-        _, _, early = self._retire(job_id)
+        with TraceAnnotation("tuner.retire", jobs=1):
+            _, _, early = self._retire(job_id)
         self.evicted_count += 1
         return early
 
@@ -1586,20 +1635,22 @@ class TuningService:
         # pow2 buckets on both axes so repeat drains reuse jit shapes
         jb = _dtw._pad_pow2(len(live), lo=1)
         npad = _dtw._pad_pow2(max(queries[i].shape[0] for i in live))
-        xs = np.zeros((jb, npad), np.float32)
-        xl = np.zeros((jb,), np.int32)
-        sx = np.zeros((jb,), np.float32)
-        sxx = np.zeros((jb,), np.float32)
-        xv = np.zeros((jb, npad), np.float32) if prob_mode else None
-        for r, i in enumerate(live):
-            q = queries[i]
-            xs[r, : q.shape[0]] = q
-            xl[r] = q.shape[0]
-            sx[r], sxx[r] = _dtw.query_moments(q)
-            if prob_mode:
-                v = variances[i]
-                if v is not None and v.shape[0] == q.shape[0]:
-                    xv[r, : q.shape[0]] = v
+        with TraceAnnotation("tuner.verdict.pack", jobs=len(live),
+                             padded=jb, npad=npad):
+            xs = np.zeros((jb, npad), np.float32)
+            xl = np.zeros((jb,), np.int32)
+            sx = np.zeros((jb,), np.float32)
+            sxx = np.zeros((jb,), np.float32)
+            xv = np.zeros((jb, npad), np.float32) if prob_mode else None
+            for r, i in enumerate(live):
+                q = queries[i]
+                xs[r, : q.shape[0]] = q
+                xl[r] = q.shape[0]
+                sx[r], sxx[r] = _dtw.query_moments(q)
+                if prob_mode:
+                    v = variances[i]
+                    if v is not None and v.shape[0] == q.shape[0]:
+                        xv[r, : q.shape[0]] = v
         kw = dict(xvars=xv, threshold=float(self.threshold)) \
             if prob_mode else {}
 
@@ -1608,18 +1659,20 @@ class TuningService:
                 xs, self.bank.series, self.bank.lengths, xlens=xl,
                 band=self.band, sx=sx, sxx=sxx,
                 plan=self.bank.score_plan(), use_kernel=use_kernel, **kw)
-        res = self._dispatch_resilient(
-            call, functools.partial(call, use_kernel=False),
-            (xs, xl, sx, sxx), "verdict")
+        with TraceAnnotation("tuner.verdict.dispatch"):
+            res = self._dispatch_resilient(
+                call, functools.partial(call, use_kernel=False),
+                (xs, xl, sx, sxx), "verdict")
         scores, probs = res if prob_mode else (res, None)
-        if prob_mode:
-            probs = np.asarray(probs, np.float64)
-        scores = np.asarray(scores, np.float64)
-        self.offline_dispatch_count += 1
-        for r, i in enumerate(live):
-            out[i] = scores[r]
+        with TraceAnnotation("tuner.verdict.pull"):
             if prob_mode:
-                pout[i] = probs[r]
+                probs = np.asarray(probs, np.float64)
+            scores = np.asarray(scores, np.float64)
+            self.offline_dispatch_count += 1
+            for r, i in enumerate(live):
+                out[i] = scores[r]
+                if prob_mode:
+                    pout[i] = probs[r]
         return out, pout
 
     def _render_verdict(self, job_id: str, sims: np.ndarray,
@@ -1695,14 +1748,17 @@ class TuningService:
             raise KeyError(f"unknown job(s): {missing}")
         if not ids:
             return {}
-        self._drain_tick_for(set(ids))
-        retired = [self._retire(j) for j in ids]
-        sims, probs = self._verdict_scores([x for x, _, _ in retired],
-                                           [v for _, v, _ in retired])
-        return {jid: self._render_verdict(
-                    jid, sims[i], retired[i][2],
-                    None if probs is None else probs[i])
-                for i, jid in enumerate(ids)}
+        with TraceAnnotation("tuner.finish_many", jobs=len(ids)):
+            self._drain_tick_for(set(ids))
+            with TraceAnnotation("tuner.retire", jobs=len(ids)):
+                retired = [self._retire(j) for j in ids]
+            sims, probs = self._verdict_scores([x for x, _, _ in retired],
+                                               [v for _, v, _ in retired])
+            with TraceAnnotation("tuner.verdict.render", jobs=len(ids)):
+                return {jid: self._render_verdict(
+                            jid, sims[i], retired[i][2],
+                            None if probs is None else probs[i])
+                        for i, jid in enumerate(ids)}
 
     def finish_later(self, job_id: str) -> None:
         """Deferred finish: the job leaves its slot now (so slots
@@ -1723,7 +1779,8 @@ class TuningService:
                 f"a verdict for job {job_id!r} is already pending "
                 "delivery; drain_finishes() before deferring a reused id")
         self._drain_tick_for({job_id})
-        x, vx, early = self._retire(job_id)
+        with TraceAnnotation("tuner.retire", jobs=1):
+            x, vx, early = self._retire(job_id)
         self._finish_queue.append((job_id, x, vx, early))
         if len(self._finish_queue) >= self.finish_batch:
             self._finished.update(self._drain_queue())
@@ -1732,12 +1789,14 @@ class TuningService:
         if not self._finish_queue:
             return {}
         queued, self._finish_queue = self._finish_queue, []
-        sims, probs = self._verdict_scores([x for _, x, _, _ in queued],
-                                           [v for _, _, v, _ in queued])
-        return {jid: self._render_verdict(
-                    jid, sims[i], early,
-                    None if probs is None else probs[i])
-                for i, (jid, _, _, early) in enumerate(queued)}
+        with TraceAnnotation("tuner.finish_many", jobs=len(queued)):
+            sims, probs = self._verdict_scores(
+                [x for _, x, _, _ in queued], [v for _, _, v, _ in queued])
+            with TraceAnnotation("tuner.verdict.render", jobs=len(queued)):
+                return {jid: self._render_verdict(
+                            jid, sims[i], early,
+                            None if probs is None else probs[i])
+                        for i, (jid, _, _, early) in enumerate(queued)}
 
     def drain_finishes(self) -> Dict[str, TuneDecision]:
         """Render every deferred verdict (one batched dispatch), plus any
