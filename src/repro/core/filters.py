@@ -5,13 +5,14 @@ low-pass Chebyshev filter before storing/matching (§3.1.1, §4).  We design
 the filter ourselves (analog Chebyshev-I prototype -> frequency pre-warp ->
 bilinear transform) so the hot path has no scipy dependency, and apply it
 either with a lax.scan (direct-form-II-transposed, batched over series) or
-with the Pallas IIR kernel in ``repro.kernels.iir``.
+with the Pallas IIR kernel in ``repro.kernels.iir``.  The causal streaming
+filter of in-flight jobs runs the same recurrence on the host in numpy.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ __all__ = [
     "normalize01",
     "preprocess",
     "preprocess_bank",
+    "lfilter_carry",
     "StreamingFilter",
 ]
 
@@ -138,28 +140,50 @@ def filtfilt(b: np.ndarray, a: np.ndarray, x: jax.Array) -> jax.Array:
 # Streaming (stateful causal) filtering
 # ---------------------------------------------------------------------------
 
-@jax.jit
-def _lfilter_scan_carry(b: jax.Array, a: jax.Array, x: jax.Array,
-                        zi: jax.Array, nvalid: jax.Array
-                        ) -> Tuple[jax.Array, jax.Array]:
-    """One DF2T pass over a (padded) chunk with explicit state in/out.
+def lfilter_carry(b: np.ndarray, a: np.ndarray, x: np.ndarray,
+                  nvalid: np.ndarray, zi: np.ndarray
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """DF2T over a batch of padded chunks with explicit state in/out, on
+    the host in float32.
 
-    x: [T] chunk, zi: [n-1] filter state, nvalid: samples of x that are
-    real — the state freezes after them, so padded tails never leak into
-    the carried state (y's tail is garbage; callers slice).  Returns
-    (y [T], zf [n-1]).  DF2T is causal, so filtering chunk-by-chunk with
-    the carried state is *exactly* the one-shot :func:`lfilter` of the
+    ``b``/``a``: float32 coefficients normalized so ``a[0] == 1``; ``x``:
+    ``[J, C]`` chunks; ``nvalid``: ``[J]`` real samples per row — a row's
+    state freezes after them, so ragged chunks share one call and padded
+    tails never leak into the carried state (``y``'s tail is garbage;
+    callers slice); ``zi``: ``[J, n-1]`` states.  Returns ``(y [J, C],
+    zf [J, n-1])``.  Every element runs the same float32 multiply/add
+    sequence whatever ``J`` is, so a row's output does not depend on the
+    rows sharing its call.  DF2T is causal, so filtering chunk by chunk
+    with the carried state is the one-shot :func:`lfilter` of the
     concatenated signal — the invariant the streaming service leans on.
     """
-    def step(state, inp):
-        xt, s = inp
-        yt = b[0] * xt + state[0]
-        nxt = b[1:] * xt - a[1:] * yt + jnp.pad(state[1:], (0, 1))
-        return jnp.where(s < nvalid, nxt, state), yt
+    x = np.asarray(x, np.float32)
+    nvalid = np.asarray(nvalid).reshape(-1, 1)
+    z = np.array(zi, np.float32)
+    y = np.empty_like(x)
+    b0, bt, at = b[0], b[1:], a[1:]
+    for t in range(x.shape[1]):
+        xt = x[:, t:t + 1]
+        yt = b0 * xt + z[:, :1]
+        y[:, t] = yt[:, 0]
+        # z_i <- b_{i+1} x - a_{i+1} y + z_{i+1}
+        nxt = bt * xt - at * yt
+        nxt[:, :-1] += z[:, 1:]
+        live = t < nvalid
+        z = nxt if live.all() else np.where(live, nxt, z)
+    return y, z
 
-    zf, y = jax.lax.scan(
-        step, zi, (x, jnp.arange(x.shape[0], dtype=jnp.int32)))
-    return y, zf
+
+@functools.lru_cache(maxsize=None)
+def _streaming_ba(order: int, ripple_db: float, cutoff: float):
+    """Normalized float32 ``(b, a)``, one read-only pair per design, so
+    filters of one design share the very same arrays."""
+    b, a = _default_ba(order, ripple_db, cutoff)
+    a = np.asarray(a, np.float64)
+    b = (np.asarray(b, np.float64) / a[0]).astype(np.float32)
+    a = (a / a[0]).astype(np.float32)
+    b.flags.writeable = a.flags.writeable = False
+    return b, a
 
 
 class StreamingFilter:
@@ -174,40 +198,46 @@ class StreamingFilter:
     group delay).  Utilization series are already on the [0, 1] scale, so
     no running normalization is applied.
 
-    Chunks are padded to power-of-two buckets before the jitted scan (the
-    state freezes after the valid samples), so arbitrary tick sizes reuse
-    a handful of compiled shapes instead of tracing per length.
+    The work is a few multiply-adds per sample, so it runs on the host
+    (:func:`lfilter_carry`); :meth:`run_many` filters the chunks of many
+    filters of one design in one batched call.
     """
 
     def __init__(self, order: int = None, ripple_db: float = None,
                  cutoff: float = None) -> None:
-        b, a = _default_ba(order if order is not None else DEFAULT_ORDER,
-                           ripple_db if ripple_db is not None
-                           else DEFAULT_RIPPLE_DB,
-                           cutoff if cutoff is not None else DEFAULT_CUTOFF)
-        a = np.asarray(a, np.float64)
-        self._b = jnp.asarray(np.asarray(b, np.float64) / a[0],
-                              jnp.float32)
-        self._a = jnp.asarray(a / a[0], jnp.float32)
+        self._b, self._a = _streaming_ba(
+            order if order is not None else DEFAULT_ORDER,
+            ripple_db if ripple_db is not None else DEFAULT_RIPPLE_DB,
+            cutoff if cutoff is not None else DEFAULT_CUTOFF)
         self.reset()
 
     def reset(self) -> None:
-        self._z = jnp.zeros((self._b.shape[0] - 1,), jnp.float32)
+        self._z = np.zeros((self._b.shape[0] - 1,), np.float32)
 
     def __call__(self, chunk: np.ndarray) -> np.ndarray:
-        from .dtw import _chunk_bucket      # shared jit-shape bucketing
+        return StreamingFilter.run_many([self], [chunk])[0]
 
-        x = np.asarray(chunk, np.float32).reshape(-1)
-        c = x.shape[0]
-        if c == 0:
-            return np.zeros((0,), np.float32)
-        cp = _chunk_bucket(c)
-        xp = np.zeros((cp,), np.float32)
-        xp[:c] = x
-        y, self._z = _lfilter_scan_carry(self._b, self._a, jnp.asarray(xp),
-                                         self._z, jnp.int32(c))
-        # slice on the host: a device slice compiles once per tail length
-        return np.asarray(y)[:c]
+    @staticmethod
+    def run_many(filters: Sequence["StreamingFilter"],
+                 chunks: Sequence[np.ndarray]) -> List[np.ndarray]:
+        """Advance each filter over its chunk in ONE :func:`lfilter_carry`
+        call; returns the filtered chunks in order.  Each output is
+        bit-identical to ``filters[i](chunks[i])`` alone."""
+        xs = [np.asarray(c, np.float32).reshape(-1) for c in chunks]
+        if not filters:
+            return []
+        b, a = filters[0]._b, filters[0]._a
+        if any(f._b is not b or f._a is not a for f in filters):
+            raise ValueError("run_many needs filters of one design")
+        lens = np.array([x.shape[0] for x in xs], np.int64)
+        x = np.zeros((len(xs), int(lens.max())), np.float32)
+        for i, xi in enumerate(xs):
+            x[i, : xi.shape[0]] = xi
+        y, zf = lfilter_carry(b, a, x, lens,
+                              np.stack([f._z for f in filters]))
+        for f, z in zip(filters, zf):
+            f._z = z
+        return [y[i, : n] for i, n in enumerate(lens)]
 
 
 # ---------------------------------------------------------------------------

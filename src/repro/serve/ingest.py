@@ -29,10 +29,11 @@ here, so the tick engine only ever sees clean, causally-filtered chunks.
   stalled job's slot (``TuningService.sweep_stalled``) and flag jobs
   whose monitoring agent has degraded.
 
-Each filter call in a drain is one device round trip; it runs inside a
-``tuner.filter`` profiler span (``jax.profiler.TraceAnnotation``, free
-while no trace is being captured) and is counted in
-:attr:`IngestFront.filter_count`.
+:meth:`IngestFront.drain_many` filters the chunks of every drained job in
+one batched host call (``filters.lfilter_carry``: a few multiply-adds a
+sample, no device round trip); the call runs inside one ``tuner.filter``
+profiler span (``jax.profiler.TraceAnnotation``, free while no trace is
+being captured) and is counted in :attr:`IngestFront.filter_count`.
 
 The filter is applied at *drain* time on the concatenated chunk — the
 same call structure the monolithic service used — so layering changes
@@ -45,7 +46,7 @@ from __future__ import annotations
 import collections
 import json
 import warnings
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -503,8 +504,8 @@ class IngestFront:
         self.stragglers = StragglerDetector(factor=straggler_factor)
         self._jobs: Dict[str, _JobIngest] = {}
         self._last_push: Dict[str, float] = {}
-        #: causal-filter calls made by :meth:`drain` (one device round
-        #: trip each)
+        #: batched causal-filter calls made by :meth:`drain_many` (one
+        #: per drain that filters anything)
         self.filter_count = 0
 
     def register(self, job_id: str) -> None:
@@ -576,29 +577,44 @@ class IngestFront:
         ``with_variance=True`` (requires ``track_variance=True``)
         returns an aligned ``(chunk, vchunk)`` pair instead, with
         unsupplied variances defaulted from the filter residual."""
-        ji = self._jobs[job_id]
-        if with_variance and ji.vbuffer is None:
+        return self.drain_many([job_id], with_variance)[0]
+
+    def drain_many(self, job_ids: Sequence[str],
+                   with_variance: bool = False) -> List[Any]:
+        """:meth:`drain` of every listed job, in order, with the causal
+        filter of all of them run as ONE batched call (one
+        ``tuner.filter`` span).  Each entry is what ``drain(job_id)``
+        would have returned alone: a job's filtered chunk does not
+        depend on which other jobs share the batch."""
+        jis = [self._jobs[j] for j in job_ids]
+        if with_variance and any(ji.vbuffer is None for ji in jis):
             raise ValueError("drain(with_variance=True) requires "
                              "track_variance=True on the IngestFront")
-        raw = ji.buffer.drain()
-        if raw is None:
-            return (None, None) if with_variance else None
-        if ji.filt is not None:
-            with TraceAnnotation("tuner.filter", samples=raw.shape[0]):
-                chunk = ji.filt(raw)
+        raws = [ji.buffer.drain() for ji in jis]
+        chunks: List[Optional[np.ndarray]] = list(raws)
+        todo = [i for i, (ji, raw) in enumerate(zip(jis, raws))
+                if raw is not None and ji.filt is not None]
+        if todo:
+            with TraceAnnotation("tuner.filter", jobs=len(todo),
+                                 samples=sum(raws[i].shape[0]
+                                             for i in todo)):
+                out = StreamingFilter.run_many(
+                    [jis[i].filt for i in todo], [raws[i] for i in todo])
+            for i, chunk in zip(todo, out):
+                chunks[i] = chunk
             self.filter_count += 1
-        else:
-            chunk = raw
-        if ji.vbuffer is not None:
-            vchunk = ji.vbuffer.drain()
-            if not with_variance:
-                return chunk
-            resid = (raw - chunk) ** 2 if ji.filt is not None \
-                else np.zeros_like(raw)
-            vchunk = np.where(np.isnan(vchunk), resid, vchunk) \
-                .astype(np.float32)
-            return chunk, vchunk
-        return (chunk, None) if with_variance else chunk
+        result: List[Any] = []
+        for ji, raw, chunk in zip(jis, raws, chunks):
+            vchunk = None
+            if raw is not None and ji.vbuffer is not None:
+                vchunk = ji.vbuffer.drain()
+                if with_variance:
+                    resid = (raw - chunk) ** 2 if ji.filt is not None \
+                        else np.zeros_like(raw)
+                    vchunk = np.where(np.isnan(vchunk), resid, vchunk) \
+                        .astype(np.float32)
+            result.append((chunk, vchunk) if with_variance else chunk)
+        return result
 
     def dropped(self, job_id: str) -> int:
         return self._jobs[job_id].buffer.dropped

@@ -363,7 +363,7 @@ def restore_service(tree: Dict[str, Any],
             if "vbuf" in jt:
                 ji.vbuffer.append(np.asarray(jt["vbuf"], np.float32))
         if ji.filt is not None and "filtz" in jt:
-            ji.filt._z = jnp.asarray(np.asarray(jt["filtz"], np.float32))
+            ji.filt._z = np.array(jt["filtz"], np.float32)
         svc._jobs[job.job_id] = job
 
     fq_tree = tree.get("fq", {})

@@ -156,8 +156,9 @@ boundary:
 * ``tuner.tick`` (``tick``: the tick's id, ``internal``: 1 for the
   drain tick of a finish) over the region ``last_tick_latency`` times,
   holding in order ``tuner.drain`` (``jobs``, ``samples``, ``filtered``:
-  the due-job loop with one ``tuner.filter`` span, ``samples``, per
-  causal-filter call of ``serve.ingest``), ``tuner.repack``
+  the due-job loop around ``IngestFront.drain_many``, whose one batched
+  causal-filter call of the drained jobs is a ``tuner.filter`` span,
+  ``jobs``, ``samples``; ``filtered`` is 1 when it ran), ``tuner.repack``
   (``slot_repacks``, ``k_repacks``: this tick's), ``tuner.chunks``
   (``chunk``, ``slots``), ``tuner.dispatch`` (``mode``, ``k_live``: the
   uploads and the tick dispatch), ``tuner.pull`` (the ``[S, K]`` pull and
@@ -1340,14 +1341,11 @@ class TuningService:
             filters0 = self._front.filter_count
             samples = 0
             due = self._sched.due_jobs(now, self._jobs.keys())
-            for job in self._jobs.values():
-                if job.job_id not in due:
-                    continue
-                if prob_mode:
-                    chunk, vchunk = self._front.drain(job.job_id,
-                                                      with_variance=True)
-                else:
-                    chunk, vchunk = self._front.drain(job.job_id), None
+            jobs = [job for job in self._jobs.values() if job.job_id in due]
+            drained = self._front.drain_many([job.job_id for job in jobs],
+                                             with_variance=prob_mode)
+            for job, got in zip(jobs, drained):
+                chunk, vchunk = got if prob_mode else (got, None)
                 if chunk is None:
                     continue
                 job.x.append(chunk)

@@ -136,10 +136,9 @@ def test_tick_and_finish_spans(tmp_path, bank, mode):
         assert tick.names() == want
         drain, = tick.find("tuner.drain")
         assert drain.args == {"jobs": JOBS, "samples": JOBS * PUSH,
-                              "filtered": JOBS}
-        filters = drain.find("tuner.filter")
-        assert len(filters) == drain.args["filtered"]
-        assert [f.args for f in filters] == [{"samples": PUSH}] * JOBS
+                              "filtered": 1}
+        filt, = drain.find("tuner.filter")
+        assert filt.args == {"jobs": JOBS, "samples": JOBS * PUSH}
         repack, = tick.find("tuner.repack")
         assert set(repack.args) == {"slot_repacks", "k_repacks"}
         chunks, = tick.find("tuner.chunks")
@@ -156,8 +155,10 @@ def test_tick_and_finish_spans(tmp_path, bank, mode):
     inner, = fin.find("tuner.tick")
     assert inner.args == {"tick": TICKS + 1, "internal": 1}
     assert inner.names() == want
-    assert inner.find("tuner.drain")[0].args == {"jobs": 2, "samples": 6,
-                                                 "filtered": 2}
+    inner_drain, = inner.find("tuner.drain")
+    assert inner_drain.args == {"jobs": 2, "samples": 6, "filtered": 1}
+    assert [f.args for f in inner_drain.find("tuner.filter")] == [
+        {"jobs": 2, "samples": 6}]
     assert fin.find("tuner.retire")[0].args == {"jobs": 2}
     assert fin.find("tuner.verdict.pack")[0].args == {
         "jobs": 2, "padded": 2, "npad": 32}

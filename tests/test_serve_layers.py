@@ -82,6 +82,107 @@ def test_service_backpressure_policies(paper_bank):
 
 
 # ---------------------------------------------------------------------------
+# ingest: batched drain
+# ---------------------------------------------------------------------------
+
+def _feed(fronts, rng, jobs, rounds, variance):
+    """The same ragged pushes into every front: some jobs skip a round,
+    half the variance pushes carry explicit values (the rest default to
+    the filter residual at drain time)."""
+    for _ in range(rounds):
+        for jid in jobs:
+            n = int(rng.integers(0, 6))
+            if not n:
+                continue
+            x = rng.uniform(0, 1, size=n).astype(np.float32)
+            v = None
+            if variance and rng.random() < 0.5:
+                v = rng.uniform(0, 0.01, size=n).astype(np.float32)
+            for front in fronts:
+                front.push(jid, x, variance=v)
+
+
+@pytest.mark.parametrize("variance", [False, True])
+def test_ingest_drain_many_equals_sequential_drains(variance):
+    jobs = [f"j{i}" for i in range(6)]
+    kw = dict(denoise=True, track_variance=variance)
+    batched, single = IngestFront(**kw), IngestFront(**kw)
+    for front in (batched, single):
+        for jid in jobs:
+            front.register(jid)
+    rng = np.random.default_rng(5)
+    for _ in range(4):
+        _feed((batched, single), rng, jobs, 2, variance)
+        got = batched.drain_many(jobs, with_variance=variance)
+        want = [single.drain(jid, with_variance=variance) for jid in jobs]
+        for g, w in zip(got, want):
+            g, w = (g, w) if variance else ((g, None), (w, None))
+            for a, b in zip(g, w):
+                if b is None:
+                    assert a is None
+                else:
+                    np.testing.assert_array_equal(a, b)
+        for jid in jobs:
+            np.testing.assert_array_equal(batched._jobs[jid].filt._z,
+                                          single._jobs[jid].filt._z)
+    assert batched.filter_count == 4
+    assert all(not batched.has_data(jid) for jid in jobs)
+
+
+def test_ingest_filter_count_one_per_batched_drain():
+    for denoise, per_drain in ((True, 1), (False, 0)):
+        front = IngestFront(denoise=denoise)
+        for jid in ("a", "b", "c"):
+            front.register(jid)
+        front.drain_many(["a", "b", "c"])           # nothing to filter
+        assert front.filter_count == 0
+        for k in range(1, 4):
+            front.push("a", np.ones(3, np.float32))
+            front.push("c", np.ones(1, np.float32))
+            out = front.drain_many(["a", "b", "c"])
+            assert out[1] is None
+            assert [o.shape for o in (out[0], out[2])] == [(3,), (1,)]
+            assert front.filter_count == k * per_drain
+    with pytest.raises(ValueError, match="track_variance"):
+        front.drain_many(["a"], with_variance=True)
+
+
+def test_denoised_service_snapshot_mid_stream_continues_exactly(paper_bank):
+    """A snapshot taken between ticks, with samples still queued, carries
+    each job's host filter state: the restored service filters the rest
+    of the stream bit-identically to one that never stopped."""
+    from repro.serve.recovery import restore_service, snapshot_service
+
+    rng = np.random.default_rng(2)
+    qs = {f"j{i}": rng.uniform(0, 1, size=48).astype(np.float32)
+          for i in range(3)}
+    svc = TuningService(paper_bank, band=8, denoise=True)
+    for jid, q in qs.items():
+        svc.submit(jid, expected_len=len(q))
+    for t in range(3):
+        for jid, q in qs.items():
+            svc.push(jid, q[8 * t: 8 * t + 8])
+        svc.tick()
+    for jid, q in qs.items():                       # queued, not drained
+        svc.push(jid, q[24:29])
+    twin = restore_service(snapshot_service(svc), paper_bank)
+    for jid in qs:
+        z = twin._front._jobs[jid].filt._z
+        assert isinstance(z, np.ndarray) and z.dtype == np.float32
+        np.testing.assert_array_equal(z, svc._front._jobs[jid].filt._z)
+    for s in (svc, twin):
+        s.tick()
+        for jid, q in qs.items():
+            s.push(jid, q[29:])
+        s.tick()
+    for jid in qs:
+        np.testing.assert_array_equal(twin._jobs[jid].x.view(),
+                                      svc._jobs[jid].x.view())
+        np.testing.assert_array_equal(twin._jobs[jid].last_sims,
+                                      svc._jobs[jid].last_sims)
+
+
+# ---------------------------------------------------------------------------
 # ingest: trace log
 # ---------------------------------------------------------------------------
 

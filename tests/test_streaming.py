@@ -20,7 +20,7 @@ from hypothesis import given, settings, strategies as st
 from repro import mrsim
 from repro.core import (OnlineMatcher, StreamingFilter, dtw, similarity_bank)
 from repro.core.database import pack_series
-from repro.core.filters import cheby1_design, lfilter
+from repro.core.filters import cheby1_design, lfilter, lfilter_carry
 from repro.core.similarity import prefix_similarity_bank
 from repro.serve.tuning import TuningService
 
@@ -237,6 +237,61 @@ def test_streaming_filter_chunking_invariant():
         sf = StreamingFilter()
         got = np.concatenate([sf(c) for c in np.split(x, np.cumsum(chunks))[:-1]])
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def _batched(x, nvalid, zi):
+    f = StreamingFilter()
+    return lfilter_carry(f._b, f._a, x, nvalid, zi)
+
+
+@pytest.mark.parametrize("C", [1, 8, 13])
+def test_lfilter_carry_ragged_rows_equal_per_job_filters(C):
+    """One batched call over ragged rows (0 to C valid samples) gives,
+    row for row and bit for bit, what a StreamingFilter run per job
+    gives, and carries each row's state exactly as that filter does."""
+    rng = np.random.default_rng(C)
+    J = 7
+    nvalid = np.array([0, C, 1, C // 2, C, 0, max(C - 1, 0)])
+    x = rng.uniform(0, 1, size=(J, C)).astype(np.float32)
+    solo, zi = [], []
+    for j in range(J):              # warm each filter to its own state
+        sf = StreamingFilter()
+        sf(rng.uniform(0, 1, size=5 + j))
+        zi.append(sf._z.copy())
+        solo.append(sf)
+    y, zf = _batched(x, nvalid, np.stack(zi))
+    assert y.dtype == zf.dtype == np.float32
+    for j in range(J):
+        want = solo[j](x[j, : nvalid[j]])
+        np.testing.assert_array_equal(y[j, : nvalid[j]], want)
+        np.testing.assert_array_equal(zf[j], solo[j]._z)
+    np.testing.assert_array_equal(zf[0], zi[0])     # nvalid 0: untouched
+
+
+@given(st.integers(min_value=0, max_value=2**31 - 1))
+@settings(max_examples=10, deadline=None)
+def test_lfilter_carry_independent_of_batch_company(seed):
+    """A row's output and state do not depend on which rows share its
+    call, nor where it sits in the batch."""
+    rng = np.random.default_rng(seed)
+    C = int(rng.integers(1, 12))
+    J = int(rng.integers(2, 40))
+    x = rng.uniform(0, 1, size=(J, C)).astype(np.float32)
+    nvalid = rng.integers(0, C + 1, size=J)
+    zi = rng.normal(scale=0.3, size=(J, 6)).astype(np.float32)
+    y, zf = _batched(x, nvalid, zi)
+    pick = rng.permutation(J)[: int(rng.integers(1, J + 1))]
+    y2, zf2 = _batched(x[pick], nvalid[pick], zi[pick])
+    for r, j in enumerate(pick):
+        np.testing.assert_array_equal(y2[r, : nvalid[j]], y[j, : nvalid[j]])
+        np.testing.assert_array_equal(zf2[r], zf[j])
+
+
+def test_streaming_filter_run_many_rejects_mixed_designs():
+    with pytest.raises(ValueError, match="one design"):
+        StreamingFilter.run_many([StreamingFilter(), StreamingFilter(
+            cutoff=0.25)], [np.ones(3), np.ones(3)])
+    assert StreamingFilter.run_many([], []) == []
 
 
 def test_iter_cpu_series_concatenates_to_simulate():
