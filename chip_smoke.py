@@ -32,8 +32,9 @@ Each phase checks:
   scores to 5e-3, same leader (and, on the point rule, the same match).
 
 ``--chips 4`` runs only the bank-sharded service over a 4-chip mesh
-(64 jobs, 16 ticks, finals) against the unsharded one on one chip of the
-same process: scores to 1e-6, identical decisions, one dispatch a tick.
+(64 jobs, 16 ticks, finals; the Pallas kernels on each chip's share of
+the bank) against the unsharded one on one chip of the same process:
+scores to 1e-6, identical decisions, one dispatch a tick.
 
 Without a TPU it exits non-zero at once and names the platform it found.
 The last line of a passing run is one JSON object:
@@ -324,15 +325,16 @@ def run_phase(name: str, db, jobs, *, slots: int, ticks: int,
 def run_sharded(db, jobs, *, ticks: int, n_finish: int, chips: int,
                 counter: CompileCounter) -> None:
     """The bank-sharded service over a ``chips``-device mesh against the
-    unsharded one on one device (both tick on the jnp wavefront: the
-    sharded tick closes over it, the unsharded one is its twin)."""
+    unsharded one on one device (both tick and score verdicts with the
+    Pallas kernels: the sharded service runs the same dispatches under
+    ``shard_map``)."""
     import jax
     from repro.serve.tuning import TuningService
 
     kw = dict(band=8, denoise=True)
     mesh = jax.make_mesh((chips,), ("bank",))
     shd = TuningService(db, slots=len(jobs), mesh=mesh, **kw)
-    ref = jnp_twin(db, len(jobs), kw)
+    ref = TuningService(db, slots=len(jobs), **kw)
     for svc in (shd, ref):
         for jid, x in jobs.items():
             svc.submit(jid, expected_len=len(x))

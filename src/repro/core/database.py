@@ -86,10 +86,10 @@ class SeriesBank:
     lengths: np.ndarray                      # [K] int32
     labels: Tuple[str, ...] = ()
     entries: Tuple[Entry, ...] = ()
-    #: memoized device-side tiling for the matrix-free offline scorers
-    #: (``core.dtw.ScoreBankPlan``) — series/lengths are frozen, so the
-    #: plan can never go stale; ``dataclasses.replace`` copies start
-    #: fresh.  Excluded from comparison/repr.
+    #: memoized device-side tilings for the matrix-free offline scorers
+    #: (``core.dtw.ScoreBankPlan``, one per mesh) — series/lengths are
+    #: frozen, so a plan can never go stale; ``dataclasses.replace``
+    #: copies start fresh.  Excluded from comparison/repr.
     _score_plan: object = dataclasses.field(default=None, init=False,
                                             repr=False, compare=False)
     #: memoized paper-pipeline-filtered copy (see :meth:`preprocessed`).
@@ -103,16 +103,22 @@ class SeriesBank:
         """Unpadded series k."""
         return self.series[k, : int(self.lengths[k])]
 
-    def score_plan(self):
+    def score_plan(self, mesh=None):
         """Device-resident tiled upload of this bank for the closed-end
-        moment scorers (``core.dtw.dtw_score_bank*``), built once and
+        moment scorers (``core.dtw.dtw_score_bank*``), built once per
+        ``mesh`` (None: one device; a 1-D mesh: K-sharded over it) and
         reused across verdicts — the finish()/match hot path must not
         re-pack and re-upload the same bank per call."""
-        plan = self._score_plan
+        plans = self._score_plan
+        if plans is None:
+            plans = {}
+            object.__setattr__(self, "_score_plan", plans)
+        plan = plans.get(mesh)
         if plan is None:
             from . import dtw as _dtw
-            plan = _dtw.build_score_plan(self.series, self.lengths)
-            object.__setattr__(self, "_score_plan", plan)
+            plan = _dtw.build_score_plan(self.series, self.lengths,
+                                         mesh=mesh)
+            plans[mesh] = plan
         return plan
 
     def preprocessed(self) -> "SeriesBank":

@@ -157,6 +157,16 @@ __all__ = [
 
 _INF = jnp.float32(3.0e38)
 
+#: reference-tile width of the Pallas streaming tick kernels
+#: (``kernels.dtw.stream``; the ``block_k`` of the tick dispatchers).
+TICK_BLOCK_K = 128
+
+
+def _kernel_backend() -> bool:
+    """Whether the dispatchers default to the Pallas kernels (a TPU
+    backend) rather than the jnp twins."""
+    return jax.default_backend() == "tpu"
+
 
 def cost_matrix(x: jax.Array, y: jax.Array) -> jax.Array:
     """Pairwise |x_i - y_j| (paper Eq. 2) -> [N, M]."""
@@ -989,7 +999,7 @@ def bank_extend_tick_dispatch(rows, ns, bank_t, lengths, chunks, nvalid,
     wavefront (the dispatch-resilience fallback twin).  Tick layout in
     and out ([J, M, K])."""
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = _kernel_backend()
     if use_kernel:
         from ..kernels.dtw import stream_bank_extend
         new_rows, ns2 = stream_bank_extend(
@@ -1033,7 +1043,7 @@ def bank_extend_tick_scored_dispatch(rows, moms, ns, sx, sxx, bank_t,
                                      band: Optional[int] = None,
                                      use_kernel: Optional[bool] = None,
                                      interpret: Optional[bool] = None,
-                                     block_k: int = 128):
+                                     block_k: int = TICK_BLOCK_K):
     """Fused scoring tick routed to the best backend: the moment-carrying
     Pallas streaming kernel on TPU (DP row AND the three [BK, M] moment
     slabs pinned in VMEM across the whole chunk), the jnp wavefront
@@ -1046,7 +1056,7 @@ def bank_extend_tick_scored_dispatch(rows, moms, ns, sx, sxx, bank_t,
     cell-by-cell equivalence suite pins kernel == jnp wavefront.
     """
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = _kernel_backend()
     if use_kernel:
         if interpret is None:
             from ..kernels.common import default_interpret
@@ -1100,14 +1110,14 @@ def bank_extend_tick_scored_var_dispatch(rows, moms, ns, sx, sxx, vstats,
                                          threshold: float = 0.9,
                                          use_kernel: Optional[bool] = None,
                                          interpret: Optional[bool] = None,
-                                         block_k: int = 128):
+                                         block_k: int = TICK_BLOCK_K):
     """Variance-carrying fused scoring tick routed to the best backend
     (Pallas streaming kernel with six VMEM moment slabs on TPU, jnp
     wavefront elsewhere) — the probabilistic twin of
     :func:`bank_extend_tick_scored_dispatch`, returning the 8-tuple of
     :func:`bank_extend_tick_scored_var`."""
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = _kernel_backend()
     if use_kernel:
         if interpret is None:
             from ..kernels.common import default_interpret
@@ -1190,14 +1200,14 @@ def bank_extend_tick_scored_var_approx_dispatch(
         rows, moms, ns, sx, sxx, vstats, bank_t, lengths, chunks, vchunks,
         nvalid, qlens, band: Optional[int] = None, threshold: float = 0.9,
         use_kernel: Optional[bool] = None,
-        interpret: Optional[bool] = None, block_k: int = 128):
+        interpret: Optional[bool] = None, block_k: int = TICK_BLOCK_K):
     """Approx variance-carrying fused scoring tick routed to the best
     backend (Pallas streaming kernel with FOUR VMEM moment slabs on TPU,
     jnp wavefront elsewhere) — the serving twin of
     :func:`bank_extend_tick_scored_var_dispatch`, returning the 8-tuple
     of :func:`bank_extend_tick_scored_var_approx`."""
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = _kernel_backend()
     if use_kernel:
         if interpret is None:
             from ..kernels.common import default_interpret
@@ -1834,31 +1844,100 @@ class ScoreBankPlan:
     uploaded.  Build once per bank (``database.SeriesBank.score_plan``
     caches it) and reuse across verdicts — re-deriving it per call would
     re-upload the whole bank every ``finish()``.
+
+    A plan over a 1-D ``mesh`` splits the bank's K axis over the mesh
+    devices: the tiles sit on the devices in turn (the jnp scorer's
+    dispatches then run where their tile is), and ``sharded`` holds the
+    whole bank, in bank order, K-sharded for the offline kernel.
     """
     k: int
     inv: np.ndarray                     # [K] un-permutation of tile order
     tiles: Tuple[Tuple[jax.Array, jax.Array], ...]   # ([BK, M_t], [BK])
+    mesh: Optional[jax.sharding.Mesh] = None
+    sharded: Optional[Tuple[jax.Array, jax.Array]] = None  # [Kp, M], [Kp]
+
+
+def _kernel_block(k: int) -> int:
+    """Reference-tile width of the offline kernel for a K-row bank."""
+    return min(128, _pad_pow2(k))
+
+
+def shard_width(k: int, ndev: int, block: int) -> int:
+    """K padded to ``ndev`` equal shards, each a whole number of
+    ``block``-wide kernel tiles when it is wider than one tile."""
+    w = -(-k // ndev)
+    if w > block:
+        w = -(-w // block) * block
+    return w * ndev
 
 
 def build_score_plan(series, lengths=None,
-                     block_k: int = _SCORE_BLOCK_K) -> ScoreBankPlan:
+                     block_k: int = _SCORE_BLOCK_K,
+                     mesh: Optional[jax.sharding.Mesh] = None
+                     ) -> ScoreBankPlan:
     """Sort, tile, trim and upload a [K, M] bank for the offline
-    scorers.  Per-reference scores are independent of the ordering and
-    tiling, so any plan of the same bank scores identically."""
+    scorers, on one device or K-sharded over a 1-D ``mesh``.
+    Per-reference scores are independent of the ordering, tiling and
+    sharding, so any plan of the same bank scores identically."""
     series = np.asarray(series, np.float32)
     k, m = series.shape
     lengths = np.full((k,), m, np.int32) if lengths is None \
         else np.asarray(lengths, np.int32)
     order = np.argsort(lengths, kind="stable")
+    devices = [None] if mesh is None else list(mesh.devices.flat)
+
+    def put(a, t):
+        dev = devices[t % len(devices)]
+        return jnp.asarray(a) if dev is None else jax.device_put(a, dev)
     tiles = []
-    for lo in range(0, k, block_k):
+    for t, lo in enumerate(range(0, k, block_k)):
         sel = order[lo: lo + block_k]
         m_t = min(m, max(8, -(-int(lengths[sel].max()) // 8) * 8))
-        tiles.append((jnp.asarray(series[sel, :m_t]),
-                      jnp.asarray(lengths[sel])))
+        tiles.append((put(series[sel, :m_t], t), put(lengths[sel], t)))
     inv = np.empty((k,), np.int64)
     inv[order] = np.arange(k)
-    return ScoreBankPlan(k=k, inv=inv, tiles=tuple(tiles))
+    sharded = None
+    if mesh is not None:
+        kp = shard_width(k, len(devices), _kernel_block(k))
+        bank = np.zeros((kp, m), np.float32)
+        bank[:k] = series
+        lens = np.ones((kp,), np.int32)
+        lens[:k] = lengths
+        P = jax.sharding.PartitionSpec
+        axis = mesh.axis_names[0]
+        sharded = (
+            jax.device_put(bank, jax.sharding.NamedSharding(
+                mesh, P(axis, None))),
+            jax.device_put(lens, jax.sharding.NamedSharding(mesh, P(axis))))
+    return ScoreBankPlan(k=k, inv=inv, tiles=tuple(tiles), mesh=mesh,
+                         sharded=sharded)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_offline(mesh, variance: bool, approx: bool,
+                     band: Optional[int], threshold: float, block_k: int,
+                     interpret: bool):
+    """The offline kernel shard_mapped over ``mesh``: each device scores
+    its K shard of the bank against every (replicated) query, and the
+    [J, Kp] outputs stay K-sharded until the caller pulls them."""
+    from ..kernels.dtw import (score_bank_offline_kernel,
+                               score_bank_offline_var_kernel)
+    P = jax.sharding.PartitionSpec
+    axis = mesh.axis_names[0]
+    if variance:
+        body = functools.partial(
+            score_bank_offline_var_kernel, band=band, threshold=threshold,
+            block_k=block_k, interpret=interpret, approx=approx)
+        in_specs = (P(), P(), P(), P(axis, None), P(axis), P(), P(), P())
+        n_out = 3
+    else:
+        body = functools.partial(score_bank_offline_kernel, band=band,
+                                 block_k=block_k, interpret=interpret)
+        in_specs = (P(), P(), P(axis, None), P(axis), P(), P())
+        n_out = 2
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=(P(None, axis),) * n_out,
+                                 check_vma=False))
 
 
 def dtw_score_bank_many(xs, bank, lengths=None, xlens=None,
@@ -1901,6 +1980,12 @@ def dtw_score_bank_many(xs, bank, lengths=None, xlens=None,
     ref-tile) program — and to the tiled jnp wavefront elsewhere;
     ``use_kernel``/``interpret`` exist so tests can pin kernel == jnp in
     interpret mode on CPU hosts.
+
+    A ``plan`` built over a 1-D mesh (:func:`build_score_plan`) splits
+    the work over its devices: the kernel path is one shard_mapped
+    program in which each device scores its K shard of the bank, the
+    jnp path runs each tile on the device that holds it.  Scores are
+    bit-identical to the unsharded ones.
     """
     xs = np.asarray(xs, np.float32)
     if xs.ndim != 2:
@@ -1931,12 +2016,29 @@ def dtw_score_bank_many(xs, bank, lengths=None, xlens=None,
         raise ValueError(f"prob_mode must be 'exact' or 'approx', "
                          f"got {prob_mode!r}")
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = _kernel_backend()
     if k == 0:
         z = jnp.zeros((j, 0), jnp.float32)
         out = (z, z) if xvars is not None else (z,)
         out = out + (z,) if return_distances else out
         return out if len(out) > 1 else out[0]
+    if use_kernel and plan is not None and plan.mesh is not None:
+        if interpret is None:
+            from ..kernels.common import default_interpret
+            interpret = default_interpret()
+        bank_sh, lens_sh = plan.sharded
+        fn = _sharded_offline(plan.mesh, xvars is not None,
+                              prob_mode == "approx", band, float(threshold),
+                              _kernel_block(k), interpret)
+        if xvars is not None:
+            out = fn(xs, xvars, xlens, bank_sh, lens_sh, sx, sxx, vstats)
+        else:
+            out = fn(xs, xlens, bank_sh, lens_sh, sx, sxx)
+        if bank_sh.shape[0] != k:
+            out = tuple(o[:, :k] for o in out)
+        if return_distances:
+            return out
+        return out[:2] if xvars is not None else out[0]
     if xvars is not None:
         if use_kernel:
             if interpret is None:
@@ -1953,7 +2055,7 @@ def dtw_score_bank_many(xs, bank, lengths=None, xlens=None,
                 jnp.asarray(series), jnp.asarray(lengths),
                 jnp.asarray(sx), jnp.asarray(sxx), jnp.asarray(vstats),
                 band=band, threshold=float(threshold),
-                block_k=min(128, _pad_pow2(k)), interpret=interpret)
+                block_k=_kernel_block(k), interpret=interpret)
             return (scores, probs, dists) if return_distances \
                 else (scores, probs)
         # jnp path: the simple tiled wavefront always (the windowed /
@@ -1986,8 +2088,7 @@ def dtw_score_bank_many(xs, bank, lengths=None, xlens=None,
         scores, dists = score_bank_offline_kernel(
             jnp.asarray(xs), jnp.asarray(xlens), jnp.asarray(series),
             jnp.asarray(lengths), jnp.asarray(sx), jnp.asarray(sxx),
-            band=band, block_k=min(128, _pad_pow2(k)),
-            interpret=interpret)
+            band=band, block_k=_kernel_block(k), interpret=interpret)
         return (scores, dists) if return_distances else scores
     # jnp path: tile the bank in ascending-length order with a trimmed
     # per-tile width (ragged banks pay for their own lengths, not the

@@ -42,8 +42,12 @@ Tick engine (device-resident tick)
   (``kernels.dtw.stream``).
 * ``mesh=`` shards the bank: a 1-D device mesh partitions the ``[M, K]``
   reference bank and every ``[.., K]`` state slab over its single axis
-  via ``jax.shard_map``.  The sharded tick is bit-identical
-  to the unsharded one and remains ONE dispatch.  :meth:`rescale`
+  via ``jax.shard_map``: each device runs the same tick dispatch (the
+  Pallas kernel on TPU) on its K shard, and a batch of verdicts scores
+  each device's shard of the bank there.  Sharded ticks and verdicts are
+  bit-identical to unsharded ones, and a tick remains ONE dispatch.
+  ``mesh={"bank": 4}`` (the form a deployment file states) is the
+  ``bank`` axis over the first four devices.  :meth:`rescale`
   re-homes the state onto a different mesh mid-flight (or back to a
   single device) — the hook a ``runtime.fault.ElasticController``
   decision drives when hosts die or join.
@@ -160,15 +164,18 @@ boundary:
   causal-filter call of the drained jobs is a ``tuner.filter`` span,
   ``jobs``, ``samples``; ``filtered`` is 1 when it ran), ``tuner.repack``
   (``slot_repacks``, ``k_repacks``: this tick's), ``tuner.chunks``
-  (``chunk``, ``slots``), ``tuner.dispatch`` (``mode``, ``k_live``: the
-  uploads and the tick dispatch), ``tuner.pull`` (the ``[S, K]`` pull and
+  (``chunk``, ``slots``), ``tuner.dispatch`` (``mode``, ``k_live``,
+  ``shards``: the number of devices the dispatch fans over, 1 without a
+  mesh; the uploads and the tick dispatch), ``tuner.pull`` (the
+  ``[S, K]`` pull and
   its scatter to bank columns), ``tuner.decide`` (``jobs``,
   ``decisions``: the decision rule) and ``tuner.prefilter``.
 * ``tuner.finish_many`` (``jobs``), from :meth:`finish_many` and from
   each batched drain of the :meth:`finish_later` queue, holding the drain
   tick (a nested ``tuner.tick`` with ``internal=1``), ``tuner.retire``
   (``jobs``), ``tuner.verdict.pack`` (``jobs``, ``padded``, ``npad``),
-  ``tuner.verdict.dispatch``, ``tuner.verdict.pull`` and
+  ``tuner.verdict.dispatch`` (``shards``, as for the tick),
+  ``tuner.verdict.pull`` and
   ``tuner.verdict.render`` (``jobs``).
 """
 
@@ -177,6 +184,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
+import warnings
 from typing import Dict, List, Mapping, Optional, Tuple, Union
 
 import jax
@@ -200,16 +208,83 @@ from .scheduler import SlotScheduler
 __all__ = ["InFlightJob", "TuningService", "MultiTenantTuningService"]
 
 
-def _auto_axes(mesh: Optional[jax.sharding.Mesh]
+def _auto_axes(mesh: Union[jax.sharding.Mesh, Dict[str, int], None]
                ) -> Optional[jax.sharding.Mesh]:
     """``mesh`` with every axis ``Auto`` (``jax.make_mesh`` defaults to
     ``Explicit``): the state re-pack gathers and the tick's shard_map
-    then need no sharding annotations, whatever mesh the caller built."""
+    then need no sharding annotations, whatever mesh the caller built.
+    The dict form ``{"<axis>": n}``, which a deployment's JSON can state,
+    is the 1-D mesh of that axis over the first ``n`` devices; a process
+    with fewer devices gets all of them and a warning (the dispatch
+    spans' ``shards`` argument records the fan-out either way)."""
     if mesh is None:
         return None
+    if isinstance(mesh, dict):
+        if len(mesh) != 1:
+            raise ValueError("a mesh given as a dict names one bank axis "
+                             f"and its device count; got {mesh}")
+        (axis, n), = mesh.items()
+        devices = jax.devices()[: int(n)]
+        if len(devices) < int(n):
+            warnings.warn(f"mesh {mesh}: this process has {len(devices)} "
+                          "device(s); the bank shards over those",
+                          RuntimeWarning, stacklevel=3)
+        mesh = jax.make_mesh((len(devices),), (axis,), devices=devices)
     return jax.sharding.Mesh(
         mesh.devices, mesh.axis_names,
         axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
+
+
+#: tick mode -> (its ``core.dtw`` dispatch, the name of the kernel
+#: program that dispatch runs on TPU).  One device and a mesh run the
+#: same dispatch; the shard_mapped program takes the kernel program's
+#: name, so a device trace reads both as the same tick program.
+_TICK_DISPATCH = {
+    "prob": (_dtw.bank_extend_tick_scored_var_dispatch,
+             "_scored_kernel_tick_var"),
+    "approx_prob": (_dtw.bank_extend_tick_scored_var_approx_dispatch,
+                    "_scored_kernel_tick_var_approx"),
+    "scored": (_dtw.bank_extend_tick_scored_dispatch, "_scored_kernel_tick"),
+    "distance": (_dtw.bank_extend_tick_dispatch, "stream_bank_extend"),
+}
+
+
+def _tick_specs(mode: str, axis: str):
+    """(in, out) partition specs of a tick dispatch's arguments and
+    results: DP rows, moment slabs, bank, lengths and the ``[S, K]``
+    outputs split along K; per-slot arrays replicated."""
+    P = jax.sharding.PartitionSpec
+    rows, moms = P(None, None, axis), P(None, None, None, axis)
+    bank_t, per_k, out_k, rep = P(None, axis), P(axis), P(None, axis), P()
+    if mode == "distance":
+        return (rows, rep, bank_t, per_k, rep, rep, rep), (rows, rep)
+    if mode == "scored":
+        return ((rows, moms, rep, rep, rep, bank_t, per_k, rep, rep, rep),
+                (rows, moms, rep, rep, rep, out_k))
+    return ((rows, moms, rep, rep, rep, rep, bank_t, per_k, rep, rep, rep,
+             rep), (rows, moms, rep, rep, rep, out_k, rep, out_k))
+
+
+def tick_program(mode: str, mesh: Optional[jax.sharding.Mesh] = None,
+                 **kw):
+    """The tick dispatch of ``mode`` with ``kw`` (``band``,
+    ``threshold``, ``use_kernel``, ``interpret``) bound.  Over a 1-D
+    ``mesh`` it is the same dispatch shard_mapped over the bank axis and
+    jitted: each device runs it on its K shard, the Pallas kernel on
+    TPU (``use_kernel=False``: the jnp twin)."""
+    if mode not in _TICK_DISPATCH:
+        raise ValueError(f"unknown tick mode {mode!r}")
+    dispatch, kernel_name = _TICK_DISPATCH[mode]
+    fn = functools.partial(dispatch, **kw)
+    if mesh is None:
+        return fn
+    ins, outs = _tick_specs(mode, mesh.axis_names[0])
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=outs,
+                            check_vma=False)
+    twin = dispatch.__name__.removesuffix("_dispatch")
+    sharded.__name__ = (twin if kw.get("use_kernel") is False
+                        else kernel_name) + "_sharded"
+    return jax.jit(sharded)
 
 
 @dataclasses.dataclass
@@ -283,9 +358,11 @@ class TuningService:
     (rows are never collected any more; the name survives because the
     semantics — "score while in flight" — do).
 
-    ``mesh=`` (a 1-D ``jax.sharding.Mesh``) partitions the reference axis
-    K over the mesh devices; the bank is padded up to a device-count
-    multiple internally and padded rows never surface in scores.
+    ``mesh=`` (a 1-D ``jax.sharding.Mesh``, or ``{"<axis>": n}`` for that
+    axis over the first n devices) partitions the reference axis K over
+    the mesh devices, for ticks and verdicts; the bank is padded up to a
+    device-count multiple internally and padded rows never surface in
+    scores.
 
     ``prefilter_top=P`` enables the streaming wavelet prefilter: ticks
     dispatch over the pruned survivor union instead of all K references
@@ -328,7 +405,8 @@ class TuningService:
                  denoise: bool = False,
                  score_in_flight: Optional[bool] = None,
                  collect_rows: Optional[bool] = None,
-                 mesh: Optional[jax.sharding.Mesh] = None,
+                 mesh: Union[jax.sharding.Mesh, Dict[str, int],
+                             None] = None,
                  prefilter_top: Optional[int] = None,
                  prefilter_margin: float = 0.05,
                  prefilter_min_fraction: float = 0.1,
@@ -500,8 +578,7 @@ class TuningService:
         # compiled eagerly (the pre-overload behavior); the degraded
         # ladder modes compile on first use under load.
         self._tick_fns: Dict[str, Tuple] = {}
-        self._tick_fn, self._tick_fallback = \
-            self._tick_fn_for(self._base_mode())
+        self._tick_fn_for(self._base_mode())
 
         #: device dispatches issued by :meth:`tick` — the scaling invariant
         #: is one dispatch per data-carrying tick, however many jobs are
@@ -583,13 +660,21 @@ class TuningService:
         return jax.device_put(arr, jax.sharding.NamedSharding(
             self.mesh, jax.sharding.PartitionSpec(*spec)))
 
+    def _k_pad(self, k: int) -> int:
+        """``k`` padded to a device-count multiple, so the shard_map
+        fan-out divides evenly, with each shard a whole number of the
+        tick kernel's reference tiles when it is wider than one (the
+        kernel would otherwise pad the state on every tick)."""
+        if self.mesh is None:
+            return k
+        block = _dtw.TICK_BLOCK_K if _dtw._kernel_backend() else 1
+        return _dtw.shard_width(k, self._ndev, block)
+
     def _k_bucket(self, k: int) -> int:
         """Padded width of a pruned pack: power-of-two (so re-packs cycle
         through at most log2(K) compiled tick shapes), at least one VPU
-        sublane tile, and a device-count multiple so the shard_map fan-out
-        still divides evenly."""
-        kp = max(8, 1 << (max(k, 1) - 1).bit_length())
-        return kp + ((-kp) % self._ndev)
+        sublane tile, and :meth:`_k_pad`-ded."""
+        return self._k_pad(max(8, 1 << (max(k, 1) - 1).bit_length()))
 
     def _pack_device_state(self, idx: np.ndarray, rows, moms) -> None:
         """(Re)build the device-resident tick arrays over bank columns
@@ -603,11 +688,11 @@ class TuningService:
         prefilter already dropped the reference (their scores for it are
         masked on the way out of every tick).
 
-        The full pack keeps the legacy padding (K up to a device-count
-        multiple); pruned packs pad to :meth:`_k_bucket`.
+        The full pack pads K by :meth:`_k_pad`; pruned packs pad to
+        :meth:`_k_bucket`.
         """
         k_new, m, axis = len(idx), self._m, self._axis
-        kp = self._k + ((-self._k) % self._ndev) if k_new == self._k \
+        kp = self._k_pad(self._k) if k_new == self._k \
             else self._k_bucket(k_new)
         series_t = np.zeros((m, kp), np.float32)
         series_t[:, :k_new] = self._full_series_t[:, idx]
@@ -848,7 +933,7 @@ class TuningService:
         grown = not np.isin(idx, self._packed_idx,
                             assume_unique=True).all()
         full = len(idx) == self._k
-        kp_target = self._k + ((-self._k) % self._ndev) if full \
+        kp_target = self._k_pad(self._k) if full \
             else self._k_bucket(len(idx))
         if not grown and kp_target >= self._kp:
             return
@@ -884,16 +969,18 @@ class TuningService:
         compiles at construction, degraded modes on first use."""
         fns = self._tick_fns.get(mode)
         if fns is None:
-            fns = self._build_tick_fn(self._axis, mode)
+            fns = self._build_tick_fn(mode)
             self._tick_fns[mode] = fns
         return fns
 
-    def _build_tick_fn(self, axis: Optional[str], mode: str):
-        """The ONE jitted callable a tick dispatches: fused scored extend
-        (or the distance-only variant), optionally shard_mapped over the
-        bank axis.  Sharding is exact — every DP cell and score is a
-        per-reference quantity, so the fan-out computes disjoint K slices
-        and the [S, K] score gather is the only cross-device output.
+    def _build_tick_fn(self, mode: str):
+        """The ONE callable a tick dispatches, and its fallback: the
+        ``core.dtw`` dispatch of ``mode`` (fused scored extend, its
+        probability twins, or the distance-only variant), shard_mapped
+        over the bank axis under a mesh (:func:`tick_program`).
+        Sharding is exact — every DP cell and score is a per-reference
+        quantity, so the fan-out computes disjoint K slices and the
+        [S, K] score gather is the only cross-device output.
 
         ``mode`` selects the dispatch flavor (``"prob"`` /
         ``"approx_prob"`` / ``"scored"`` / ``"distance"``): the
@@ -903,136 +990,23 @@ class TuningService:
         degraded tick leaves the rows bitwise what the full tick would
         have computed and only side channels go stale).
 
-        Returns ``(tick_fn, fallback_fn_or_None)``.  On the unsharded
-        paths the fallback is the same dispatch pinned to the jnp
-        wavefront twin (``use_kernel=False``) — bit-identical to the
-        Pallas kernel, so a degraded tick after retry exhaustion changes
-        latency, never results.  The shard_mapped paths already close
-        over the jnp impl, so their fallback is None (retries only)."""
-        band = self.band
-        if mode == "prob":
-            threshold = float(self.threshold)
-            if self.mesh is None:
-                # probabilistic twin: six moment slabs + variance
-                # folds through the same kernel machinery, probs
-                # beside scores.  Separate entry point, so the exact
-                # tick's compiled graph is untouched.
-                return (functools.partial(
-                    _dtw.bank_extend_tick_scored_var_dispatch,
-                    band=band, threshold=threshold),
-                    functools.partial(
-                        _dtw.bank_extend_tick_scored_var_dispatch,
-                        band=band, threshold=threshold,
-                        use_kernel=False))
-
-            def inner_var(rows, moms, ns, sx, sxx, vstats, bank_t,
-                          lengths, chunks, vchunks, nvalid, qlens):
-                return _dtw._bank_extend_diag_impl(
-                    rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
-                    nvalid, qlens, band=band, score=True,
-                    vchunks=vchunks, vstats=vstats,
-                    threshold=threshold)
-            P = jax.sharding.PartitionSpec
-            return jax.jit(jax.shard_map(
-                inner_var, mesh=self.mesh, check_vma=False,
-                in_specs=(P(None, None, axis),
-                          P(None, None, None, axis),
-                          P(), P(), P(), P(None, None), P(None, axis),
-                          P(axis), P(), P(), P(), P()),
-                out_specs=(P(None, None, axis),
-                           P(None, None, None, axis),
-                           P(), P(), P(), P(None, axis),
-                           P(None, None), P(None, axis)))), None
-        if mode == "approx_prob":
-            threshold = float(self.threshold)
-            if self.mesh is None:
-                # approximate-tail twin: FOUR moment slabs (sy, syy,
-                # sxy, svy) through the same kernel machinery; svyy and
-                # svxy are reconstructed at the score tail from the
-                # per-slot variance folds (core.dtw's
-                # _prob_from_moments_approx), trading a tolerance-band
-                # probability error for ~2 fewer slab channels per
-                # cell.  Separate entry point: neither the exact prob
-                # graph nor the scored graph is touched.
-                return (functools.partial(
-                    _dtw.bank_extend_tick_scored_var_approx_dispatch,
-                    band=band, threshold=threshold),
-                    functools.partial(
-                        _dtw.bank_extend_tick_scored_var_approx_dispatch,
-                        band=band, threshold=threshold,
-                        use_kernel=False))
-
-            def inner_approx(rows, moms, ns, sx, sxx, vstats, bank_t,
-                             lengths, chunks, vchunks, nvalid, qlens):
-                return _dtw._bank_extend_diag_impl(
-                    rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
-                    nvalid, qlens, band=band, score=True,
-                    vchunks=vchunks, vstats=vstats,
-                    threshold=threshold)
-            P = jax.sharding.PartitionSpec
-            return jax.jit(jax.shard_map(
-                inner_approx, mesh=self.mesh, check_vma=False,
-                in_specs=(P(None, None, axis),
-                          P(None, None, None, axis),
-                          P(), P(), P(), P(None, None), P(None, axis),
-                          P(axis), P(), P(), P(), P()),
-                out_specs=(P(None, None, axis),
-                           P(None, None, None, axis),
-                           P(), P(), P(), P(None, axis),
-                           P(None, None), P(None, axis)))), None
-        if mode == "scored":
-            if self.mesh is None:
-                # routes to the moment-carrying Pallas streaming kernel on
-                # TPU (DP row + (sy, syy, sxy) slabs pinned in VMEM across
-                # the chunk), the jnp wavefront elsewhere.
-                return (functools.partial(
-                    _dtw.bank_extend_tick_scored_dispatch, band=band),
-                    functools.partial(
-                        _dtw.bank_extend_tick_scored_dispatch, band=band,
-                        use_kernel=False))
-
-            def inner(rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
-                      nvalid, qlens):
-                return _dtw._bank_extend_diag_impl(
-                    rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
-                    nvalid, qlens, band=band, score=True)
-            P = jax.sharding.PartitionSpec
-            return jax.jit(jax.shard_map(
-                inner, mesh=self.mesh, check_vma=False,
-                in_specs=(P(None, None, axis), P(None, None, None, axis),
-                          P(), P(), P(), P(None, axis), P(axis), P(), P(),
-                          P()),
-                out_specs=(P(None, None, axis), P(None, None, None, axis),
-                           P(), P(), P(), P(None, axis)))), None
-
-        if mode != "distance":
-            raise ValueError(f"unknown tick mode {mode!r}")
-        if self.mesh is None:
-            # bank_extend_tick_dispatch routes to the Pallas streaming
-            # kernel on TPU and the (already-jitted) jnp wavefront
-            # elsewhere.
-            return (functools.partial(_dtw.bank_extend_tick_dispatch,
-                                      band=band),
-                    functools.partial(_dtw.bank_extend_tick_dispatch,
-                                      band=band, use_kernel=False))
-
-        def inner(rows, ns, bank_t, lengths, chunks, nvalid, qlens):
-            return _dtw.bank_extend_tick(rows, ns, bank_t, lengths, chunks,
-                                         nvalid, qlens, band=band)
-        P = jax.sharding.PartitionSpec
-        return jax.jit(jax.shard_map(
-            inner, mesh=self.mesh, check_vma=False,
-            in_specs=(P(None, None, axis), P(), P(None, axis), P(axis),
-                      P(), P(), P()),
-            out_specs=(P(None, None, axis), P()))), None
+        Returns ``(tick_fn, fallback_fn)``.  The fallback is the same
+        dispatch pinned to the jnp wavefront twin (``use_kernel=False``)
+        — bit-identical to the Pallas kernel, so a degraded tick after
+        retry exhaustion changes latency, never results."""
+        kw = dict(band=self.band)
+        if mode in ("prob", "approx_prob"):
+            kw["threshold"] = float(self.threshold)
+        return (tick_program(mode, self.mesh, **kw),
+                tick_program(mode, self.mesh, use_kernel=False, **kw))
 
     # -- dispatch resilience --------------------------------------------------
     def _dispatch_resilient(self, fn, fallback, args, kind: str):
         """Run one device dispatch, ``fn(*args)``, through the
         retry/backoff wrapper.
 
-        ``fallback`` is the jnp wavefront twin on unsharded paths (None
-        when ``fn`` already is jnp), called with the same ``args``.
+        ``fallback`` is the jnp wavefront twin of ``fn``, called with the
+        same ``args``.
         Transient device errors — and chaos-injected ones, consulted per
         *attempt* so a fault burst spans retries — are retried per
         ``self.retry_policy``; after exhaustion the fallback serves the
@@ -1057,7 +1031,7 @@ class TuningService:
         seeded probe re-tries the primary once per probe slot, and a
         success re-promotes the kernel path (``degraded`` clears)."""
         chaos = self.chaos
-        breaker = self.breaker if fallback is not None else None
+        breaker = self.breaker
         if chaos is None and self.retry_policy is None and breaker is None:
             return fn(*args)
         sig = (kind,) + tuple((a.shape, a.dtype) for a in args
@@ -1093,7 +1067,7 @@ class TuningService:
                                                   base_delay=0.0)
         result, report = call_with_retry(
             attempt, policy=policy, transient=transient,
-            fallback=None if fallback is None else lambda: fallback(*args))
+            fallback=lambda: fallback(*args))
         self.retry_count += report["retries"]
         if report["degraded"]:
             self.degraded_dispatch_count += 1
@@ -1118,9 +1092,11 @@ class TuningService:
         self.evict(job_id)
 
     # -- elastic rescale ------------------------------------------------------
-    def rescale(self, mesh: Optional[jax.sharding.Mesh]) -> None:
-        """Re-home the device state onto a different 1-D mesh (or back
-        to a single device with ``mesh=None``) mid-flight — the hook a
+    def rescale(self, mesh: Union[jax.sharding.Mesh, Dict[str, int],
+                                  None]) -> None:
+        """Re-home the device state onto a different 1-D mesh (either
+        form ``mesh=`` takes, or back to a single device with
+        ``mesh=None``) mid-flight — the hook a
         ``runtime.fault.ElasticController`` rescale decision drives when
         hosts die or join.  The bank re-pads to the new device-count
         multiple and every state slab moves by the same on-device gather
@@ -1145,8 +1121,7 @@ class TuningService:
         self._pack_device_state(self._packed_idx, rows, moms)
         self._tick_fns = {}            # per-mode callables are mesh-bound
         self._executed.clear()
-        self._tick_fn, self._tick_fallback = \
-            self._tick_fn_for(self._base_mode())
+        self._tick_fn_for(self._base_mode())
         self.rescale_count += 1
 
     # -- job lifecycle -------------------------------------------------------
@@ -1398,7 +1373,8 @@ class TuningService:
         base = self._base_mode()
         tick_fn, tick_fb = self._tick_fn_for(mode)
         scores = probs = None
-        with TraceAnnotation("tuner.dispatch", mode=mode, k_live=k_live):
+        with TraceAnnotation("tuner.dispatch", mode=mode, k_live=k_live,
+                             shards=self._ndev):
             if mode == "prob":
                 args = (self._rows, self._moms, self._ns, self._sx,
                         self._sxx, self._vstats, self._bank_t,
@@ -1656,8 +1632,9 @@ class TuningService:
             return _dtw.dtw_score_bank_many(
                 xs, self.bank.series, self.bank.lengths, xlens=xl,
                 band=self.band, sx=sx, sxx=sxx,
-                plan=self.bank.score_plan(), use_kernel=use_kernel, **kw)
-        with TraceAnnotation("tuner.verdict.dispatch"):
+                plan=self.bank.score_plan(self.mesh), use_kernel=use_kernel,
+                **kw)
+        with TraceAnnotation("tuner.verdict.dispatch", shards=self._ndev):
             res = self._dispatch_resilient(
                 call, functools.partial(call, use_kernel=False),
                 (xs, xl, sx, sxx), "verdict")

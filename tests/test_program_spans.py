@@ -144,7 +144,7 @@ def test_tick_and_finish_spans(tmp_path, bank, mode):
         chunks, = tick.find("tuner.chunks")
         assert chunks.args == {"chunk": PUSH, "slots": svc.slot_capacity}
         dispatch, = tick.find("tuner.dispatch")
-        assert dispatch.args == {"mode": mode, "k_live": K}
+        assert dispatch.args == {"mode": mode, "k_live": K, "shards": 1}
         decide, = tick.find("tuner.decide")
         assert decide.args["jobs"] == JOBS
         assert 0 <= decide.args["decisions"] <= JOBS
@@ -162,6 +162,7 @@ def test_tick_and_finish_spans(tmp_path, bank, mode):
     assert fin.find("tuner.retire")[0].args == {"jobs": 2}
     assert fin.find("tuner.verdict.pack")[0].args == {
         "jobs": 2, "padded": 2, "npad": 32}
+    assert fin.find("tuner.verdict.dispatch")[0].args == {"shards": 1}
     assert fin.find("tuner.verdict.render")[0].args == {"jobs": 2}
     assert svc.ticks == TICKS + 1
 

@@ -6,8 +6,11 @@ limit).  These tests compile each kernel of the serving path for a
 described, unattached ``v5e:2x2`` chip at the shapes ``chip_smoke.py``
 serves (S jobs x K=1024 references x M=512 samples), and check that the
 compiled program holds the Mosaic kernel (``tpu_custom_call``) and fits
-one chip's 16 GB of HBM.  Nothing runs, so results are not checked here
-(the interpret-mode equivalence suites pin them).
+one chip's 16 GB of HBM.  The four-chip deployment's tick and verdict
+(a 4,096-run bank K-sharded over the 2x2 host) compile over the four
+chips and fit each, while the same tick on one chip does not.  Nothing
+runs, so results are not checked here (the interpret-mode equivalence
+suites pin them).
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler's library.
@@ -120,3 +123,84 @@ def test_offline_verdict_compiles(spec, nch, j, band):
             return score_bank_offline_var_kernel(
                 *a, band=band, threshold=0.9, interpret=False)
     _check(fn, *args)
+
+
+# -- the four-chip deployment: a 4,096-run bank K-sharded over a 2x2 host --
+
+K4, S4, J4 = 4096, 256, 32
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    import numpy as np
+    return jax.sharding.Mesh(
+        np.asarray(topo.devices[:4]), ("bank",),
+        axis_types=(jax.sharding.AxisType.Auto,))
+
+
+def _planned(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def _tick_args(make, k):
+    """The scored tick's arguments; ``make(shape, k_axis, dtype)``."""
+    i32 = jnp.int32
+    return (make((S4, M, k), 2), make((3, S4, M, k), 3),
+            make((S4,), None, i32), make((S4,)), make((S4,)),
+            make((M, k), 1), make((k,), 0, i32),
+            make((S4, C)), make((S4,), None, i32), make((S4,), None, i32))
+
+
+def test_sharded_tick_and_verdict_fit_four_chips(mesh4):
+    """The scored tick shard_mapped over the bank axis (each chip runs
+    the Mosaic kernel on its 1,024 references) and the K-sharded verdict
+    both compile for the 2x2 host, and each chip's planned bytes fit its
+    16 GB."""
+    from repro.serve.tuning import tick_program
+    P = jax.sharding.PartitionSpec
+
+    def make(shape, k_axis=None, dtype=jnp.float32):
+        spec = [None] * len(shape)
+        if k_axis is not None:
+            spec[k_axis] = "bank"
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=(
+            jax.sharding.NamedSharding(mesh4, P(*spec))))
+
+    tick = tick_program("scored", mesh4, band=8, use_kernel=True,
+                        interpret=False)
+    compiled = tick.lower(*_tick_args(make, K4)).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel"
+    assert compiled.input_shardings[0][0].num_devices == 4
+    assert _planned(compiled) < HBM_BYTES, \
+        f"{_planned(compiled) / 1e9:.2f} GB does not fit one chip"
+
+    verdict = _dtw._sharded_offline(mesh4, False, False, 8, 0.9, 128, False)
+    i32 = jnp.int32
+    compiled = verdict.lower(
+        make((J4, N)), make((J4,), None, i32), make((K4, M), 0),
+        make((K4,), 0, i32), make((J4,)), make((J4,))).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel"
+    assert compiled.input_shardings[0][2].num_devices == 4
+    assert _planned(compiled) < HBM_BYTES
+
+
+def test_unsharded_tick_does_not_fit_one_chip(spec):
+    """The same tick over all 4,096 references on one chip needs more
+    than its 16 GB (the compiler refuses it): the deployment needs the
+    four chips."""
+    from jax.errors import JaxRuntimeError
+    from repro.serve.tuning import tick_program
+
+    def make(shape, k_axis=None, dtype=jnp.float32):
+        return spec(shape, dtype)
+
+    tick = tick_program("scored", band=8, use_kernel=True, interpret=False)
+    try:
+        compiled = jax.jit(tick).lower(*_tick_args(make, K4)).compile()
+    except JaxRuntimeError as e:
+        assert "RESOURCE_EXHAUSTED" in str(e), e
+        return
+    assert _planned(compiled) > HBM_BYTES, \
+        f"{_planned(compiled) / 1e9:.2f} GB fits one chip"
