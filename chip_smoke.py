@@ -200,9 +200,34 @@ def kernel_served(rec: TickRecorder) -> dict:
         "temp_size_in_bytes", "alias_size_in_bytes")}
 
 
+def rank(svc, sims):
+    """The service's own rank of one job's [K] scores."""
+    return svc._ranks(np.asarray(sims)[None], None)[0]
+
+
 def leader(svc, sims) -> str:
-    """The service's own ranking of one job's [K] scores."""
-    return svc._rank(svc._reduce(sims))[0]
+    """The workload the service ranks first on one job's [K] scores."""
+    return svc._workloads[rank(svc, sims).leader]
+
+
+def check_final(phase: str, svc, d, matrix_sims, point: bool) -> float:
+    """A final decision against the service's rank of the matrix path's
+    [K] scores of the same query: the same leader (the first maximum of
+    ``d.scores``) and, on the point rule, the same match -> the largest
+    per-workload score difference."""
+    ref = rank(svc, matrix_sims)
+    ref_leader = svc._workloads[ref.leader]
+    got = max(d.scores, key=d.scores.get)
+    if got != ref_leader:
+        raise AssertionError(f"{phase}: final leader of {d.workload}: "
+                             f"{got} vs matrix path {ref_leader}")
+    if point:
+        ref_match = ref_leader if ref.score >= svc.threshold else None
+        if d.matched != ref_match:
+            raise AssertionError(f"{phase}: final match of {d.workload}: "
+                                 f"{d.matched} vs {ref_match}")
+    return max(abs(d.scores[w] - s)
+               for w, s in zip(svc._workloads, ref.scores.tolist()))
 
 
 def push_tick(svc, jobs, t: int) -> bool:
@@ -296,20 +321,10 @@ def run_phase(name: str, db, jobs, *, slots: int, ticks: int,
     # verdicts against the matrix path (dtw_matrix + backtrack + corr)
     bank, worst = db.bank(), 0.0
     for j in checked:
-        ref = svc._reduce(similarity_bank(queries[j], bank, band=kw.get(
-            "band"), matrix_path=True))
-        d = finals[j]
-        worst = max(worst, max(abs(d.scores[w] - s) for w, s in ref.items()))
-        ref_leader, ref_score, _ = svc._rank(ref)
-        if svc._rank(d.scores)[0] != ref_leader:
-            raise AssertionError(f"{name}: final leader of {j}: "
-                                 f"{svc._rank(d.scores)[0]} vs matrix "
-                                 f"path {ref_leader}")
-        if "min_probability" not in kw:
-            ref_match = ref_leader if ref_score >= svc.threshold else None
-            if d.matched != ref_match:
-                raise AssertionError(f"{name}: final match of {j}: "
-                                     f"{d.matched} vs {ref_match}")
+        worst = max(worst, check_final(
+            name, svc, finals[j], similarity_bank(
+                queries[j], bank, band=kw.get("band"), matrix_path=True),
+            point="min_probability" not in kw))
     if not worst <= FINAL_TOL:
         raise AssertionError(f"{name}: verdict vs matrix path error "
                              f"{worst:.3g} > {FINAL_TOL}")

@@ -169,14 +169,16 @@ boundary:
   mesh; the uploads and the tick dispatch), ``tuner.pull`` (the
   ``[S, K]`` pull and
   its scatter to bank columns), ``tuner.decide`` (``jobs``,
-  ``decisions``: the decision rule) and ``tuner.prefilter``.
+  ``ranked``: the rows ranked by the one batched call of the decision
+  rule, ``decisions``) and ``tuner.prefilter``.
 * ``tuner.finish_many`` (``jobs``), from :meth:`finish_many` and from
   each batched drain of the :meth:`finish_later` queue, holding the drain
   tick (a nested ``tuner.tick`` with ``internal=1``), ``tuner.retire``
   (``jobs``), ``tuner.verdict.pack`` (``jobs``, ``padded``, ``npad``),
   ``tuner.verdict.dispatch`` (``shards``, as for the tick),
   ``tuner.verdict.pull`` and
-  ``tuner.verdict.render`` (``jobs``).
+  ``tuner.verdict.render`` (``jobs``, ``ranked``: the verdict rows
+  ranked in its one batched call).
 """
 
 from __future__ import annotations
@@ -185,7 +187,7 @@ import dataclasses
 import functools
 import time
 import warnings
-from typing import Dict, List, Mapping, Optional, Tuple, Union
+from typing import Dict, List, Mapping, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -287,6 +289,17 @@ def tick_program(mode: str, mesh: Optional[jax.sharding.Mesh] = None,
     return jax.jit(sharded)
 
 
+class _Rank(NamedTuple):
+    """One row's rank: the [W] per-workload scores, the leading
+    workload's index and score, the runner-up's score, and (probabilistic
+    mode) the leader's match probability."""
+    scores: np.ndarray
+    leader: int
+    score: float
+    runner_up: float
+    probability: Optional[float]
+
+
 @dataclasses.dataclass
 class InFlightJob:
     """Host-side bookkeeping for one slot (device state lives stacked in
@@ -328,6 +341,10 @@ class InFlightJob:
     #: the final verdict recomputes offline from the full query and is
     #: bitwise unchanged).
     degraded_level: int = 0
+    #: this tick's rank of ``last_sims``, from the decision rule's one
+    #: batched call: the hand-off to :meth:`TuningService._maybe_decide`,
+    #: which stays per job because fault injection wraps it per job.
+    rank: Optional[_Rank] = None
 
     @property
     def fraction_seen(self) -> float:
@@ -436,7 +453,17 @@ class TuningService:
             score_in_flight = True if collect_rows is None else collect_rows
         self._labels: Tuple[str, ...] = self.bank.labels or tuple(
             f"ref{k}" for k in range(len(self.bank)))
-        self._n_workloads = len(set(self._labels))
+        # the decision rule's column grouping, read once from the labels:
+        # workloads in first-appearance order (the rank's tie-break), a
+        # stable permutation making each workload's columns contiguous
+        # and each group's first position.
+        self._workloads: Tuple[str, ...] = tuple(dict.fromkeys(self._labels))
+        self._n_workloads = len(self._workloads)
+        code = {w: i for i, w in enumerate(self._workloads)}
+        group = np.array([code[lbl] for lbl in self._labels], np.int64)
+        self._col_perm = np.argsort(group, kind="stable")
+        self._col_starts = np.searchsorted(group[self._col_perm],
+                                           np.arange(self._n_workloads))
         if min_probability is not None:
             if not (0.0 < min_probability <= 1.0):
                 raise ValueError("min_probability must be in (0, 1]")
@@ -1456,32 +1483,44 @@ class TuningService:
                 for job, *_ in pending:
                     job.degraded_level = max(job.degraded_level, lvl)
 
-            decisions = 0
+            # a level-2 job's moment/query-stat channels are stale, so
+            # any score a later scored tick emits for its slot is
+            # garbage: freeze last_sims/last_probs at their last exact
+            # values instead of overwriting them.
+            fresh = []
             for job, ch, _ in pending:
                 job.n += ch.shape[0]
-                decision = None
-                # a level-2 job's moment/query-stat channels are stale,
-                # so any score a later scored tick emits for its slot is
-                # garbage: freeze last_sims/last_probs at their last
-                # exact values instead of overwriting them.
                 if sims_all is not None and job.degraded_level < 2:
-                    sims = sims_all[job.slot]
+                    fresh.append(job)
+            ranked: Dict[str, Optional[TuneDecision]] = {}
+            if fresh:
+                slots = np.array([job.slot for job in fresh])
+                sims = sims_all[slots]
+                prs = probs_all[slots] if probs_all is not None else None
+                for i, job in enumerate(fresh):
                     if job.allowed is not None:
                         # a column another job kept alive may be pruned
                         # for THIS job: mask it out of this job's view.
-                        sims = np.where(job.allowed, sims, -np.inf)
-                    job.last_sims = sims
-                    if probs_all is not None:
-                        pr = probs_all[job.slot]
-                        if job.allowed is not None:
-                            pr = np.where(job.allowed, pr, 0.0)
-                        job.last_probs = pr
-                    if job.early is None and job.degraded_level == 0:
-                        decision = self._maybe_decide(job)
-                        decisions += decision is not None
+                        sims[i, ~job.allowed] = -np.inf
+                        if prs is not None:
+                            prs[i, ~job.allowed] = 0.0
+                    job.last_sims = sims[i]
+                    if prs is not None:
+                        job.last_probs = prs[i]
+                rows = [i for i, job in enumerate(fresh)
+                        if job.early is None and job.degraded_level == 0
+                        and job.n >= 2]
+                if rows:
+                    for i, rank in zip(rows, self._ranks(
+                            sims[rows], None if prs is None else prs[rows])):
+                        fresh[i].rank = rank
+                        ranked[fresh[i].job_id] = self._maybe_decide(fresh[i])
+            for job, *_ in pending:
                 if out.get(job.job_id) is None:
-                    out[job.job_id] = decision
-            span.set_metadata(jobs=len(pending), decisions=decisions)
+                    out[job.job_id] = ranked.get(job.job_id)
+            span.set_metadata(
+                jobs=len(pending), ranked=len(ranked),
+                decisions=sum(d is not None for d in ranked.values()))
         # prune with THIS tick's information (scores just computed, n just
         # advanced): eviction decisions lag the data by zero ticks, the
         # re-pack they imply happens at the top of the next tick.
@@ -1491,29 +1530,40 @@ class TuningService:
         return out
 
     # -- decision rule -------------------------------------------------------
-    def _reduce(self, sims: np.ndarray) -> Dict[str, float]:
-        """Per-workload best over the bank's (possibly multi-entry) rows."""
-        scores: Dict[str, float] = {}
-        for lbl, s in zip(self._labels, sims):
-            scores[lbl] = max(scores.get(lbl, -1.0), float(s))
-        return scores
+    def _workload_max(self, rows: np.ndarray) -> np.ndarray:
+        """[J, W] per-workload best of [J, K] rows, workloads in
+        first-appearance order: NaN ignored, floored at -1.0."""
+        grouped = np.take(rows, self._col_perm, axis=1)
+        return np.fmax(np.fmax.reduceat(grouped, self._col_starts, axis=1),
+                       -1.0)
 
-    @staticmethod
-    def _rank(scores: Dict[str, float]) -> Tuple[str, float, float]:
-        """(leader, leader_score, runner_up_score); insertion order breaks
-        ties so repeated ticks rank deterministically."""
-        leader, ls = None, -np.inf
-        for w, s in scores.items():
-            if s > ls:
-                leader, ls = w, s
-        rs = max((s for w, s in scores.items() if w != leader), default=-1.0)
-        return leader, ls, rs
+    def _ranks(self, sims: np.ndarray, probs: Optional[np.ndarray]
+               ) -> List[_Rank]:
+        """Rank [J, K] rows in one batched reduce -> each row's rank.
+        The first maximum leads, so repeated ticks rank
+        deterministically; the runner-up is -1.0 with one workload. In
+        probabilistic mode ``probs`` holds the rows' match probabilities,
+        reduced the same way and read at the leader."""
+        w = self._workload_max(sims)
+        at = np.arange(w.shape[0])
+        lead = np.argmax(w, axis=1)
+        if w.shape[1] < 2:
+            rs = np.full(w.shape[0], -1.0)
+        else:
+            others = w.copy()
+            others[at, lead] = -np.inf
+            rs = others.max(axis=1)
+        lps = (self._workload_max(probs)[at, lead].tolist()
+               if probs is not None else [None] * len(lead))
+        return [_Rank(*r) for r in zip(w, lead.tolist(),
+                                       w[at, lead].tolist(), rs.tolist(),
+                                       lps)]
 
     def _maybe_decide(self, job: InFlightJob) -> Optional[TuneDecision]:
-        if job.n < 2:
-            return None
-        scores = self._reduce(job.last_sims)
-        leader, ls, rs = self._rank(scores)
+        """Step the job's leader/stability state from its rank this tick
+        (``job.rank``) -> its early decision, or None."""
+        leader = self._workloads[job.rank.leader]
+        ls, rs, lp = job.rank.score, job.rank.runner_up, job.rank.probability
         # the margin test needs a real runner-up: with < 2 workloads in
         # the bank it would be vacuously true (rs == -1.0), so the
         # service abstains in flight instead of fast-tracking the only
@@ -1530,9 +1580,7 @@ class TuningService:
         # even when the point estimate momentarily clears the threshold.
         # At zero input variance the probability is exactly
         # 1{corr >= threshold}, so the two gates coincide bitwise.
-        lp = None
         if self.min_probability is not None:
-            lp = self._reduce(job.last_probs).get(leader, 0.0)
             confident = lp >= self.min_probability
         else:
             confident = ls >= self.threshold
@@ -1542,7 +1590,8 @@ class TuningService:
             cfg = self.db.best_config(leader) if self.db is not None else None
             job.early = TuneDecision(
                 workload=job.job_id, matched=leader, corr=ls, config=cfg,
-                scores=scores, fraction_seen=job.fraction_seen, final=False,
+                scores=dict(zip(self._workloads, job.rank.scores.tolist())),
+                fraction_seen=job.fraction_seen, final=False,
                 decided_at_fraction=job.fraction_seen, probability=lp)
             return job.early
         return None
@@ -1650,14 +1699,24 @@ class TuningService:
                     pout[i] = probs[r]
         return out, pout
 
-    def _render_verdict(self, job_id: str, sims: np.ndarray,
-                        early: Optional[TuneDecision],
-                        probs: Optional[np.ndarray] = None) -> TuneDecision:
-        scores = self._reduce(sims)
-        leader, ls, _ = self._rank(scores)
-        lp = None
+    def _render_verdicts(self, ids: List[str], sims: np.ndarray,
+                         probs: Optional[np.ndarray],
+                         earlies: List[Optional[TuneDecision]]
+                         ) -> Dict[str, TuneDecision]:
+        """Final decisions for the [J, K] verdict block, ranked in one
+        call."""
+        with TraceAnnotation("tuner.verdict.render", jobs=len(ids)) as span:
+            out = {jid: self._render_verdict(jid, early, rank)
+                   for jid, early, rank in zip(ids, earlies,
+                                               self._ranks(sims, probs))}
+            span.set_metadata(ranked=len(ids))
+        return out
+
+    def _render_verdict(self, job_id: str, early: Optional[TuneDecision],
+                        rank: _Rank) -> TuneDecision:
+        leader, ls, lp = self._workloads[rank.leader], rank.score, \
+            rank.probability
         if self.min_probability is not None:
-            lp = self._reduce(probs).get(leader, 0.0)
             matched = leader if lp >= self.min_probability else None
         else:
             matched = leader if ls >= self.threshold else None
@@ -1665,7 +1724,8 @@ class TuningService:
             if self.db is not None and matched is not None else None
         decision = TuneDecision(
             workload=job_id, matched=matched, corr=ls, config=cfg,
-            scores=scores, fraction_seen=1.0, final=True,
+            scores=dict(zip(self._workloads, rank.scores.tolist())),
+            fraction_seen=1.0, final=True,
             decided_at_fraction=(early.decided_at_fraction
                                  if early is not None else 1.0),
             probability=lp)
@@ -1729,11 +1789,8 @@ class TuningService:
                 retired = [self._retire(j) for j in ids]
             sims, probs = self._verdict_scores([x for x, _, _ in retired],
                                                [v for _, v, _ in retired])
-            with TraceAnnotation("tuner.verdict.render", jobs=len(ids)):
-                return {jid: self._render_verdict(
-                            jid, sims[i], retired[i][2],
-                            None if probs is None else probs[i])
-                        for i, jid in enumerate(ids)}
+            return self._render_verdicts(ids, sims, probs,
+                                         [early for _, _, early in retired])
 
     def finish_later(self, job_id: str) -> None:
         """Deferred finish: the job leaves its slot now (so slots
@@ -1767,11 +1824,8 @@ class TuningService:
         with TraceAnnotation("tuner.finish_many", jobs=len(queued)):
             sims, probs = self._verdict_scores(
                 [x for _, x, _, _ in queued], [v for _, _, v, _ in queued])
-            with TraceAnnotation("tuner.verdict.render", jobs=len(queued)):
-                return {jid: self._render_verdict(
-                            jid, sims[i], early,
-                            None if probs is None else probs[i])
-                        for i, (jid, _, _, early) in enumerate(queued)}
+            return self._render_verdicts([jid for jid, *_ in queued], sims,
+                                         probs, [e for *_, e in queued])
 
     def drain_finishes(self) -> Dict[str, TuneDecision]:
         """Render every deferred verdict (one batched dispatch), plus any

@@ -148,6 +148,10 @@ def test_tick_and_finish_spans(tmp_path, bank, mode):
         decide, = tick.find("tuner.decide")
         assert decide.args["jobs"] == JOBS
         assert 0 <= decide.args["decisions"] <= JOBS
+        # no early decision can come before the third tick, so each of
+        # these ticks ranks every scored job in its one batched call
+        scored = mode != "distance"
+        assert decide.args["ranked"] == (JOBS if scored else 0)
 
     fin = roots[-1]
     assert fin.args == {"jobs": 2}
@@ -155,6 +159,8 @@ def test_tick_and_finish_spans(tmp_path, bank, mode):
     inner, = fin.find("tuner.tick")
     assert inner.args == {"tick": TICKS + 1, "internal": 1}
     assert inner.names() == want
+    inner_decide, = inner.find("tuner.decide")
+    assert 0 <= inner_decide.args["ranked"] <= inner_decide.args["jobs"]
     inner_drain, = inner.find("tuner.drain")
     assert inner_drain.args == {"jobs": 2, "samples": 6, "filtered": 1}
     assert [f.args for f in inner_drain.find("tuner.filter")] == [
@@ -163,7 +169,8 @@ def test_tick_and_finish_spans(tmp_path, bank, mode):
     assert fin.find("tuner.verdict.pack")[0].args == {
         "jobs": 2, "padded": 2, "npad": 32}
     assert fin.find("tuner.verdict.dispatch")[0].args == {"shards": 1}
-    assert fin.find("tuner.verdict.render")[0].args == {"jobs": 2}
+    assert fin.find("tuner.verdict.render")[0].args == {"jobs": 2,
+                                                         "ranked": 2}
     assert svc.ticks == TICKS + 1
 
 
