@@ -52,24 +52,24 @@ every DP in a single jit dispatch:
   the **streaming** engine: the DP state is carried across arriving query
   chunks (row-wise [K, M] carry), so an in-flight job can be matched while
   it executes; any chunking reproduces the one-shot solve exactly.
-* :func:`bank_extend_tick` / :func:`bank_extend_tick_scored` — the
-  **device-resident service tick** (serve.tuning's hot path): the same
-  streaming recurrence evaluated along anti-diagonals of the chunk block
-  (no per-sample [J, K, M] cost slab, no log(M) in-row scan), K-last
-  layout so the reference axis vectorizes and shards, optionally fused
-  with on-device open-end prefix scoring (warp-path correlation moments
-  carried through the DP, [J, K] scores out — no row stack ever leaves
-  the device).  On TPU both tick flavors route to the Pallas streaming
-  kernels (``kernels.dtw.stream``): the distance-only tick via
-  :func:`bank_extend_tick_dispatch`, the fused scoring tick via
-  :func:`bank_extend_tick_scored_dispatch` (DP row AND the three moment
-  slabs pinned in VMEM across the whole chunk).
+* :func:`bank_extend_tick` — the **device-resident service tick**
+  (serve.tuning's hot path): the same streaming recurrence evaluated
+  along anti-diagonals of the chunk block (no per-sample [J, K, M] cost
+  slab, no log(M) in-row scan), K-last layout so the reference axis
+  vectorizes and shards, fused with on-device open-end prefix scoring
+  when given a moment slab (warp-path correlation moments carried
+  through the DP, [J, K] scores out — no row stack ever leaves the
+  device).  One entry point for every channel set (none: distance only;
+  3: scored; 4 / 6: with variances, below); on TPU it routes to the
+  Pallas streaming kernels (``kernels.dtw.stream``) through
+  :func:`_scored_kernel_tick`, with the DP row AND the moment slabs
+  pinned in VMEM across the whole chunk.
 
 Uncertain-series matching (variance mode)
 -----------------------------------------
-Real traces carry per-sample measurement noise; the variance-mode
-entry points (:func:`bank_extend_tick_scored_var`,
-``dtw_score_bank_many(xvars=...)`` and their Pallas twins) propagate a
+Real traces carry per-sample measurement noise; the variance mode
+(:func:`bank_extend_tick` with ``vchunks``/``vstats``,
+``dtw_score_bank_many(xvars=...)`` and their Pallas twins) propagates a
 per-sample variance ``v_i`` through the SAME warp path and emit a match
 *probability* P[true warp correlation >= threshold] beside the point
 score.  Slab layout: the moment slab doubles from three channels
@@ -82,16 +82,16 @@ one definition shared by every path, exactly like
 observed correlation for noise-inflated query variance and applies
 first-order (delta-method) error propagation; zero input variance
 reduces BITWISE to the point rule, so variance mode is a strict
-generalization.  Exact-mode entry points are untouched (separate jitted
-functions, unchanged compiled graphs).
+generalization.  The channel set is static (a slab's shape, an argument
+given or None), so the three-channel tick traces none of it.
 
 Two probability modes share that machinery:
 
 * **exact** (six channels, above) — the verdict tail.  ``finish()``
   scoring and every offline probability goes through it; its numbers
   are the contract.
-* **approx** (:func:`bank_extend_tick_scored_var_approx`, FOUR
-  channels) — the serving tail.  Only ``svy = Σ v_i·y~_j(i)`` rides the
+* **approx** (:func:`bank_extend_tick` with a FOUR-channel slab) —
+  the serving tail.  Only ``svy = Σ v_i·y~_j(i)`` rides the
   warp path beside (sy, syy, sxy); the two dropped channels (svyy,
   svxy) are reconstructed at the score tail by
   :func:`_prob_from_moments_approx` from the carried proxy plus the
@@ -143,13 +143,6 @@ __all__ = [
     "dtw_bank_init",
     "dtw_bank_extend",
     "bank_extend_tick",
-    "bank_extend_tick_scored",
-    "bank_extend_tick_scored_var",
-    "bank_extend_tick_scored_var_approx",
-    "bank_extend_tick_dispatch",
-    "bank_extend_tick_scored_dispatch",
-    "bank_extend_tick_scored_var_dispatch",
-    "bank_extend_tick_scored_var_approx_dispatch",
     "backtrack",
     "warp_to",
     "dtw_warp",
@@ -158,7 +151,7 @@ __all__ = [
 _INF = jnp.float32(3.0e38)
 
 #: reference-tile width of the Pallas streaming tick kernels
-#: (``kernels.dtw.stream``; the ``block_k`` of the tick dispatchers).
+#: (``kernels.dtw.stream``; the ``block_k`` of :func:`bank_extend_tick`).
 TICK_BLOCK_K = 128
 
 
@@ -548,23 +541,24 @@ _MOM_SHIFT = jnp.float32(0.5)
 _Y_VALID = jnp.float32(1.0e30)
 
 
-def _bank_extend_diag_impl(rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
-                           nvalid, qlens, *, band: Optional[int],
-                           score: bool, vchunks=None, vstats=None,
-                           threshold: Optional[float] = None):
-    """Wavefront chunk-extend of J streaming bank DPs, optionally fused
-    with on-device open-end prefix scoring.  Pure function of arrays (jit
-    and shard_map wrappers live below / in serve.tuning) — everything is
-    elementwise per reference k, so sharding the K axis is exact.
+@functools.partial(jax.jit, static_argnames=("band", "threshold"))
+def _bank_extend_diag_impl(rows, moms, ns, sx, sxx, vstats, bank_t, lengths,
+                           chunks, vchunks, nvalid, qlens, *,
+                           band: Optional[int], threshold: float):
+    """Wavefront chunk-extend of J streaming bank DPs, fused with
+    on-device open-end prefix scoring when a moment slab is given: the
+    jnp twin of :func:`_scored_kernel_tick`, with the same arguments and
+    results (see :func:`bank_extend_tick`).  Everything is elementwise
+    per reference k, so sharding the K axis is exact.
 
-    rows    [J, M, K]    last DP row per job (init +inf), K-last layout
-    moms    [3, J, M, K] warp-path (sy, syy, sxy) moments of ``rows``'s
-                         cells (init 0; ignored unless ``score``)
-    ns      [J] int32    query samples consumed per job
-    sx, sxx [J] f32      centered query moment scalars (ignored w/o score)
-    bank_t  [M, K]       transposed reference bank
-    chunks  [J, C]       new samples (tail beyond ``nvalid[j]`` ignored)
-    qlens   [J] int32    total expected query length (banded only)
+    rows    [J, M, K]      last DP row per job (init +inf), K-last layout
+    moms    [nch, J, M, K] warp-path (sy, syy, sxy) moments of ``rows``'s
+                           cells (init 0), or None: distance only
+    ns      [J] int32      query samples consumed per job
+    sx, sxx [J] f32        centered query moment scalars
+    bank_t  [M, K]         transposed reference bank
+    chunks  [J, C]         new samples (tail beyond ``nvalid[j]`` ignored)
+    qlens   [J] int32      total expected query length (banded only)
 
     Variance mode (``vchunks`` [J, C] per-sample measurement variances,
     ``vstats`` [J, 3] running (sv, svx, svxx) folds, ``threshold`` the
@@ -577,14 +571,9 @@ def _bank_extend_diag_impl(rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
     only the svy proxy rides the path (delta ``v_i * delta_sy``) and
     the probability reduction is :func:`_prob_from_moments_approx`.
 
-    Returns ``(rows, moms, ns, sx, sxx, scores)``; ``scores`` is the
-    [J, K] open-end warp correlation per (job, reference) when ``score``
-    (the fused replacement for host ``prefix_similarity_bank``), else a
-    zero-size placeholder.  In variance mode two more results follow:
-    ``(..., vstats2, probs)`` with ``probs`` [J, K] the
-    :func:`_prob_from_moments` match probabilities at the same open-end
-    endpoints.  Cell values match ``_bank_extend_many`` to f32
-    tolerance (same recurrence, different evaluation order).
+    The score tail is :func:`_tick_tail`.  Cell values match
+    ``_bank_extend_many`` to f32 tolerance (same recurrence, different
+    evaluation order).
     """
     j, c = chunks.shape
     m, k = bank_t.shape
@@ -601,7 +590,8 @@ def _bank_extend_diag_impl(rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
     prow = jnp.concatenate(
         [jnp.broadcast_to(corner[:, None, None], (j, 1, k)), rows,
          jnp.full((j, c, k), _INF)], axis=1)                       # [J,M+C+1,K]
-    nch = moms.shape[0]                       # 3, or 6 in variance mode
+    score = moms is not None
+    nch = moms.shape[0] if score else 0       # 3, or 4 / 6 with variances
     if score:
         bpad = jnp.concatenate(
             [prow[None], jnp.concatenate(
@@ -693,26 +683,11 @@ def _bank_extend_diag_impl(rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
     # slot C-1 finishes column j = t - (C-1): steps C-1 .. C+M-2 emit the
     # post-chunk DP row (and its moments) column by column.
     new_rows = row_outs[c - 1:].transpose(1, 0, 2)                 # [J, M, K]
-    ns2 = ns + nvalid
     if not score:
-        return new_rows, moms, ns2, sx, sxx, jnp.zeros((j, 0))
-
+        return new_rows, None, ns + nvalid, sx, sxx, None
     new_moms = mom_outs[c - 1:].transpose(1, 2, 0, 3)            # [nch,J,M,K]
-    vmask = valid.astype(jnp.float32)
-    sx2 = sx + jnp.sum(xm * vmask, axis=1)
-    sxx2 = sxx + jnp.sum(xm * xm * vmask, axis=1)
-    if vchunks is None:
-        scores = _moment_scores(new_rows, new_moms, ns2, sx2, sxx2, lengths)
-        return new_rows, new_moms, ns2, sx2, sxx2, scores
-    vq = vchunks * vmask
-    vstats2 = vstats + jnp.stack(
-        [jnp.sum(vq, axis=1), jnp.sum(vq * xm, axis=1),
-         jnp.sum(vq * xm * xm, axis=1)], axis=1)                 # [J, 3]
-    scores = _moment_scores(new_rows, new_moms[:3], ns2, sx2, sxx2, lengths)
-    prob_fn = _moment_scores_prob if nch == 6 else _moment_scores_prob_approx
-    probs = prob_fn(new_rows, new_moms, ns2, sx2, sxx2,
-                    vstats2, lengths, threshold)
-    return new_rows, new_moms, ns2, sx2, sxx2, scores, vstats2, probs
+    return _tick_tail(new_rows, new_moms, ns, sx, sxx, vstats, lengths,
+                      chunks, vchunks, nvalid, threshold)
 
 
 def _corr_from_moments(sy, syy, sxy, sx, sxx, n):
@@ -741,20 +716,27 @@ def _corr_from_moments(sy, syy, sxy, sx, sxx, n):
     return jnp.where(degx | degy, jnp.where(both, 1.0, 0.0), corr)
 
 
-def _moment_scores(rows, moms, ns, sx, sxx, lengths):
-    """Open-end warp correlation per (job, reference) -> [J, K].
-
-    The on-device tail of the fused scorer: mask the DP row to true
-    columns, take the open-end argmin (the best reference *prefix*), read
-    the warp-path moments at that cell, and evaluate the correlation with
-    ``similarity.RunningMoments``'s formula and degenerate conventions.
-    """
+def _open_end_moments(rows, moms, lengths):
+    """The moment slabs read at each (job, reference)'s open end -> [nch,
+    J, K]: the argmin of the DP row over the reference's true columns
+    (the best reference *prefix*)."""
     m = rows.shape[1]
     colmask = jnp.arange(m, dtype=jnp.int32)[:, None] < lengths[None, :]
     masked = jnp.where(colmask[None], rows, _INF)
     j_end = jnp.argmin(masked, axis=1)                             # [J, K]
-    msel = jnp.take_along_axis(moms, j_end[None, :, None, :],
-                               axis=2)[:, :, 0, :]                 # [3, J, K]
+    return jnp.take_along_axis(moms, j_end[None, :, None, :],
+                               axis=2)[:, :, 0, :]
+
+
+def _moment_scores(rows, moms, ns, sx, sxx, lengths):
+    """Open-end warp correlation per (job, reference) -> [J, K].
+
+    The on-device tail of the fused scorer: read the warp-path moments at
+    the open-end endpoint (:func:`_open_end_moments`) and evaluate the
+    correlation with ``similarity.RunningMoments``'s formula and
+    degenerate conventions.
+    """
+    msel = _open_end_moments(rows, moms, lengths)                 # [3, J, K]
     n = jnp.maximum(ns, 1).astype(jnp.float32)[:, None]            # [J, 1]
     out = _corr_from_moments(msel[0], msel[1], msel[2], sx[:, None],
                              sxx[:, None], n)
@@ -815,32 +797,6 @@ def _prob_from_moments(sy, syy, sxy, svy, svyy, svxy, sx, sxx,
     phi = 0.5 * jax.lax.erfc(-z / jnp.sqrt(jnp.float32(2.0)))
     point = (r_hat >= threshold).astype(phi.dtype)
     return jnp.where(sigma > 0, phi, point)
-
-
-def _moment_scores_prob(rows, moms, ns, sx, sxx, vstats, lengths,
-                        threshold):
-    """Open-end match probability per (job, reference) -> [J, K].
-
-    The probabilistic twin of :func:`_moment_scores`: same masked
-    open-end argmin endpoint, but the gather reads all SIX moment
-    channels ([6, J, M, K] slab: (sy, syy, sxy, svy, svyy, svxy)) and
-    the tail is :func:`_prob_from_moments` with the path-independent
-    variance folds ``vstats`` = [J, 3] (sv, svx, svxx).  Empty slots
-    get probability 0.0 (no evidence -> abstain).
-    """
-    m = rows.shape[1]
-    colmask = jnp.arange(m, dtype=jnp.int32)[:, None] < lengths[None, :]
-    masked = jnp.where(colmask[None], rows, _INF)
-    j_end = jnp.argmin(masked, axis=1)                             # [J, K]
-    msel = jnp.take_along_axis(moms, j_end[None, :, None, :],
-                               axis=2)[:, :, 0, :]                 # [6, J, K]
-    n = jnp.maximum(ns, 1).astype(jnp.float32)[:, None]            # [J, 1]
-    probs = _prob_from_moments(
-        msel[0], msel[1], msel[2], msel[3], msel[4], msel[5],
-        sx[:, None], sxx[:, None], vstats[:, 0][:, None],
-        vstats[:, 1][:, None], vstats[:, 2][:, None], n,
-        jnp.float32(threshold))
-    return jnp.where(ns[:, None] > 0, probs, 0.0)
 
 
 def _prob_from_moments_approx(sy, syy, sxy, svy, sx, sxx, sv, svx, svxx,
@@ -912,121 +868,37 @@ def _prob_from_moments_approx(sy, syy, sxy, svy, sx, sxx, sv, svx, svxx,
     return jnp.where(sigma > 0, phi, point)
 
 
-def _moment_scores_prob_approx(rows, moms, ns, sx, sxx, vstats, lengths,
-                               threshold):
-    """Open-end approx match probability per (job, reference) -> [J, K].
+def _moment_probs(rows, moms, ns, sx, sxx, vstats, lengths, threshold):
+    """Open-end match probability per (job, reference) -> [J, K].
 
-    The four-channel twin of :func:`_moment_scores_prob`: same masked
-    open-end argmin endpoint, but the gather reads the [4, J, M, K]
-    slab (sy, syy, sxy, svy) and the tail is
-    :func:`_prob_from_moments_approx`.  Feeding it the first four
-    channels of an exact six-channel slab gives bit-identical output
-    (channel 3 is svy in both layouts) — which is how the degraded
-    approx tick under an exact-mode service reuses its slab.
+    The probabilistic twin of :func:`_moment_scores`: the same open-end
+    endpoint, with the path-independent variance folds ``vstats`` = [J, 3]
+    (sv, svx, svxx).  A six-channel slab (sy, syy, sxy, svy, svyy, svxy)
+    takes the exact tail :func:`_prob_from_moments`, a four-channel one
+    (sy, syy, sxy, svy) the approximate :func:`_prob_from_moments_approx`.
+    The first four channels of an exact slab give the approximate tail
+    bit-identical output (channel 3 is svy in both layouts), which is how
+    the degraded approx tick of an exact-mode service reuses its slab.
+    Empty slots get probability 0.0 (no evidence -> abstain).
     """
-    m = rows.shape[1]
-    colmask = jnp.arange(m, dtype=jnp.int32)[:, None] < lengths[None, :]
-    masked = jnp.where(colmask[None], rows, _INF)
-    j_end = jnp.argmin(masked, axis=1)                             # [J, K]
-    msel = jnp.take_along_axis(moms, j_end[None, :, None, :],
-                               axis=2)[:, :, 0, :]                 # [4, J, K]
+    msel = _open_end_moments(rows, moms, lengths)               # [nch, J, K]
     n = jnp.maximum(ns, 1).astype(jnp.float32)[:, None]            # [J, 1]
-    probs = _prob_from_moments_approx(
-        msel[0], msel[1], msel[2], msel[3],
-        sx[:, None], sxx[:, None], vstats[:, 0][:, None],
-        vstats[:, 1][:, None], vstats[:, 2][:, None], n,
-        jnp.float32(threshold))
+    tail = _prob_from_moments if moms.shape[0] == 6 \
+        else _prob_from_moments_approx
+    probs = tail(*msel, sx[:, None], sxx[:, None], vstats[:, 0][:, None],
+                 vstats[:, 1][:, None], vstats[:, 2][:, None], n,
+                 jnp.float32(threshold))
     return jnp.where(ns[:, None] > 0, probs, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("band",))
-def bank_extend_tick(rows, ns, bank_t, lengths, chunks, nvalid, qlens,
-                     band: Optional[int] = None):
-    """Distance-only streaming tick (jnp wavefront) -> (rows, ns).
-
-    K-last layout (rows [J, M, K], bank_t [M, K]).  The non-TPU fallback
-    of the fused tick; ``kernels.dtw.stream`` is the Pallas twin for TPU
-    backends (see :func:`bank_extend_tick_dispatch`).
-    """
-    z3 = jnp.zeros((3, 1, 1, 1))
-    zj = jnp.zeros(chunks.shape[:1])
-    new_rows, _, ns2, _, _, _ = _bank_extend_diag_impl(
-        rows, z3, ns, zj, zj, bank_t, lengths, chunks, nvalid, qlens,
-        band=band, score=False)
-    return new_rows, ns2
-
-
-@functools.partial(jax.jit, static_argnames=("band",))
-def bank_extend_tick_scored(rows, moms, ns, sx, sxx, bank_t, lengths,
-                            chunks, nvalid, qlens,
-                            band: Optional[int] = None):
-    """Fused scoring tick -> (rows, moms, ns, sx, sxx, scores [J, K])."""
-    return _bank_extend_diag_impl(rows, moms, ns, sx, sxx, bank_t, lengths,
-                                  chunks, nvalid, qlens, band=band,
-                                  score=True)
-
-
-@functools.partial(jax.jit, static_argnames=("band", "threshold"))
-def bank_extend_tick_scored_var(rows, moms, ns, sx, sxx, vstats, bank_t,
-                                lengths, chunks, vchunks, nvalid, qlens,
-                                band: Optional[int] = None,
-                                threshold: float = 0.9):
-    """Variance-carrying fused scoring tick (jnp wavefront) ->
-    ``(rows, moms, ns, sx, sxx, scores, vstats, probs)``.
-
-    Same recurrence as :func:`bank_extend_tick_scored` with the moment
-    slab doubled to six channels ([6, J, M, K]: sy, syy, sxy, svy, svyy,
-    svxy), per-sample variances ``vchunks`` [J, C] riding beside the
-    samples and the [J, 3] path-independent variance folds ``vstats``
-    (sv, svx, svxx) riding beside sx/sxx.  ``probs`` [J, K] are the
-    :func:`_prob_from_moments` match probabilities at the open-end
-    endpoints; ``scores`` stays the point correlation.  A separate entry
-    point (not a flag on the exact tick) so the exact tick's compiled
-    graph and cost are untouched when variance mode is off.
-    """
-    return _bank_extend_diag_impl(rows, moms, ns, sx, sxx, bank_t, lengths,
-                                  chunks, nvalid, qlens, band=band,
-                                  score=True, vchunks=vchunks,
-                                  vstats=vstats, threshold=threshold)
-
-
-def bank_extend_tick_dispatch(rows, ns, bank_t, lengths, chunks, nvalid,
-                              qlens, band: Optional[int] = None,
-                              use_kernel: Optional[bool] = None):
-    """Distance-only tick routed to the best backend: the Pallas streaming
-    kernel on TPU (DP row pinned in VMEM across the chunk), the jnp
-    wavefront everywhere else.  ``use_kernel=False`` forces the jnp
-    wavefront (the dispatch-resilience fallback twin).  Tick layout in
-    and out ([J, M, K])."""
-    if use_kernel is None:
-        use_kernel = _kernel_backend()
-    if use_kernel:
-        from ..kernels.dtw import stream_bank_extend
-        new_rows, ns2 = stream_bank_extend(
-            rows.transpose(0, 2, 1), ns, bank_t.T, lengths, chunks,
-            nvalid, qlens, band=band)
-        return new_rows.transpose(0, 2, 1), ns2
-    return bank_extend_tick(rows, ns, bank_t, lengths, chunks, nvalid,
-                            qlens, band=band)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("band", "interpret", "block_k"))
-def _scored_kernel_tick(rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
-                        nvalid, qlens, band: Optional[int],
-                        interpret: bool, block_k: int):
-    """Fused Pallas scoring tick in tick (K-last) layout — the layout
-    shuffles into/out of the kernel's K-major convention, the pallas_call
-    itself, the query-moment fold and the open-end score reduction all
-    trace into ONE jit, so nothing materializes between them beyond what
-    XLA schedules."""
-    from ..kernels.dtw import stream_bank_extend_scored_kernel
-    rows_km, moms_km, _ = stream_bank_extend_scored_kernel(
-        rows.transpose(0, 2, 1), moms.transpose(0, 1, 3, 2), ns,
-        bank_t.T, lengths, chunks, nvalid, qlens, band=band,
-        block_k=block_k, interpret=interpret)
-    new_rows = rows_km.transpose(0, 2, 1)                  # [J, M, K]
-    new_moms = moms_km.transpose(0, 1, 3, 2)               # [3, J, M, K]
+def _tick_tail(rows, moms, ns, sx, sxx, vstats, lengths, chunks, vchunks,
+               nvalid, threshold):
+    """The tick's epilogue after the DP, shared by the kernel path and the
+    jnp twin: fold the chunk's valid samples into the path-independent
+    query moments (sx, sxx and, with variances, ``vstats``) and score the
+    open ends of the new ``rows`` — the point correlation, and with a
+    variance slab the match probability (:func:`_moment_probs`).
+    Returns the tick's 6-tuple, or its 8-tuple with variances."""
     c = chunks.shape[1]
     xm = chunks - _MOM_SHIFT
     vmask = (jnp.arange(c, dtype=jnp.int32)[None, :]
@@ -1034,191 +906,91 @@ def _scored_kernel_tick(rows, moms, ns, sx, sxx, bank_t, lengths, chunks,
     sx2 = sx + jnp.sum(xm * vmask, axis=1)
     sxx2 = sxx + jnp.sum(xm * xm * vmask, axis=1)
     ns2 = ns + nvalid
-    scores = _moment_scores(new_rows, new_moms, ns2, sx2, sxx2, lengths)
-    return new_rows, new_moms, ns2, sx2, sxx2, scores
-
-
-def bank_extend_tick_scored_dispatch(rows, moms, ns, sx, sxx, bank_t,
-                                     lengths, chunks, nvalid, qlens,
-                                     band: Optional[int] = None,
-                                     use_kernel: Optional[bool] = None,
-                                     interpret: Optional[bool] = None,
-                                     block_k: int = TICK_BLOCK_K):
-    """Fused scoring tick routed to the best backend: the moment-carrying
-    Pallas streaming kernel on TPU (DP row AND the three [BK, M] moment
-    slabs pinned in VMEM across the whole chunk), the jnp wavefront
-    everywhere else.  Tick layout in and out (rows [J, M, K], moms
-    [3, J, M, K]); returns the same 6-tuple as
-    :func:`bank_extend_tick_scored`.
-
-    ``use_kernel``/``interpret`` exist for tests: forcing the kernel path
-    on a CPU host runs it in Pallas interpret mode, which is how the
-    cell-by-cell equivalence suite pins kernel == jnp wavefront.
-    """
-    if use_kernel is None:
-        use_kernel = _kernel_backend()
-    if use_kernel:
-        if interpret is None:
-            from ..kernels.common import default_interpret
-            interpret = default_interpret()
-        return _scored_kernel_tick(rows, moms, ns, sx, sxx, bank_t,
-                                   lengths, chunks, nvalid, qlens,
-                                   band=band, interpret=interpret,
-                                   block_k=block_k)
-    return bank_extend_tick_scored(rows, moms, ns, sx, sxx, bank_t,
-                                   lengths, chunks, nvalid, qlens,
-                                   band=band)
+    if vchunks is None:
+        scores = _moment_scores(rows, moms, ns2, sx2, sxx2, lengths)
+        return rows, moms, ns2, sx2, sxx2, scores
+    vq = vchunks * vmask
+    vstats2 = vstats + jnp.stack(
+        [jnp.sum(vq, axis=1), jnp.sum(vq * xm, axis=1),
+         jnp.sum(vq * xm * xm, axis=1)], axis=1)                 # [J, 3]
+    scores = _moment_scores(rows, moms[:3], ns2, sx2, sxx2, lengths)
+    probs = _moment_probs(rows, moms, ns2, sx2, sxx2, vstats2, lengths,
+                          threshold)
+    return rows, moms, ns2, sx2, sxx2, scores, vstats2, probs
 
 
 @functools.partial(jax.jit, static_argnames=("band", "threshold",
                                              "interpret", "block_k"))
-def _scored_kernel_tick_var(rows, moms, ns, sx, sxx, vstats, bank_t,
-                            lengths, chunks, vchunks, nvalid, qlens,
-                            band: Optional[int], threshold: float,
-                            interpret: bool, block_k: int):
-    """Variance-carrying Pallas scoring tick in tick (K-last) layout —
-    the six-channel twin of :func:`_scored_kernel_tick`."""
-    from ..kernels.dtw import stream_bank_extend_scored_kernel
+def _scored_kernel_tick(rows, moms, ns, sx, sxx, vstats, bank_t, lengths,
+                        chunks, vchunks, nvalid, qlens, band: Optional[int],
+                        threshold: float, interpret: bool, block_k: int):
+    """The Pallas tick in tick (K-last) layout: the layout shuffles into
+    and out of the kernel's K-major convention, the pallas_call, the
+    query folds and the score tail all trace into ONE jit, so nothing
+    materializes between them beyond what XLA schedules.  Without a
+    moment slab it is the distance-only kernel, and ``sx``/``sxx`` pass
+    through."""
+    from ..kernels.dtw import (stream_bank_extend_kernel,
+                               stream_bank_extend_scored_kernel)
+    if moms is None:
+        rows_km, ns2 = stream_bank_extend_kernel(
+            rows.transpose(0, 2, 1), ns, bank_t.T, lengths, chunks, nvalid,
+            qlens, band=band, block_k=block_k, interpret=interpret)
+        return rows_km.transpose(0, 2, 1), None, ns2, sx, sxx, None
     rows_km, moms_km, _ = stream_bank_extend_scored_kernel(
         rows.transpose(0, 2, 1), moms.transpose(0, 1, 3, 2), ns,
         bank_t.T, lengths, chunks, nvalid, qlens, band=band,
         block_k=block_k, interpret=interpret, vchunks=vchunks)
-    new_rows = rows_km.transpose(0, 2, 1)                  # [J, M, K]
-    new_moms = moms_km.transpose(0, 1, 3, 2)               # [6, J, M, K]
-    c = chunks.shape[1]
-    xm = chunks - _MOM_SHIFT
-    vmask = (jnp.arange(c, dtype=jnp.int32)[None, :]
-             < nvalid[:, None]).astype(jnp.float32)
-    sx2 = sx + jnp.sum(xm * vmask, axis=1)
-    sxx2 = sxx + jnp.sum(xm * xm * vmask, axis=1)
-    vq = vchunks * vmask
-    vstats2 = vstats + jnp.stack(
-        [jnp.sum(vq, axis=1), jnp.sum(vq * xm, axis=1),
-         jnp.sum(vq * xm * xm, axis=1)], axis=1)
-    ns2 = ns + nvalid
-    scores = _moment_scores(new_rows, new_moms[:3], ns2, sx2, sxx2,
-                            lengths)
-    probs = _moment_scores_prob(new_rows, new_moms, ns2, sx2, sxx2,
-                                vstats2, lengths, threshold)
-    return new_rows, new_moms, ns2, sx2, sxx2, scores, vstats2, probs
+    return _tick_tail(rows_km.transpose(0, 2, 1),          # [J, M, K]
+                      moms_km.transpose(0, 1, 3, 2),       # [nch, J, M, K]
+                      ns, sx, sxx, vstats, lengths, chunks, vchunks,
+                      nvalid, threshold)
 
 
-def bank_extend_tick_scored_var_dispatch(rows, moms, ns, sx, sxx, vstats,
-                                         bank_t, lengths, chunks, vchunks,
-                                         nvalid, qlens,
-                                         band: Optional[int] = None,
-                                         threshold: float = 0.9,
-                                         use_kernel: Optional[bool] = None,
-                                         interpret: Optional[bool] = None,
-                                         block_k: int = TICK_BLOCK_K):
-    """Variance-carrying fused scoring tick routed to the best backend
-    (Pallas streaming kernel with six VMEM moment slabs on TPU, jnp
-    wavefront elsewhere) — the probabilistic twin of
-    :func:`bank_extend_tick_scored_dispatch`, returning the 8-tuple of
-    :func:`bank_extend_tick_scored_var`."""
-    if use_kernel is None:
-        use_kernel = _kernel_backend()
-    if use_kernel:
-        if interpret is None:
-            from ..kernels.common import default_interpret
-            interpret = default_interpret()
-        return _scored_kernel_tick_var(rows, moms, ns, sx, sxx, vstats,
-                                       bank_t, lengths, chunks, vchunks,
-                                       nvalid, qlens, band=band,
-                                       threshold=threshold,
-                                       interpret=interpret,
-                                       block_k=block_k)
-    return bank_extend_tick_scored_var(rows, moms, ns, sx, sxx, vstats,
-                                       bank_t, lengths, chunks, vchunks,
-                                       nvalid, qlens, band=band,
-                                       threshold=threshold)
+def bank_extend_tick(rows, moms, ns, sx, sxx, vstats, bank_t, lengths,
+                     chunks, vchunks, nvalid, qlens, *,
+                     band: Optional[int] = None, threshold: float = 0.9,
+                     use_kernel: Optional[bool] = None,
+                     interpret: Optional[bool] = None,
+                     block_k: int = TICK_BLOCK_K):
+    """The streaming tick: advance J streaming DPs by one chunk and score
+    them, in tick layout (rows [J, M, K], moms [nch, J, M, K], bank_t
+    [M, K]).  The channel set comes from the arguments:
 
+    * ``moms`` with 3 channels (sy, syy, sxy), ``vstats``/``vchunks``
+      None: the point-rule scored tick -> ``(rows, moms, ns, sx, sxx,
+      scores)``;
+    * 6 channels with per-sample variances ``vchunks`` [J, C] and their
+      folds ``vstats`` [J, 3]: the exact probability tail, 4 channels
+      the approximate one -> ``(rows, moms, ns, sx, sxx, scores, vstats,
+      probs)``;
+    * ``moms`` None: the distance-only tick -> ``(rows, None, ns, sx,
+      sxx, None)``, ``sx``/``sxx`` passed through.
 
-@functools.partial(jax.jit, static_argnames=("band", "threshold"))
-def bank_extend_tick_scored_var_approx(rows, moms, ns, sx, sxx, vstats,
-                                       bank_t, lengths, chunks, vchunks,
-                                       nvalid, qlens,
-                                       band: Optional[int] = None,
-                                       threshold: float = 0.9):
-    """Approximate variance-carrying fused scoring tick (jnp wavefront)
-    -> ``(rows, moms, ns, sx, sxx, scores, vstats, probs)``.
-
-    The serving-rate probability tick: same recurrence and return
-    contract as :func:`bank_extend_tick_scored_var` but the moment slab
-    is FOUR channels ([4, J, M, K]: sy, syy, sxy, svy) — one carried
-    σ²-proxy instead of three — and ``probs`` comes from the
-    :func:`_prob_from_moments_approx` tail (reconstructed svyy/svxy).
-    ~1.3x the exact scored tick's slab traffic instead of ~2x; the
-    exact six-channel tick stays the verdict/finish scorer.  Zero
-    input variance reduces probs BITWISE to the point rule, exactly
-    like the exact tail.
+    The tick runs the Pallas streaming kernel on TPU
+    (:func:`_scored_kernel_tick`; DP row and moment slabs pinned in VMEM
+    across the chunk) and the jnp wavefront :func:`_bank_extend_diag_impl`
+    elsewhere, bit-identical on dyadic data.  ``use_kernel=False`` forces
+    the jnp twin (the dispatch-resilience fallback); forcing the kernel
+    on a CPU host runs it in Pallas interpret mode (``interpret``), which
+    is how the equivalence suites pin kernel == jnp twin.
     """
-    if moms.shape[0] != 4:
-        raise ValueError("approx variance mode needs a four-channel "
-                         f"moment slab, got {moms.shape[0]} channels")
-    return _bank_extend_diag_impl(rows, moms, ns, sx, sxx, bank_t, lengths,
-                                  chunks, nvalid, qlens, band=band,
-                                  score=True, vchunks=vchunks,
-                                  vstats=vstats, threshold=threshold)
-
-
-@functools.partial(jax.jit, static_argnames=("band", "threshold",
-                                             "interpret", "block_k"))
-def _scored_kernel_tick_var_approx(rows, moms, ns, sx, sxx, vstats, bank_t,
-                                   lengths, chunks, vchunks, nvalid, qlens,
-                                   band: Optional[int], threshold: float,
-                                   interpret: bool, block_k: int):
-    """Approx variance-carrying Pallas scoring tick in tick (K-last)
-    layout — the four-channel twin of :func:`_scored_kernel_tick_var`
-    (same kernel, one variance slab instead of three, approx tail)."""
-    from ..kernels.dtw import stream_bank_extend_scored_kernel
-    rows_km, moms_km, _ = stream_bank_extend_scored_kernel(
-        rows.transpose(0, 2, 1), moms.transpose(0, 1, 3, 2), ns,
-        bank_t.T, lengths, chunks, nvalid, qlens, band=band,
-        block_k=block_k, interpret=interpret, vchunks=vchunks)
-    new_rows = rows_km.transpose(0, 2, 1)                  # [J, M, K]
-    new_moms = moms_km.transpose(0, 1, 3, 2)               # [4, J, M, K]
-    c = chunks.shape[1]
-    xm = chunks - _MOM_SHIFT
-    vmask = (jnp.arange(c, dtype=jnp.int32)[None, :]
-             < nvalid[:, None]).astype(jnp.float32)
-    sx2 = sx + jnp.sum(xm * vmask, axis=1)
-    sxx2 = sxx + jnp.sum(xm * xm * vmask, axis=1)
-    vq = vchunks * vmask
-    vstats2 = vstats + jnp.stack(
-        [jnp.sum(vq, axis=1), jnp.sum(vq * xm, axis=1),
-         jnp.sum(vq * xm * xm, axis=1)], axis=1)
-    ns2 = ns + nvalid
-    scores = _moment_scores(new_rows, new_moms[:3], ns2, sx2, sxx2,
-                            lengths)
-    probs = _moment_scores_prob_approx(new_rows, new_moms, ns2, sx2, sxx2,
-                                       vstats2, lengths, threshold)
-    return new_rows, new_moms, ns2, sx2, sxx2, scores, vstats2, probs
-
-
-def bank_extend_tick_scored_var_approx_dispatch(
-        rows, moms, ns, sx, sxx, vstats, bank_t, lengths, chunks, vchunks,
-        nvalid, qlens, band: Optional[int] = None, threshold: float = 0.9,
-        use_kernel: Optional[bool] = None,
-        interpret: Optional[bool] = None, block_k: int = TICK_BLOCK_K):
-    """Approx variance-carrying fused scoring tick routed to the best
-    backend (Pallas streaming kernel with FOUR VMEM moment slabs on TPU,
-    jnp wavefront elsewhere) — the serving twin of
-    :func:`bank_extend_tick_scored_var_dispatch`, returning the 8-tuple
-    of :func:`bank_extend_tick_scored_var_approx`."""
+    nch = 0 if moms is None else moms.shape[0]
+    if nch not in ((0, 3) if vchunks is None else (4, 6)):
+        given = "without" if vchunks is None else "with"
+        raise ValueError(f"no tick takes {nch} moment channels {given} "
+                         "variances (0 or 3 without, 4 or 6 with)")
+    args = (rows, moms, ns, sx, sxx, vstats, bank_t, lengths, chunks,
+            vchunks, nvalid, qlens)
     if use_kernel is None:
         use_kernel = _kernel_backend()
-    if use_kernel:
-        if interpret is None:
-            from ..kernels.common import default_interpret
-            interpret = default_interpret()
-        return _scored_kernel_tick_var_approx(
-            rows, moms, ns, sx, sxx, vstats, bank_t, lengths, chunks,
-            vchunks, nvalid, qlens, band=band, threshold=threshold,
-            interpret=interpret, block_k=block_k)
-    return bank_extend_tick_scored_var_approx(
-        rows, moms, ns, sx, sxx, vstats, bank_t, lengths, chunks, vchunks,
-        nvalid, qlens, band=band, threshold=threshold)
+    if not use_kernel:
+        return _bank_extend_diag_impl(*args, band=band, threshold=threshold)
+    if interpret is None:
+        from ..kernels.common import default_interpret
+        interpret = default_interpret()
+    return _scored_kernel_tick(*args, band=band, threshold=threshold,
+                               interpret=interpret, block_k=block_k)
 
 
 # ---------------------------------------------------------------------------

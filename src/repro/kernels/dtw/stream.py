@@ -25,11 +25,11 @@ on ragged banks, Sakoe-Chiba bands, and arbitrary chunkings):
 
 Two kernels share the row-update machinery:
 
-* :func:`stream_bank_extend` — the distance-only tick (the large-K
-  throughput mode): one [BK, M] DP row slice per program.
-* :func:`stream_bank_extend_scored` — the FUSED scoring tick: the same
-  program additionally pins the three warp-path correlation-moment slabs
-  (sy, syy, sxy) of the DP row in VMEM and carries them through the DP
+* :func:`stream_bank_extend_kernel` — the distance-only tick (the
+  large-K throughput mode): one [BK, M] DP row slice per program.
+* :func:`stream_bank_extend_scored_kernel` — the FUSED scoring tick: the
+  same program additionally pins the three warp-path correlation-moment
+  slabs (sy, syy, sxy) of the DP row in VMEM and carries them through the DP
   with backtrack-identical predecessor selection (argmin over diag /
   vert / horiz with ``core.dtw.backtrack``'s tie order — diag first,
   then vert).  The horizontal moment recurrence m(i, j) = m(i, j-1) -
@@ -40,8 +40,8 @@ Two kernels share the row-update machinery:
   the same depth as the min-plus distance scan.  Cell values and moments
   match ``core.dtw._bank_extend_diag_impl`` cell-for-cell (pinned by
   tests/test_kernels.py); the open-end score reduction stays outside the
-  kernel (``core.dtw.bank_extend_tick_scored_dispatch`` fuses it into
-  the same jit).
+  kernel (``core.dtw._scored_kernel_tick``, the kernel path of
+  ``core.dtw.bank_extend_tick``, fuses it into the same jit).
 """
 
 from __future__ import annotations
@@ -56,8 +56,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["stream_bank_extend_kernel", "stream_bank_extend",
-           "stream_bank_extend_scored_kernel", "stream_bank_extend_scored"]
+__all__ = ["stream_bank_extend_kernel", "stream_bank_extend_scored_kernel"]
 
 _INF = 3.0e38  # plain float: jnp scalars become captured consts in Pallas
 
@@ -387,17 +386,6 @@ def stream_bank_extend_kernel(rows, ns, bank, lengths, chunks, nvalid,
     return new_rows[:, :k, :m], ns2
 
 
-def stream_bank_extend(rows, ns, bank, lengths, chunks, nvalid, qlens,
-                       band: Optional[int] = None,
-                       interpret: Optional[bool] = None):
-    """Backend-defaulted entry: compiled on TPU, interpret elsewhere."""
-    from ..common import default_interpret
-    interpret = default_interpret() if interpret is None else interpret
-    return stream_bank_extend_kernel(rows, ns, bank, lengths, chunks,
-                                     nvalid, qlens, band=band,
-                                     interpret=interpret)
-
-
 def stream_bank_extend_scored_kernel(rows, moms, ns, bank, lengths, chunks,
                                      nvalid, qlens,
                                      band: Optional[int] = None,
@@ -414,10 +402,10 @@ def stream_bank_extend_scored_kernel(rows, moms, ns, bank, lengths, chunks,
     variances with a SIX-channel ``moms`` [6, J, K, M] (sy, syy, sxy,
     svy, svyy, svxy) for the exact tail, or a FOUR-channel [4, J, K, M]
     (sy, syy, sxy, svy) for the approx serving tick — the extra slabs
-    ride the same VMEM row-scan.  The
-    open-end score reduction over the returned slabs lives in
-    ``core.dtw`` (``bank_extend_tick_scored[_var]_dispatch``) so the
-    moment semantics stay defined in exactly one place.
+    ride the same VMEM row-scan.  The open-end score reduction over
+    the returned slabs lives in ``core.dtw`` (``_tick_tail``, run by
+    ``bank_extend_tick``) so the moment semantics stay defined in
+    exactly one place.
     """
     rows = jnp.asarray(rows, jnp.float32)
     moms = jnp.asarray(moms, jnp.float32)
@@ -444,14 +432,3 @@ def stream_bank_extend_scored_kernel(rows, moms, ns, bank, lengths, chunks,
         nvalid, qlens, band, bk, interpret, vchunks=vchunks)
     return (new_rows[:, :k, :m],
             new_moms.transpose(1, 0, 2, 3)[:, :, :k, :m], ns2)
-
-
-def stream_bank_extend_scored(rows, moms, ns, bank, lengths, chunks,
-                              nvalid, qlens, band: Optional[int] = None,
-                              interpret: Optional[bool] = None):
-    """Backend-defaulted entry for the fused scoring tick."""
-    from ..common import default_interpret
-    interpret = default_interpret() if interpret is None else interpret
-    return stream_bank_extend_scored_kernel(rows, moms, ns, bank, lengths,
-                                            chunks, nvalid, qlens,
-                                            band=band, interpret=interpret)

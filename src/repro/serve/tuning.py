@@ -37,8 +37,11 @@ Tick engine (device-resident tick)
   with prefix scoring FUSED into the same dispatch: the device returns a
   ``[S, K]`` open-end warp-correlation array, not DP rows.
   ``dispatch_count`` records the invariant: dispatches == ticks(with
-  data) no matter how many jobs are in flight.  On TPU backends BOTH
-  tick flavors route to the Pallas streaming kernels
+  data) no matter how many jobs are in flight.  Every tick mode runs
+  the one entry point ``core.dtw.bank_extend_tick``; the mode only sets
+  the channel set of the moment slab it is given (``"scored"`` 3,
+  ``"approx_prob"`` 4, ``"prob"`` 6, ``"distance"`` none), and on TPU
+  backends it routes to the Pallas streaming kernels
   (``kernels.dtw.stream``).
 * ``mesh=`` shards the bank: a 1-D device mesh partitions the ``[M, K]``
   reference bank and every ``[.., K]`` state slab over its single axis
@@ -83,9 +86,9 @@ so the service *abstains* while the posterior is flat instead of
 committing on a lucky noisy prefix; the emitted ``TuneDecision``
 records the probability.  At zero input variance the probability is
 exactly 1.0 iff the correlation clears ``threshold``, so probabilistic
-decisions reduce bitwise to the point rule.  The exact tick's compiled
-graph is untouched when the mode is off (separate jitted entry
-points).
+decisions reduce bitwise to the point rule.  The scored tick's compiled
+graph is untouched when the mode is off: the channel set is static, so
+the three-channel tick traces no variance op.
 
 Two probability tails share that machinery (``prob_mode=``):
 
@@ -237,55 +240,57 @@ def _auto_axes(mesh: Union[jax.sharding.Mesh, Dict[str, int], None]
         axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
 
 
-#: tick mode -> (its ``core.dtw`` dispatch, the name of the kernel
-#: program that dispatch runs on TPU).  One device and a mesh run the
-#: same dispatch; the shard_mapped program takes the kernel program's
-#: name, so a device trace reads both as the same tick program.
-_TICK_DISPATCH = {
-    "prob": (_dtw.bank_extend_tick_scored_var_dispatch,
-             "_scored_kernel_tick_var"),
-    "approx_prob": (_dtw.bank_extend_tick_scored_var_approx_dispatch,
-                    "_scored_kernel_tick_var_approx"),
-    "scored": (_dtw.bank_extend_tick_scored_dispatch, "_scored_kernel_tick"),
-    "distance": (_dtw.bank_extend_tick_dispatch, "stream_bank_extend"),
-}
+#: moment channels of each tick mode's slab (0: none, the distance tick).
+_TICK_CHANNELS = {"prob": 6, "approx_prob": 4, "scored": 3, "distance": 0}
+
+#: the tick's arguments, in the order ``core.dtw.bank_extend_tick`` takes
+#: them; a tick without variances leaves out ``vstats`` and ``vchunks``.
+_TICK_ARGS = ("rows", "moms", "ns", "sx", "sxx", "vstats", "bank_t",
+              "lengths", "chunks", "vchunks", "nvalid", "qlens")
 
 
-def _tick_specs(mode: str, axis: str):
-    """(in, out) partition specs of a tick dispatch's arguments and
-    results: DP rows, moment slabs, bank, lengths and the ``[S, K]``
-    outputs split along K; per-slot arrays replicated."""
+def _tick_layout(nch: int) -> Tuple[str, ...]:
+    """The positional arguments of a tick over ``nch`` moment channels."""
+    return tuple(a for a in _TICK_ARGS
+                 if nch > 3 or a not in ("vstats", "vchunks"))
+
+
+def _tick_specs(nch: int, axis: str):
+    """(in, out) partition specs of a tick's arguments and results: DP
+    rows, moment slabs, bank, lengths and the ``[S, K]`` outputs split
+    along K; per-slot arrays replicated."""
     P = jax.sharding.PartitionSpec
-    rows, moms = P(None, None, axis), P(None, None, None, axis)
-    bank_t, per_k, out_k, rep = P(None, axis), P(axis), P(None, axis), P()
-    if mode == "distance":
-        return (rows, rep, bank_t, per_k, rep, rep, rep), (rows, rep)
-    if mode == "scored":
-        return ((rows, moms, rep, rep, rep, bank_t, per_k, rep, rep, rep),
-                (rows, moms, rep, rep, rep, out_k))
-    return ((rows, moms, rep, rep, rep, rep, bank_t, per_k, rep, rep, rep,
-             rep), (rows, moms, rep, rep, rep, out_k, rep, out_k))
+    split = {"rows": P(None, None, axis), "moms": P(None, None, None, axis),
+             "bank_t": P(None, axis), "lengths": P(axis)}
+    ins = tuple(split.get(a, P()) for a in _tick_layout(nch))
+    outs = (split["rows"], split["moms"], P(), P(), P(), P(None, axis))
+    return ins, outs + ((P(), P(None, axis)) if nch > 3 else ())
 
 
 def tick_program(mode: str, mesh: Optional[jax.sharding.Mesh] = None,
                  **kw):
-    """The tick dispatch of ``mode`` with ``kw`` (``band``,
+    """``core.dtw.bank_extend_tick`` over ``mode``'s channel set, taking
+    the mode's arguments (:func:`_tick_layout`), with ``kw`` (``band``,
     ``threshold``, ``use_kernel``, ``interpret``) bound.  Over a 1-D
-    ``mesh`` it is the same dispatch shard_mapped over the bank axis and
-    jitted: each device runs it on its K shard, the Pallas kernel on
-    TPU (``use_kernel=False``: the jnp twin)."""
-    if mode not in _TICK_DISPATCH:
+    ``mesh``: the same tick shard_mapped over the bank axis and jitted,
+    each device running it on its K shard, named for the program it
+    runs there so that a device trace reads both as one tick program."""
+    if mode not in _TICK_CHANNELS:
         raise ValueError(f"unknown tick mode {mode!r}")
-    dispatch, kernel_name = _TICK_DISPATCH[mode]
-    fn = functools.partial(dispatch, **kw)
+    nch = _TICK_CHANNELS[mode]
+    layout = _tick_layout(nch)
+
+    def tick(*args):
+        named = dict(zip(layout, args))
+        return _dtw.bank_extend_tick(*(named.get(a) for a in _TICK_ARGS),
+                                     **kw)
     if mesh is None:
-        return fn
-    ins, outs = _tick_specs(mode, mesh.axis_names[0])
-    sharded = jax.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=outs,
+        return tick
+    ins, outs = _tick_specs(nch, mesh.axis_names[0])
+    sharded = jax.shard_map(tick, mesh=mesh, in_specs=ins, out_specs=outs,
                             check_vma=False)
-    twin = dispatch.__name__.removesuffix("_dispatch")
-    sharded.__name__ = (twin if kw.get("use_kernel") is False
-                        else kernel_name) + "_sharded"
+    sharded.__name__ = ("_bank_extend_diag_impl" if kw.get("use_kernel")
+                        is False else "_scored_kernel_tick") + "_sharded"
     return jax.jit(sharded)
 
 
@@ -1001,29 +1006,27 @@ class TuningService:
         return fns
 
     def _build_tick_fn(self, mode: str):
-        """The ONE callable a tick dispatches, and its fallback: the
-        ``core.dtw`` dispatch of ``mode`` (fused scored extend, its
-        probability twins, or the distance-only variant), shard_mapped
-        over the bank axis under a mesh (:func:`tick_program`).
-        Sharding is exact — every DP cell and score is a per-reference
-        quantity, so the fan-out computes disjoint K slices and the
-        [S, K] score gather is the only cross-device output.
+        """The ONE callable a tick dispatches, and its fallback:
+        ``core.dtw.bank_extend_tick`` over ``mode``'s channel set,
+        shard_mapped over the bank axis under a mesh
+        (:func:`tick_program`).  Sharding is exact — every DP cell and
+        score is a per-reference quantity, so the fan-out computes
+        disjoint K slices and the [S, K] score gather is the only
+        cross-device output.
 
-        ``mode`` selects the dispatch flavor (``"prob"`` /
-        ``"approx_prob"`` / ``"scored"`` / ``"distance"``): the
-        configured mode in an unloaded service, or a cheaper ladder
-        rung's flavor under overload (every flavor updates the DP rows
-        identically — same warp-path predecessor selection — so a
-        degraded tick leaves the rows bitwise what the full tick would
-        have computed and only side channels go stale).
+        ``mode`` sets the channel set (``"prob"`` 6, ``"approx_prob"``
+        4, ``"scored"`` 3, ``"distance"`` none): the configured mode in
+        an unloaded service, or a cheaper ladder rung's under overload
+        (every channel set updates the DP rows identically — same
+        warp-path predecessor selection — so a degraded tick leaves the
+        rows bitwise what the full tick would have computed and only
+        side channels go stale).
 
         Returns ``(tick_fn, fallback_fn)``.  The fallback is the same
-        dispatch pinned to the jnp wavefront twin (``use_kernel=False``)
+        tick pinned to the jnp wavefront twin (``use_kernel=False``)
         — bit-identical to the Pallas kernel, so a degraded tick after
         retry exhaustion changes latency, never results."""
-        kw = dict(band=self.band)
-        if mode in ("prob", "approx_prob"):
-            kw["threshold"] = float(self.threshold)
+        kw = dict(band=self.band, threshold=float(self.threshold))
         return (tick_program(mode, self.mesh, **kw),
                 tick_program(mode, self.mesh, use_kernel=False, **kw))
 
@@ -1391,75 +1394,43 @@ class TuningService:
                     vchunks[job.slot, : ch.shape[0]] = vch
             span.set_metadata(chunk=c, slots=self._s_cap)
 
-        # Effective tick mode: the configured flavor, or a cheaper one
-        # under the overload ladder.  Every flavor updates the DP rows
+        # Effective tick mode: the configured one, or a cheaper one
+        # under the overload ladder.  Every mode updates the DP rows
         # (and ns) identically — the warp-path predecessor selection is
         # shared — so a degraded tick DELAYS decisions (side channels go
         # stale, marked on the job) but can never change them.
         mode = self._tick_mode()
-        base = self._base_mode()
+        nch = _TICK_CHANNELS[mode]
         tick_fn, tick_fb = self._tick_fn_for(mode)
+        # A rung cheaper than the configured mode ticks the first nch
+        # channels of the service's wider slab (sy, syy, sxy, then svy:
+        # the approx rung of an exact service keeps its probabilities
+        # flowing).  The channels above stay what they were — stale,
+        # never wrong-and-used, because degraded_level >= 1 suppresses
+        # every read of them — and are spliced back after the tick.
+        wide = nch > 0 and self._moms.shape[0] > nch
+        moms = self._moms[:nch] if wide else (self._moms if nch else None)
         scores = probs = None
         with TraceAnnotation("tuner.dispatch", mode=mode, k_live=k_live,
                              shards=self._ndev):
-            if mode == "prob":
-                args = (self._rows, self._moms, self._ns, self._sx,
-                        self._sxx, self._vstats, self._bank_t,
-                        self._lengths, jnp.asarray(chunks),
-                        jnp.asarray(vchunks), jnp.asarray(nvalid),
-                        jnp.asarray(self._qlens))
-                (self._rows, self._moms, self._ns, self._sx, self._sxx,
-                 scores, self._vstats, probs) = self._dispatch_resilient(
-                    tick_fn, tick_fb, args, "tick")
-            elif mode == "approx_prob":
-                # the approx tail needs only channels 0:4 (sy, syy, sxy,
-                # svy).  An approx-configured service carries exactly
-                # those four; an exact-configured service capped to this
-                # rung dispatches over the first four of its six —
-                # svyy/svxy stay stale (degraded_level=1 suppresses early
-                # decisions), but probabilities keep flowing: the rung
-                # sheds precision, not probabilities.
-                moms_in = self._moms[:4] if base == "prob" else self._moms
-                args = (self._rows, moms_in, self._ns, self._sx, self._sxx,
-                        self._vstats, self._bank_t, self._lengths,
-                        jnp.asarray(chunks), jnp.asarray(vchunks),
-                        jnp.asarray(nvalid), jnp.asarray(self._qlens))
-                (self._rows, moms_out, self._ns, self._sx, self._sxx,
-                 scores, self._vstats, probs) = self._dispatch_resilient(
-                    tick_fn, tick_fb, args, "tick")
-                if base == "prob":
-                    self._moms = self._put(
-                        jnp.concatenate([moms_out, self._moms[4:]], axis=0),
-                        (None, None, None, self._axis))
-                else:
-                    self._moms = moms_out
-            elif mode == "scored":
-                # a prob-configured service ticking at the exact_score
-                # rung runs the 3-channel dispatch over channels 0:3 of
-                # its moment slab (6 channels exact, 4 approx); the
-                # variance channels (and vstats) simply stay what they
-                # were — stale, never wrong-and-used, because
-                # degraded_level >= 1 suppresses every probability read.
-                moms_in = self._moms[:3] \
-                    if base in ("prob", "approx_prob") else self._moms
-                args = (self._rows, moms_in, self._ns, self._sx, self._sxx,
-                        self._bank_t, self._lengths, jnp.asarray(chunks),
-                        jnp.asarray(nvalid), jnp.asarray(self._qlens))
-                (self._rows, moms_out, self._ns, self._sx, self._sxx,
-                 scores) = self._dispatch_resilient(
-                    tick_fn, tick_fb, args, "tick")
-                if base in ("prob", "approx_prob"):
-                    self._moms = self._put(
-                        jnp.concatenate([moms_out, self._moms[3:]], axis=0),
-                        (None, None, None, self._axis))
-                else:
-                    self._moms = moms_out
-            else:
-                args = (self._rows, self._ns, self._bank_t, self._lengths,
-                        jnp.asarray(chunks), jnp.asarray(nvalid),
-                        jnp.asarray(self._qlens))
-                self._rows, self._ns = self._dispatch_resilient(
-                    tick_fn, tick_fb, args, "tick")
+            given = dict(
+                rows=self._rows, moms=moms, ns=self._ns, sx=self._sx,
+                sxx=self._sxx, vstats=self._vstats, bank_t=self._bank_t,
+                lengths=self._lengths, chunks=jnp.asarray(chunks),
+                vchunks=jnp.asarray(vchunks) if nch > 3 else None,
+                nvalid=jnp.asarray(nvalid), qlens=jnp.asarray(self._qlens))
+            res = self._dispatch_resilient(
+                tick_fn, tick_fb, tuple(given[a] for a in _tick_layout(nch)),
+                "tick")
+            self._rows, moms, self._ns, self._sx, self._sxx, scores = res[:6]
+            if nch > 3:
+                self._vstats, probs = res[6:]
+            if wide:
+                self._moms = self._put(
+                    jnp.concatenate([moms, self._moms[nch:]], axis=0),
+                    (None, None, None, self._axis))
+            elif nch:
+                self._moms = moms
         self.dispatch_count += 1
 
         # the tick's ONLY device->host transfer: the [S, K_live] scores
@@ -1478,7 +1449,7 @@ class TuningService:
                         np.asarray(probs, np.float64)[:, :k_live]
 
         with TraceAnnotation("tuner.decide") as span:
-            if mode != base:
+            if mode != self._base_mode():
                 lvl = 2 if mode == "distance" else 1
                 for job, *_ in pending:
                     job.degraded_level = max(job.degraded_level, lvl)

@@ -109,66 +109,102 @@ def _dyadic_series(rng, n, denom=8, hi=9):
     return (rng.integers(0, hi, n) / float(denom)).astype(np.float32)
 
 
-@pytest.mark.parametrize("band,block_k", [(None, 128), (6, 128), (None, 4),
-                                          (6, 4)])
-def test_stream_scored_kernel_cell_by_cell(band, block_k):
-    """Moment-carrying Pallas streaming kernel == the jnp scored wavefront
-    (`bank_extend_tick_scored`) AND the row-formulation reference
-    (`_bank_extend_many`) on every cell — DP rows, (sy, syy, sxy) moment
-    slabs and open-end scores alike — across ragged reference banks,
-    Sakoe-Chiba bands, ragged per-job chunks (including empty ones) and a
-    block_k that forces reference-tile padding.  Exact comparison on
-    dyadic-grid data (see `_dyadic_series`)."""
+def _var_bank(rng, k=7):
+    from repro.core.database import pack_series
+    return pack_series([_dyadic_series(rng, int(rng.integers(12, 30)))
+                        for _ in range(k)])
+
+
+def _tick(state, bank_t, lengths, ch, vch, nv, qlens, **kw):
+    """One tick of ``state`` = (rows, moms, ns, sx, sxx, vstats) through
+    ``bank_extend_tick`` -> (state, scores, probs); ``vch`` None and a
+    three-channel slab: the point-rule tick (``vstats``/``probs`` None)."""
+    from repro.core import dtw as _dtw
+    rows, moms, ns, sx, sxx, vst = state
+    out = _dtw.bank_extend_tick(rows, moms, ns, sx, sxx, vst, bank_t,
+                                lengths, ch, vch, nv, qlens, **kw)
+    vst, probs = out[6:] if vch is not None else (None, None)
+    return (*out[:5], vst), out[5], probs
+
+
+def _fresh(j, m, k, nch):
+    """An empty tick state over ``nch`` moment channels (variance folds
+    only with a variance slab)."""
     import jax.numpy as jnp
     from repro.core import dtw as _dtw
-    from repro.core.database import pack_series
+    return (jnp.full((j, m, k), _dtw._INF), jnp.zeros((nch, j, m, k)),
+            jnp.zeros((j,), jnp.int32), jnp.zeros((j,)), jnp.zeros((j,)),
+            jnp.zeros((j, 3)) if nch > 3 else None)
 
-    rng = np.random.default_rng(11 if band is None else band + block_k)
-    series = [_dyadic_series(rng, int(rng.integers(12, 30)))
-              for _ in range(7)]
-    bank = pack_series(series)
+
+#: each channel set's seed when unbanded (3: point rule, 6: exact
+#: probability, 4: approximate).
+_CELL_SEED = {3: 11, 6: 23, 4: 53}
+
+
+@pytest.mark.parametrize("band,block_k", [(None, 128), (6, 128), (None, 4),
+                                          (6, 4)])
+@pytest.mark.parametrize("nch", [3, 6, 4], ids=["scored", "exact", "approx"])
+def test_stream_scored_kernel_cell_by_cell(nch, band, block_k):
+    """Moment-carrying Pallas streaming kernel == the jnp wavefront twin
+    of ``bank_extend_tick`` on every cell, for each channel set: DP
+    rows, every moment slab, open-end scores and, with variances, the
+    (sv, svx, svxx) folds and the exact (6 channels) or approx (4)
+    probabilities; the rows also equal the row-formulation reference
+    (``_bank_extend_many``).  Across ragged reference banks, Sakoe-Chiba
+    bands, ragged per-job chunks (including empty ones) and a block_k
+    that forces reference-tile padding.  Exact comparison on dyadic-grid
+    data, variances dyadic too (see `_dyadic_series`)."""
+    import jax.numpy as jnp
+    from repro.core import dtw as _dtw
+
+    rng = np.random.default_rng(_CELL_SEED[nch] if band is None
+                                else band + block_k)
+    bank = _var_bank(rng)
     k, m = bank.series.shape
     J, C = 3, 8
     qlens = jnp.full((J,), 4 * C, jnp.int32)
     bank_t = jnp.asarray(bank.series.T)
     lengths = jnp.asarray(bank.lengths)
-    rows_w = jnp.full((J, m, k), _dtw._INF)
-    moms_w = jnp.zeros((3, J, m, k))
-    ns_w = jnp.zeros((J,), jnp.int32)
-    sx_w = jnp.zeros((J,))
-    sxx_w = jnp.zeros((J,))
-    rows_p, moms_p, ns_p, sx_p, sxx_p = rows_w, moms_w, ns_w, sx_w, sxx_w
+    state_w = state_p = _fresh(J, m, k, nch)
     rows_h = jnp.full((J, k, m), _dtw._INF)
     ns_h = jnp.zeros((J,), jnp.int32)
     for _ in range(4):
         nv = jnp.asarray(rng.integers(0, C + 1, size=J).astype(np.int32))
         ch = jnp.asarray((rng.integers(0, 9, (J, C)) / 8.0)
                          .astype(np.float32))
-        rows_w, moms_w, ns_w, sx_w, sxx_w, sc_w = \
-            _dtw.bank_extend_tick_scored(
-                rows_w, moms_w, ns_w, sx_w, sxx_w, bank_t, lengths, ch,
-                nv, qlens, band=band)
-        rows_p, moms_p, ns_p, sx_p, sxx_p, sc_p = \
-            _dtw.bank_extend_tick_scored_dispatch(
-                rows_p, moms_p, ns_p, sx_p, sxx_p, bank_t, lengths,
-                ch, nv, qlens, band=band, use_kernel=True,
-                interpret=True, block_k=block_k)
+        vch = jnp.asarray((rng.integers(0, 5, (J, C)) / 64.0)
+                          .astype(np.float32)) if nch > 3 else None
+        state_w, sc_w, pr_w = _tick(state_w, bank_t, lengths, ch, vch, nv,
+                                    qlens, band=band, threshold=0.85,
+                                    use_kernel=False)
+        state_p, sc_p, pr_p = _tick(state_p, bank_t, lengths, ch, vch, nv,
+                                    qlens, band=band, threshold=0.85,
+                                    use_kernel=True, interpret=True,
+                                    block_k=block_k)
         rows_h, ns_h, _ = _dtw._bank_extend_many(
             rows_h, ns_h, jnp.asarray(bank.series), lengths, ch, nv,
             qlens, band, False)
-        rp, rw = np.asarray(rows_p), np.asarray(rows_w)
+        rp, rw = np.asarray(state_p[0]), np.asarray(state_w[0])
         rh = np.asarray(rows_h).transpose(0, 2, 1)
         finite = rw < 1e37
         assert (finite == (rp < 1e37)).all()
         assert (finite == (rh < 1e37)).all()
         np.testing.assert_array_equal(rp[finite], rw[finite])
         np.testing.assert_array_equal(rp[finite], rh[finite])
-        mp, mw = np.asarray(moms_p), np.asarray(moms_w)
-        fin3 = np.broadcast_to(finite[None], mp.shape)
-        np.testing.assert_array_equal(mp[fin3], mw[fin3])
+        mp, mw = np.asarray(state_p[1]), np.asarray(state_w[1])
+        assert mp.shape[0] == nch
+        fin = np.broadcast_to(finite[None], mp.shape)
+        np.testing.assert_array_equal(mp[fin], mw[fin])
         np.testing.assert_array_equal(np.asarray(sc_p), np.asarray(sc_w))
-    np.testing.assert_array_equal(np.asarray(ns_p), np.asarray(ns_w))
-    np.testing.assert_array_equal(np.asarray(ns_p), np.asarray(ns_h))
+        if nch > 3:
+            np.testing.assert_array_equal(np.asarray(pr_p),
+                                          np.asarray(pr_w))
+            np.testing.assert_array_equal(np.asarray(state_p[5]),
+                                          np.asarray(state_w[5]))
+    np.testing.assert_array_equal(np.asarray(state_p[2]),
+                                  np.asarray(state_w[2]))
+    np.testing.assert_array_equal(np.asarray(state_p[2]), np.asarray(ns_h))
 
 
 def test_stream_scored_kernel_scores_match_host_backtrack():
@@ -209,11 +245,10 @@ def test_stream_scored_kernel_scores_match_host_backtrack():
     for t0 in range(nticks):
         ch = jnp.asarray(qs[:, t0 * C:(t0 + 1) * C])
         nv = jnp.full((J,), C, jnp.int32)
-        rows, moms, ns, sx, sxx, scores = \
-            _dtw.bank_extend_tick_scored_dispatch(
-                rows, moms, ns, sx, sxx, jnp.asarray(bank.series.T),
-                jnp.asarray(bank.lengths), ch, nv, qlens,
-                use_kernel=True, interpret=True)
+        rows, moms, ns, sx, sxx, scores = _dtw.bank_extend_tick(
+            rows, moms, ns, sx, sxx, None, jnp.asarray(bank.series.T),
+            jnp.asarray(bank.lengths), ch, None, nv, qlens,
+            use_kernel=True, interpret=True)
         rows_h, ns_h, coll = _dtw._bank_extend_many(
             rows_h, ns_h, jnp.asarray(bank.series),
             jnp.asarray(bank.lengths), ch, nv, qlens, None, True)
@@ -267,129 +302,70 @@ def test_stream_kernel_cell_by_cell_vs_bank_extend(band, block_k):
 # Variance-carrying (uncertain-series) DP
 # ---------------------------------------------------------------------------
 
-def _var_bank(rng, k=7):
-    from repro.core.database import pack_series
-    return pack_series([_dyadic_series(rng, int(rng.integers(12, 30)))
-                        for _ in range(k)])
-
-
-@pytest.mark.parametrize("band,block_k", [(None, 128), (6, 128), (None, 4),
-                                          (6, 4)])
-def test_stream_scored_var_kernel_cell_by_cell(band, block_k):
-    """Variance-mode Pallas streaming kernel == the jnp variance wavefront
-    on every cell — DP rows, all SIX moment slabs, scores, probs and the
-    (sv, svx, svxx) folds — across ragged banks, bands, ragged chunks and
-    tile padding; exact on dyadic grids (variances dyadic too, so every
-    variance-channel product is exactly representable)."""
-    import jax.numpy as jnp
-    from repro.core import dtw as _dtw
-
-    rng = np.random.default_rng(23 if band is None else band + block_k)
-    bank = _var_bank(rng)
-    k, m = bank.series.shape
-    J, C = 3, 8
-    qlens = jnp.full((J,), 4 * C, jnp.int32)
-    bank_t = jnp.asarray(bank.series.T)
-    lengths = jnp.asarray(bank.lengths)
-    rows_w = jnp.full((J, m, k), _dtw._INF)
-    moms_w = jnp.zeros((6, J, m, k))
-    ns_w = jnp.zeros((J,), jnp.int32)
-    sx_w = jnp.zeros((J,))
-    sxx_w = jnp.zeros((J,))
-    vst_w = jnp.zeros((J, 3))
-    state_p = (rows_w, moms_w, ns_w, sx_w, sxx_w, vst_w)
-    state_w = state_p
-    for _ in range(4):
-        nv = jnp.asarray(rng.integers(0, C + 1, size=J).astype(np.int32))
-        ch = jnp.asarray((rng.integers(0, 9, (J, C)) / 8.0)
-                         .astype(np.float32))
-        vch = jnp.asarray((rng.integers(0, 5, (J, C)) / 64.0)
-                          .astype(np.float32))
-        *state_w, sc_w, vw, pr_w = _dtw.bank_extend_tick_scored_var(
-            state_w[0], state_w[1], state_w[2], state_w[3], state_w[4],
-            state_w[5], bank_t, lengths, ch, vch, nv, qlens, band=band,
-            threshold=0.85)
-        state_w = state_w[:5] + [vw]
-        *state_p, sc_p, vp, pr_p = \
-            _dtw.bank_extend_tick_scored_var_dispatch(
-                state_p[0], state_p[1], state_p[2], state_p[3], state_p[4],
-                state_p[5], bank_t, lengths, ch, vch, nv, qlens, band=band,
-                threshold=0.85, use_kernel=True, interpret=True,
-                block_k=block_k)
-        state_p = state_p[:5] + [vp]
-        rp, rw = np.asarray(state_p[0]), np.asarray(state_w[0])
-        finite = rw < 1e37
-        assert (finite == (rp < 1e37)).all()
-        np.testing.assert_array_equal(rp[finite], rw[finite])
-        mp, mw = np.asarray(state_p[1]), np.asarray(state_w[1])
-        fin6 = np.broadcast_to(finite[None], mp.shape)
-        np.testing.assert_array_equal(mp[fin6], mw[fin6])
-        np.testing.assert_array_equal(np.asarray(sc_p), np.asarray(sc_w))
-        np.testing.assert_array_equal(np.asarray(pr_p), np.asarray(pr_w))
-        np.testing.assert_array_equal(np.asarray(vp), np.asarray(vw))
-    np.testing.assert_array_equal(np.asarray(state_p[2]),
-                                  np.asarray(state_w[2]))
+#: each variance channel set's seed (6: exact, 4: approx).
+_VAR_SEED = {6: (31, 47), 4: (59, 61)}
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
-def test_stream_var_zero_variance_reduces_bitwise(use_kernel):
+@pytest.mark.parametrize("nch", [6, 4], ids=["exact", "approx"])
+def test_stream_var_zero_variance_reduces_bitwise(nch, use_kernel):
     """With all-zero per-sample variances the variance tick's rows, base
-    moment slabs and scores are BIT-IDENTICAL to the exact scored tick's,
-    and every probability is exactly 0.0 or 1.0 with
-    ``prob == 1 <=> score >= threshold``."""
+    moment slabs and scores are BIT-IDENTICAL to the point-rule scored
+    tick's, and every probability is exactly 0.0 or 1.0 with
+    ``prob == 1 <=> score >= threshold`` — for the exact (6 channels)
+    and the approx (4) tail; the approx probs are bitwise equal to the
+    exact tick's (the approx tail reduces to the same point rule, not
+    just the same set)."""
     import jax.numpy as jnp
-    from repro.core import dtw as _dtw
 
-    rng = np.random.default_rng(31)
+    rng = np.random.default_rng(_VAR_SEED[nch][0])
     bank = _var_bank(rng)
     k, m = bank.series.shape
     J, C = 3, 8
     qlens = jnp.full((J,), 4 * C, jnp.int32)
     bank_t = jnp.asarray(bank.series.T)
     lengths = jnp.asarray(bank.lengths)
-    rows_e = jnp.full((J, m, k), _dtw._INF)
-    moms_e = jnp.zeros((3, J, m, k))
-    ns_e = jnp.zeros((J,), jnp.int32)
-    sx_e = jnp.zeros((J,))
-    sxx_e = jnp.zeros((J,))
-    rows_v, moms_v = rows_e, jnp.zeros((6, J, m, k))
-    ns_v, sx_v, sxx_v = ns_e, sx_e, sxx_e
-    vst = jnp.zeros((J, 3))
+    point = _fresh(J, m, k, 3)
+    sets = sorted({nch, 6})
+    var = {n: _fresh(J, m, k, n) for n in sets}
     thr = 0.85
+    kw = dict(band=6, threshold=thr, use_kernel=use_kernel,
+              interpret=True if use_kernel else None)
     for _ in range(4):
         nv = jnp.asarray(rng.integers(0, C + 1, size=J).astype(np.int32))
         ch = jnp.asarray((rng.integers(0, 9, (J, C)) / 8.0)
                          .astype(np.float32))
         vch = jnp.zeros((J, C))
-        rows_e, moms_e, ns_e, sx_e, sxx_e, sc_e = \
-            _dtw.bank_extend_tick_scored(rows_e, moms_e, ns_e, sx_e,
-                                         sxx_e, bank_t, lengths, ch, nv,
-                                         qlens, band=6)
-        (rows_v, moms_v, ns_v, sx_v, sxx_v, sc_v, vst,
-         pr) = _dtw.bank_extend_tick_scored_var_dispatch(
-            rows_v, moms_v, ns_v, sx_v, sxx_v, vst, bank_t, lengths, ch,
-            vch, nv, qlens, band=6, threshold=thr, use_kernel=use_kernel,
-            interpret=True if use_kernel else None)
-        np.testing.assert_array_equal(np.asarray(rows_v),
-                                      np.asarray(rows_e))
-        np.testing.assert_array_equal(np.asarray(moms_v)[:3],
-                                      np.asarray(moms_e))
-        np.testing.assert_array_equal(np.asarray(sc_v), np.asarray(sc_e))
-        assert np.asarray(vst).max() == 0.0
-        pr, sc = np.asarray(pr), np.asarray(sc_e)
-        assert set(np.unique(pr)) <= {0.0, 1.0}
-        np.testing.assert_array_equal(pr == 1.0, sc >= thr)
+        point, sc_e, _ = _tick(point, bank_t, lengths, ch, None, nv, qlens,
+                               band=6, use_kernel=False)
+        sc = np.asarray(sc_e)
+        probs = {}
+        for n in sets:
+            var[n], sc_v, probs[n] = _tick(var[n], bank_t, lengths, ch, vch,
+                                           nv, qlens, **kw)
+            np.testing.assert_array_equal(np.asarray(var[n][0]),
+                                          np.asarray(point[0]))
+            np.testing.assert_array_equal(np.asarray(var[n][1])[:3],
+                                          np.asarray(point[1]))
+            np.testing.assert_array_equal(np.asarray(sc_v), sc)
+            assert np.asarray(var[n][5]).max() == 0.0
+            pr = np.asarray(probs[n])
+            assert set(np.unique(pr)) <= {0.0, 1.0}
+            np.testing.assert_array_equal(pr == 1.0, sc >= thr)
+        np.testing.assert_array_equal(np.asarray(probs[nch]),
+                                      np.asarray(probs[6]))
 
 
-def test_stream_var_chunking_invariance():
+@pytest.mark.parametrize("nch", [6, 4], ids=["exact", "approx"])
+def test_stream_var_chunking_invariance(nch):
     """Any chunking of the variance-mode stream reproduces the one-shot
     solve BITWISE on dyadic grids (samples and variances both dyadic):
-    rows, all six moment slabs, variance folds, scores and probs."""
+    rows, every moment slab (six exact, four approx), variance folds,
+    scores and probs."""
     from hypothesis import given, settings, strategies as st
     import jax.numpy as jnp
-    from repro.core import dtw as _dtw
 
-    rng0 = np.random.default_rng(47)
+    rng0 = np.random.default_rng(_VAR_SEED[nch][1])
     bank = _var_bank(rng0, k=5)
     k, m = bank.series.shape
     N = 24
@@ -399,24 +375,18 @@ def test_stream_var_chunking_invariance():
     v = (rng0.integers(0, 5, N) / 64.0).astype(np.float32)
 
     def run(chunk_sizes):
-        rows = jnp.full((1, m, k), _dtw._INF)
-        moms = jnp.zeros((6, 1, m, k))
-        ns = jnp.zeros((1,), jnp.int32)
-        sx = jnp.zeros((1,))
-        sxx = jnp.zeros((1,))
-        vst = jnp.zeros((1, 3))
+        state = _fresh(1, m, k, nch)
         lo = 0
         out = None
         for c in chunk_sizes:
             ch, vch = q[lo:lo + c], v[lo:lo + c]
             lo += c
-            (rows, moms, ns, sx, sxx, sc, vst,
-             pr) = _dtw.bank_extend_tick_scored_var(
-                rows, moms, ns, sx, sxx, vst, bank_t, lengths,
-                jnp.asarray(ch[None]), jnp.asarray(vch[None]),
-                jnp.asarray([len(ch)], np.int32),
-                jnp.asarray([N], np.int32), band=4, threshold=0.85)
-            out = (rows, moms, vst, sc, pr)
+            state, sc, pr = _tick(
+                state, bank_t, lengths, jnp.asarray(ch[None]),
+                jnp.asarray(vch[None]), jnp.asarray([len(ch)], np.int32),
+                jnp.asarray([N], np.int32), band=4, threshold=0.85,
+                use_kernel=False)
+            out = (state[0], state[1], state[5], sc, pr)
         return [np.asarray(a) for a in out]
 
     ref = run([N])
@@ -512,177 +482,6 @@ def test_score_bank_many_var_jnp_vs_kernel():
 # ---------------------------------------------------------------------------
 # Approximate-tail (4-channel) variance DP
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("band,block_k", [(None, 128), (6, 128), (None, 4),
-                                          (6, 4)])
-def test_stream_scored_var_approx_kernel_cell_by_cell(band, block_k):
-    """Approx-mode (FOUR moment channels) Pallas streaming kernel == the
-    jnp variance wavefront on every cell — DP rows, all four slabs, the
-    (sv, svx, svxx) folds, scores and approx probs — across ragged
-    banks, bands, ragged chunks and tile padding; exact on dyadic
-    grids."""
-    import jax.numpy as jnp
-    from repro.core import dtw as _dtw
-
-    rng = np.random.default_rng(53 if band is None else band + block_k)
-    bank = _var_bank(rng)
-    k, m = bank.series.shape
-    J, C = 3, 8
-    qlens = jnp.full((J,), 4 * C, jnp.int32)
-    bank_t = jnp.asarray(bank.series.T)
-    lengths = jnp.asarray(bank.lengths)
-    rows_w = jnp.full((J, m, k), _dtw._INF)
-    moms_w = jnp.zeros((4, J, m, k))
-    ns_w = jnp.zeros((J,), jnp.int32)
-    sx_w = jnp.zeros((J,))
-    sxx_w = jnp.zeros((J,))
-    vst_w = jnp.zeros((J, 3))
-    state_p = (rows_w, moms_w, ns_w, sx_w, sxx_w, vst_w)
-    state_w = state_p
-    for _ in range(4):
-        nv = jnp.asarray(rng.integers(0, C + 1, size=J).astype(np.int32))
-        ch = jnp.asarray((rng.integers(0, 9, (J, C)) / 8.0)
-                         .astype(np.float32))
-        vch = jnp.asarray((rng.integers(0, 5, (J, C)) / 64.0)
-                          .astype(np.float32))
-        *state_w, sc_w, vw, pr_w = _dtw.bank_extend_tick_scored_var_approx(
-            state_w[0], state_w[1], state_w[2], state_w[3], state_w[4],
-            state_w[5], bank_t, lengths, ch, vch, nv, qlens, band=band,
-            threshold=0.85)
-        state_w = state_w[:5] + [vw]
-        *state_p, sc_p, vp, pr_p = \
-            _dtw.bank_extend_tick_scored_var_approx_dispatch(
-                state_p[0], state_p[1], state_p[2], state_p[3], state_p[4],
-                state_p[5], bank_t, lengths, ch, vch, nv, qlens, band=band,
-                threshold=0.85, use_kernel=True, interpret=True,
-                block_k=block_k)
-        state_p = state_p[:5] + [vp]
-        rp, rw = np.asarray(state_p[0]), np.asarray(state_w[0])
-        finite = rw < 1e37
-        assert (finite == (rp < 1e37)).all()
-        np.testing.assert_array_equal(rp[finite], rw[finite])
-        mp, mw = np.asarray(state_p[1]), np.asarray(state_w[1])
-        fin4 = np.broadcast_to(finite[None], mp.shape)
-        np.testing.assert_array_equal(mp[fin4], mw[fin4])
-        np.testing.assert_array_equal(np.asarray(sc_p), np.asarray(sc_w))
-        np.testing.assert_array_equal(np.asarray(pr_p), np.asarray(pr_w))
-        np.testing.assert_array_equal(np.asarray(vp), np.asarray(vw))
-    np.testing.assert_array_equal(np.asarray(state_p[2]),
-                                  np.asarray(state_w[2]))
-
-
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_stream_var_approx_zero_variance_reduces_bitwise(use_kernel):
-    """With all-zero per-sample variances the APPROX tick's rows, base
-    moment slabs and scores are BIT-IDENTICAL to the exact scored
-    tick's, every probability is exactly 0.0 or 1.0 with
-    ``prob == 1 <=> score >= threshold`` — and the probs are bitwise
-    equal to the exact six-channel variance tick's (the approx tail
-    reduces to the same point rule, not just the same set)."""
-    import jax.numpy as jnp
-    from repro.core import dtw as _dtw
-
-    rng = np.random.default_rng(59)
-    bank = _var_bank(rng)
-    k, m = bank.series.shape
-    J, C = 3, 8
-    qlens = jnp.full((J,), 4 * C, jnp.int32)
-    bank_t = jnp.asarray(bank.series.T)
-    lengths = jnp.asarray(bank.lengths)
-    rows_e = jnp.full((J, m, k), _dtw._INF)
-    moms_e = jnp.zeros((3, J, m, k))
-    ns_e = jnp.zeros((J,), jnp.int32)
-    sx_e = jnp.zeros((J,))
-    sxx_e = jnp.zeros((J,))
-    rows_a, moms_a = rows_e, jnp.zeros((4, J, m, k))
-    ns_a, sx_a, sxx_a = ns_e, sx_e, sxx_e
-    vst_a = jnp.zeros((J, 3))
-    rows_v, moms_v = rows_e, jnp.zeros((6, J, m, k))
-    ns_v, sx_v, sxx_v, vst_v = ns_e, sx_e, sxx_e, vst_a
-    thr = 0.85
-    for _ in range(4):
-        nv = jnp.asarray(rng.integers(0, C + 1, size=J).astype(np.int32))
-        ch = jnp.asarray((rng.integers(0, 9, (J, C)) / 8.0)
-                         .astype(np.float32))
-        vch = jnp.zeros((J, C))
-        rows_e, moms_e, ns_e, sx_e, sxx_e, sc_e = \
-            _dtw.bank_extend_tick_scored(rows_e, moms_e, ns_e, sx_e,
-                                         sxx_e, bank_t, lengths, ch, nv,
-                                         qlens, band=6)
-        (rows_a, moms_a, ns_a, sx_a, sxx_a, sc_a, vst_a,
-         pr_a) = _dtw.bank_extend_tick_scored_var_approx_dispatch(
-            rows_a, moms_a, ns_a, sx_a, sxx_a, vst_a, bank_t, lengths, ch,
-            vch, nv, qlens, band=6, threshold=thr, use_kernel=use_kernel,
-            interpret=True if use_kernel else None)
-        (rows_v, moms_v, ns_v, sx_v, sxx_v, sc_v, vst_v,
-         pr_v) = _dtw.bank_extend_tick_scored_var_dispatch(
-            rows_v, moms_v, ns_v, sx_v, sxx_v, vst_v, bank_t, lengths, ch,
-            vch, nv, qlens, band=6, threshold=thr, use_kernel=use_kernel,
-            interpret=True if use_kernel else None)
-        np.testing.assert_array_equal(np.asarray(rows_a),
-                                      np.asarray(rows_e))
-        np.testing.assert_array_equal(np.asarray(moms_a)[:3],
-                                      np.asarray(moms_e))
-        np.testing.assert_array_equal(np.asarray(sc_a), np.asarray(sc_e))
-        assert np.asarray(vst_a).max() == 0.0
-        pr, sc = np.asarray(pr_a), np.asarray(sc_e)
-        assert set(np.unique(pr)) <= {0.0, 1.0}
-        np.testing.assert_array_equal(pr == 1.0, sc >= thr)
-        np.testing.assert_array_equal(pr, np.asarray(pr_v))
-
-
-def test_stream_var_approx_chunking_invariance():
-    """Any chunking of the approx-mode stream reproduces the one-shot
-    solve BITWISE on dyadic grids: rows, all four moment slabs,
-    variance folds, scores and approx probs."""
-    from hypothesis import given, settings, strategies as st
-    import jax.numpy as jnp
-    from repro.core import dtw as _dtw
-
-    rng0 = np.random.default_rng(61)
-    bank = _var_bank(rng0, k=5)
-    k, m = bank.series.shape
-    N = 24
-    bank_t = jnp.asarray(bank.series.T)
-    lengths = jnp.asarray(bank.lengths)
-    q = _dyadic_series(rng0, N)
-    v = (rng0.integers(0, 5, N) / 64.0).astype(np.float32)
-
-    def run(chunk_sizes):
-        rows = jnp.full((1, m, k), _dtw._INF)
-        moms = jnp.zeros((4, 1, m, k))
-        ns = jnp.zeros((1,), jnp.int32)
-        sx = jnp.zeros((1,))
-        sxx = jnp.zeros((1,))
-        vst = jnp.zeros((1, 3))
-        lo = 0
-        out = None
-        for c in chunk_sizes:
-            ch, vch = q[lo:lo + c], v[lo:lo + c]
-            lo += c
-            (rows, moms, ns, sx, sxx, sc, vst,
-             pr) = _dtw.bank_extend_tick_scored_var_approx(
-                rows, moms, ns, sx, sxx, vst, bank_t, lengths,
-                jnp.asarray(ch[None]), jnp.asarray(vch[None]),
-                jnp.asarray([len(ch)], np.int32),
-                jnp.asarray([N], np.int32), band=4, threshold=0.85)
-            out = (rows, moms, vst, sc, pr)
-        return [np.asarray(a) for a in out]
-
-    ref = run([N])
-
-    @settings(max_examples=12, deadline=None)
-    @given(st.integers(min_value=1, max_value=N - 1))
-    def prop(c):
-        sizes = [c] * (N // c)
-        if N % c:
-            sizes.append(N % c)
-        got = run(sizes)
-        for a, b in zip(got, ref):
-            np.testing.assert_array_equal(a, b)
-
-    prop()
-
 
 @pytest.mark.parametrize("band,block_k", [(None, 128), (6, 128), (None, 4),
                                           (6, 4)])

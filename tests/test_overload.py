@@ -450,6 +450,102 @@ def test_distance_downgrade_suppresses_earlies_finals_bitwise():
     assert lf == gf
 
 
+# -- capped ticks: the channel splice against the per-mode branches ---------
+_STATE = ("rows", "moms", "ns", "sx", "sxx", "vstats")
+
+
+def _branch_tick(pre, base, mode, chunks, vchunks, nvalid, qlens, band,
+                 threshold):
+    """The tick as it was dispatched before one entry point took every
+    channel set: one branch a mode, each building its own arguments, the
+    approx and scored branches ticking the first 4 or 3 channels of a
+    wider slab and concatenating the untouched ones back, the distance
+    branch touching rows and ``ns`` only.  Kept as the oracle of the one
+    dispatch branch in ``TuningService._tick_impl``."""
+    import jax.numpy as jnp
+    from repro.core.dtw import bank_extend_tick
+    kw = dict(band=band, threshold=threshold, use_kernel=False)
+    rows, moms, ns, sx, sxx, vst = (pre[a] for a in _STATE)
+    bank_t, lengths = pre["bank_t"], pre["lengths"]
+    if mode == "prob":
+        rows, moms, ns, sx, sxx, _, vst, _ = bank_extend_tick(
+            rows, moms, ns, sx, sxx, vst, bank_t, lengths, chunks, vchunks,
+            nvalid, qlens, **kw)
+    elif mode == "approx_prob":
+        moms_in = moms[:4] if base == "prob" else moms
+        rows, out, ns, sx, sxx, _, vst, _ = bank_extend_tick(
+            rows, moms_in, ns, sx, sxx, vst, bank_t, lengths, chunks,
+            vchunks, nvalid, qlens, **kw)
+        moms = jnp.concatenate([out, moms[4:]], axis=0) \
+            if base == "prob" else out
+    elif mode == "scored":
+        moms_in = moms[:3] if base in ("prob", "approx_prob") else moms
+        rows, out, ns, sx, sxx, _ = bank_extend_tick(
+            rows, moms_in, ns, sx, sxx, None, bank_t, lengths, chunks, None,
+            nvalid, qlens, **kw)
+        moms = jnp.concatenate([out, moms[3:]], axis=0) \
+            if base in ("prob", "approx_prob") else out
+    else:
+        rows, _, ns, *_ = bank_extend_tick(
+            rows, None, ns, None, None, None, bank_t, lengths, chunks, None,
+            nvalid, qlens, **kw)
+    return dict(rows=rows, moms=moms, ns=ns, sx=sx, sxx=sxx, vstats=vst)
+
+
+@pytest.mark.parametrize("prob_mode", ["exact", "approx"])
+def test_capped_ticks_splice_channels_as_the_mode_branches(prob_mode):
+    """A prob-configured (six channels) and an approx-configured (four)
+    service ticked through the ladder's caps — ``approx_prob``,
+    ``scored``, ``distance`` and back — leave the DP rows, every slab
+    channel (the untouched upper ones included), ``ns``, the query folds
+    and the variance folds bitwise where the per-mode branches
+    (:func:`_branch_tick`) leave them, from the same state and chunks."""
+    svc = TuningService(_bank(), slots=4, min_probability=0.5,
+                        prob_mode=prob_mode,
+                        overload=OverloadConfig(target_p99=1e9, max_rung=3))
+    base = svc._base_mode()
+    order = ("prob", "approx_prob", "scored", "distance")   # the caps
+    calls = []
+
+    def record(mode):
+        fn, fallback = svc._tick_fn_for(mode)
+
+        def tick(*args):
+            pre = {a: getattr(svc, "_" + a) for a in
+                   _STATE + ("bank_t", "lengths")}
+            var = len(args) == 12
+            calls.append((mode, pre, args[-4] if var else args[-3],
+                          args[-3] if var else None, args[-2], args[-1]))
+            return fn(*args)
+        svc._tick_fns[mode] = (tick, fallback)
+
+    for mode in {base, "approx_prob", "scored", "distance"}:
+        record(mode)
+    streams = _streams(seed=11, length=64)
+    noise = np.random.default_rng(12)
+    for j in streams:
+        svc.submit(j, 64)
+    rungs = [1, 2, 3, 0, 2, 1, 3, 0]
+    for t, rung in enumerate(rungs):
+        for j, x in streams.items():
+            svc.push(j, x[t * 8:(t + 1) * 8],
+                     variance=noise.uniform(0.0, 0.05, 8).astype(np.float32))
+        svc._overload.rung = rung
+        svc.tick()
+        mode, pre, chunks, vchunks, nvalid, qlens = calls[-1]
+        assert mode == order[max(rung, order.index(base))]
+        want = _branch_tick(pre, base, mode, chunks, vchunks, nvalid, qlens,
+                            svc.band, float(svc.threshold))
+        for a in _STATE:
+            np.testing.assert_array_equal(np.asarray(getattr(svc, "_" + a)),
+                                          np.asarray(want[a]), err_msg=a)
+        nch = {"prob": 6, "approx_prob": 4, "scored": 3}.get(mode, 0)
+        np.testing.assert_array_equal(np.asarray(svc._moms)[nch:],
+                                      np.asarray(pre["moms"])[nch:])
+    assert {c[0] for c in calls} == {base, "approx_prob", "scored",
+                                     "distance"}
+
+
 def test_deep_prune_rung_halves_prefilter_budget():
     svc = TuningService(_bank(k=8), prefilter_top=6,
                         overload=OverloadConfig(target_p99=0.01,

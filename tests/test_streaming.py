@@ -489,7 +489,8 @@ def test_finish_purges_undelivered_decision_of_finished_job(paper_bank):
 @settings(max_examples=10, deadline=None)
 @given(st.integers(min_value=0, max_value=2**31 - 1))
 def test_wavefront_tick_equals_bank_extend_many(seed):
-    """The K-last wavefront tick (``dtw.bank_extend_tick``) must agree
+    """The K-last wavefront tick (``dtw.bank_extend_tick`` without a
+    moment slab: the distance-only tick, on its jnp twin) must agree
     cell-for-cell with the row-formulation reference, across random
     ragged chunkings, ragged banks, banded and unbanded."""
     import jax.numpy as jnp
@@ -509,9 +510,11 @@ def test_wavefront_tick_equals_bank_extend_many(seed):
     for _ in range(4):
         nv = jnp.asarray(rng.integers(0, C + 1, size=J).astype(np.int32))
         ch = jnp.asarray(rng.random((J, C)).astype(np.float32))
-        rows_w, ns_w = dtw.bank_extend_tick(
-            rows_w, ns_w, jnp.asarray(bank.series.T),
-            jnp.asarray(bank.lengths), ch, nv, qlens, band=band)
+        rows_w, moms, ns_w, _, _, scores = dtw.bank_extend_tick(
+            rows_w, None, ns_w, None, None, None,
+            jnp.asarray(bank.series.T), jnp.asarray(bank.lengths), ch,
+            None, nv, qlens, band=band, use_kernel=False)
+        assert moms is None and scores is None
         rows_h, ns_h, _ = dtw._bank_extend_many(
             rows_h, ns_h, jnp.asarray(bank.series),
             jnp.asarray(bank.lengths), ch, nv, qlens, band, False)
@@ -559,9 +562,10 @@ def test_fused_device_scores_match_host_prefix_scoring(band):
     for t0 in range(nticks):
         ch = jnp.asarray(qs[:, t0 * C:(t0 + 1) * C])
         nv = jnp.full((J,), C, jnp.int32)
-        rows, moms, ns, sx, sxx, scores = dtw.bank_extend_tick_scored(
-            rows, moms, ns, sx, sxx, jnp.asarray(bank.series.T),
-            jnp.asarray(bank.lengths), ch, nv, qlens, band=band)
+        rows, moms, ns, sx, sxx, scores = dtw.bank_extend_tick(
+            rows, moms, ns, sx, sxx, None, jnp.asarray(bank.series.T),
+            jnp.asarray(bank.lengths), ch, None, nv, qlens, band=band,
+            use_kernel=False)
         rows_h, ns_h, coll = dtw._bank_extend_many(
             rows_h, ns_h, jnp.asarray(bank.series),
             jnp.asarray(bank.lengths), ch, nv, qlens, band, True)

@@ -23,8 +23,7 @@ import pytest
 
 from repro.core import dtw as _dtw
 from repro.kernels.dtw import (score_bank_offline_kernel,
-                               score_bank_offline_var_kernel,
-                               stream_bank_extend_kernel)
+                               score_bank_offline_var_kernel)
 
 K, M, C, N = 1024, 512, 8, 512        # bank, reference length, chunk, query
 HBM_BYTES = 16e9                      # one v5e chip
@@ -73,34 +72,22 @@ def _check(fn, *args):
                          ids=["distance", "scored", "approx", "exact"])
 def test_stream_tick_compiles(spec, nch, s, band):
     """The serving tick in tick layout ([S, M, K] state), as the service
-    dispatches it: 0 channels (distance), 3 (scored), 4 (approx
-    probability) and 6 (exact probability)."""
+    dispatches it through ``bank_extend_tick``: no moment slab
+    (distance), 3 channels (scored), 4 (approx probability) and 6
+    (exact probability)."""
     i32 = jnp.int32
-    state = (spec((s, M, K)),) if nch == 0 else \
-        (spec((s, M, K)), spec((nch, s, M, K)))
-    per_job = (spec((s,), i32),)                               # ns
-    if nch:
-        per_job += (spec((s,)), spec((s,)))                    # sx, sxx
-    if nch > 3:
-        per_job += (spec((s, 3)),)                             # vstats
-    bank = (spec((M, K)), spec((K,), i32))
-    chunk = (spec((s, C)),) if nch <= 3 else (spec((s, C)), spec((s, C)))
-    tail = (spec((s,), i32), spec((s,), i32))                  # nvalid, qlens
+    var = nch > 3
+    args = (spec((s, M, K)), spec((nch, s, M, K)) if nch else None,
+            spec((s,), i32), spec((s,)), spec((s,)),           # ns, sx, sxx
+            spec((s, 3)) if var else None,                     # vstats
+            spec((M, K)), spec((K,), i32),                     # bank
+            spec((s, C)), spec((s, C)) if var else None,       # chunks
+            spec((s,), i32), spec((s,), i32))                  # nvalid, qlens
 
-    if nch == 0:
-        def fn(rows, ns, bank_t, lengths, chunks, nvalid, qlens):
-            new, ns2 = stream_bank_extend_kernel(
-                rows.transpose(0, 2, 1), ns, bank_t.T, lengths, chunks,
-                nvalid, qlens, band=band, interpret=False)
-            return new.transpose(0, 2, 1), ns2
-    else:
-        entry = {3: _dtw.bank_extend_tick_scored_dispatch,
-                 4: _dtw.bank_extend_tick_scored_var_approx_dispatch,
-                 6: _dtw.bank_extend_tick_scored_var_dispatch}[nch]
-
-        def fn(*args):
-            return entry(*args, band=band, use_kernel=True, interpret=False)
-    _check(fn, *state, *per_job, *bank, *chunk, *tail)
+    def fn(*a):
+        return _dtw.bank_extend_tick(*a, band=band, use_kernel=True,
+                                     interpret=False)
+    _check(fn, *args)
 
 
 @pytest.mark.parametrize("band", [None, 8], ids=["full", "band8"])
